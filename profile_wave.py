@@ -14,9 +14,12 @@ wal_handoff are SUBSETS of host_egress / ingress_drain respectively,
 and the WAL rows run on their own threads (concurrent with the loop);
 they are listed for attribution, not added to the share denominator.
 
-Usage: PYTHONPATH= JAX_PLATFORMS=cpu python profile_wave.py
-       [groups] [cmds] [--top N] [--cprofile] [--trace out.json]
-       [--native on|off|both]
+Usage: python profile_wave.py [groups] [cmds] [--top N] [--cprofile]
+       [--trace out.json] [--native on|off|both]
+
+Runs on whatever device JAX finds and prints its platform: on the
+machine with the chip that is the TPU; set ``JAX_PLATFORMS=cpu`` from
+outside for a CPU run, whose tables count calls and are not speeds.
 
 ``--native both`` runs the native hot-loop runtime pass and the Python
 control back to back (histograms reset between) and prints both phase
@@ -148,9 +151,16 @@ def _reset_wave_histograms() -> None:
 
 def main(groups=2048, cmds=24, top=5, cprofile=False, trace=None,
          pipeline="on", native="on") -> None:
-    import os
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    from ra_tpu.utils.lib import enable_compile_cache
+
+    enable_compile_cache()
+    import jax
+
     from bench import bench_pipeline
+
+    dev = jax.devices()[0]
+    print(f"profile_wave: platform {dev.platform} ({dev.device_kind}, "
+          f"{len(jax.devices())} device(s))", file=sys.stderr)
 
     if trace:
         # wave-phase timeline spans (Chrome/Perfetto JSON): the view
@@ -194,7 +204,8 @@ def main(groups=2048, cmds=24, top=5, cprofile=False, trace=None,
               f"p50={out['p50_ms']}ms p99={out['p99_ms']}ms [{label}]",
               file=sys.stderr)
         print(f"\n## profile_wave: {groups} groups x {cmds} cmds "
-              f"(WAL-backed, pipeline={pipeline}, {label}, "
+              f"(device {dev.platform}, WAL-backed, pipeline={pipeline}, "
+              f"{label}, "
               f"{out['value']:.0f} cmd/s, "
               f"unloaded p50 {out['p50_ms']} ms)\n")
         print(phase_tables([f"bench{i}" for i in range(3)], top=top))

@@ -124,7 +124,11 @@ class SegmentWriter:
             self._closed = True
             self._cv.notify_all()
         if self._thread is not None:
-            self._thread.join(timeout=10)
+            # for as long as its backlog takes: going on under a flusher
+            # that is still writing races it for the open handles (a
+            # 10,240-group flush outlasted the old 10 s limit on the
+            # chip host's disk, and close() died iterating them)
+            self._thread.join()
         self._drain()
         for h in self._open.values():
             h.close()

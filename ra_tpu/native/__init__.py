@@ -1,7 +1,8 @@
 """Native (C++) acceleration for the storage and hot-loop runtime paths.
 
-Two libraries, built with g++ on first use (cached ``.so`` next to the
-source) and exposed through ctypes bindings:
+Two libraries, built with g++ on first use (cached next to the source
+as ``<name>.<digest of the source>.so``) and exposed through ctypes
+bindings:
 
 - ``wal_native``: WAL batch framing + write + fsync (PR 5);
 - ``rt_native``: the hot-loop runtime (docs/INTERNALS.md §18) — ring
@@ -10,7 +11,7 @@ source) and exposed through ctypes bindings:
 Everything here has a pure-Python fallback. ``available()`` reports the
 WAL library (the historical contract); ``entry_points()`` reports every
 loaded symbol so bench artifacts are self-describing. A failed build is
-cached per source mtime (a missing compiler does not re-attempt the
+cached per source digest (a missing compiler does not re-attempt the
 build on every import) and surfaces the compiler stderr in ONE warning
 instead of a silent fallback.
 """
@@ -18,6 +19,8 @@ instead of a silent fallback.
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 import sys
@@ -38,29 +41,37 @@ _tried = False
 _rt_lib = None
 _rt_tried = False
 
-# negative build cache: src path -> source mtime the failure was seen
+# negative build cache: src path -> source digest the failure was seen
 # at (a changed source retries; an unchanged one never rebuilds), and
 # whether the one-shot warning for it was already emitted
-_build_failed: Dict[str, float] = {}
+_build_failed: Dict[str, str] = {}
 _warned: set = set()
 
 
 def _build(src: str = _SRC, so: str = _SO) -> Optional[str]:
-    if os.path.exists(so) and os.path.getmtime(so) >= os.path.getmtime(src):
-        return so
-    mtime = os.path.getmtime(src)
-    if _build_failed.get(src) == mtime:
+    """Path of the library built from ``src`` as it reads now, building
+    it if need be. The file is ``<so stem>.<source digest>.so``: the
+    libraries are git-ignored and travel with a copied tree, where
+    mtimes say nothing about which source a ``.so`` was built from."""
+    with open(src, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    stem = so[:-len(".so")]
+    built = f"{stem}.{digest}.so"
+    if os.path.exists(built):
+        return built
+    if _build_failed.get(src) == digest:
         return None  # cached negative result for this exact source
+    tmp = f"{built}.{os.getpid()}.tmp"
     try:
         subprocess.run(
-            ["g++", "-O2", "-shared", "-fPIC", "-o", so, src],
+            ["g++", "-O2", "-shared", "-fPIC", "-o", tmp, src],
             check=True,
             capture_output=True,
             timeout=120,
         )
-        return so
+        os.replace(tmp, built)  # atomic: a parallel builder never loads half a file
     except Exception as e:  # noqa: BLE001
-        _build_failed[src] = mtime
+        _build_failed[src] = digest
         if src not in _warned:
             _warned.add(src)
             detail = ""
@@ -77,6 +88,10 @@ def _build(src: str = _SRC, so: str = _SO) -> Optional[str]:
                 file=sys.stderr,
             )
         return None
+    for old in glob.glob(f"{glob.escape(stem)}.*.so"):
+        if old != built:
+            os.unlink(old)  # libraries of sources that no longer exist
+    return built
 
 
 def _load():
@@ -92,8 +107,6 @@ def _load():
             lib = ctypes.CDLL(so)
         except OSError:
             return None
-        if not hasattr(lib, "wal_write_batch"):
-            return None  # stale cached .so predating the write path
         lib.wal_frame_batch.restype = ctypes.c_long
         lib.wal_frame_batch.argtypes = [
             ctypes.c_char_p,  # kinds u8*
@@ -144,8 +157,6 @@ def _load_rt():
             lib = ctypes.CDLL(so)
         except OSError:
             return None
-        if not hasattr(lib, "rt_seal_frames"):
-            return None  # stale cached .so
         lib.rt_classify.restype = ctypes.c_long
         lib.rt_classify.argtypes = [
             ctypes.c_char_p,  # codes u8*

@@ -14,8 +14,10 @@ The majority row is then selected per-lane by comparing a sublane iota
 against ``P - 1 - nvoters // 2``.
 
 ``agreed_commit_pallas`` is numerically identical to the ``jnp.sort``
-path used inside ``consensus_step`` (asserted by parity tests, which run
-the kernel in interpret mode on CPU); swap it in with
+path used inside ``consensus_step``: tests/test_pallas_quorum.py checks
+parity in interpret mode on the CPU, tests/test_chip_compile.py compiles
+it with Mosaic for the v5e, and chip_smoke.py runs the compiled kernel
+on the chip against ``agreed_commit_sort``. Swap it in with
 ``ra_tpu.ops.consensus.configure(quorum_backend="pallas")`` before the
 first step. XLA already fuses the sort path well — this
 kernel exists for the configurations where the sort's O(P log P)
@@ -30,30 +32,37 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
 MAX_P = 8
 
 
-def _quorum_kernel(match_ref, voting_ref, nvoters_ref, out_ref):
-    # tile: (MAX_P, LANES) — peers on sublanes, groups on lanes
-    m = jnp.where(voting_ref[...], match_ref[...], -1)
+def _quorum_kernel(match_ref, nvoters_ref, out_ref):
+    # tile: (MAX_P, LANES) — peers on sublanes, groups on lanes; the
+    # wrapper has already folded the voter mask in (non-voters are -1).
+    # Everything stays int32: Mosaic has no vector-register layout for
+    # a rolled i1 mask, so the compare-exchange partners are picked
+    # from the sublane iota instead of from a shifted boolean.
+    m = match_ref[...]
+    rows = jax.lax.broadcasted_iota(jnp.int32, m.shape, 0)
     # odd-even transposition sort along the sublane (peer) axis,
     # ascending: after MAX_P passes every lane is sorted
     for p in range(MAX_P):
         start = p % 2
-        rolled = jnp.roll(m, -1, axis=0)
-        lo = jnp.minimum(m, rolled)
-        hi = jnp.maximum(m, rolled)
-        rows = jax.lax.broadcasted_iota(jnp.int32, m.shape, 0)
-        take_lo = (rows % 2 == start) & (rows < MAX_P - 1)
-        take_hi = jnp.roll(take_lo, 1, axis=0)
-        m = jnp.where(take_lo, lo, jnp.where(take_hi, jnp.roll(hi, 1, axis=0), m))
+        up = pltpu.roll(m, MAX_P - 1, 0)  # row r holds m[r + 1]
+        down = pltpu.roll(m, 1, 0)  # row r holds m[r - 1]
+        is_lo = (rows % 2 == start) & (rows < MAX_P - 1)
+        is_hi = (rows % 2 != start) & (rows >= 1)
+        m = jnp.where(
+            is_lo, jnp.minimum(m, up),
+            jnp.where(is_hi, jnp.maximum(m, down), m),
+        )
     # majority row per lane: ascending position MAX_P - 1 - nvoters // 2
-    rows = jax.lax.broadcasted_iota(jnp.int32, m.shape, 0)
     pos = MAX_P - 1 - nvoters_ref[...] // 2  # (1, LANES) broadcast row
-    sel = rows == pos
-    out_ref[...] = jnp.max(jnp.where(sel, m, -(2 ** 31 - 1)), axis=0, keepdims=True)
+    out_ref[...] = jnp.max(
+        jnp.where(rows == pos, m, -(2 ** 31 - 1)), axis=0, keepdims=True
+    )
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -70,9 +79,7 @@ def agreed_commit_pallas(
     # transpose to (P, G): peers on sublanes, groups on lanes; pad peers
     # with -1 (never selected) and groups to a lane multiple
     mt = jnp.full((MAX_P, gp), -1, jnp.int32)
-    mt = mt.at[:p, :g].set(match.T)
-    vt = jnp.zeros((MAX_P, gp), jnp.bool_)
-    vt = vt.at[:p, :g].set(voting.T)
+    mt = mt.at[:p, :g].set(jnp.where(voting, match, -1).T)
     nv = jnp.zeros((1, gp), jnp.int32).at[0, :g].set(nvoters)
 
     out = pl.pallas_call(
@@ -80,13 +87,12 @@ def agreed_commit_pallas(
         grid=(gp // LANES,),
         in_specs=[
             pl.BlockSpec((MAX_P, LANES), lambda i: (0, i)),
-            pl.BlockSpec((MAX_P, LANES), lambda i: (0, i)),
             pl.BlockSpec((1, LANES), lambda i: (0, i)),
         ],
         out_specs=pl.BlockSpec((1, LANES), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, gp), jnp.int32),
         interpret=interpret,
-    )(mt, vt, nv)
+    )(mt, nv)
     return out[0, :g]
 
 

@@ -99,7 +99,14 @@ class SpscRing:
         return n
 
     def __len__(self) -> int:
-        return int(self._idx[_PAD]) - int(self._idx[0])
+        # head BEFORE tail: any thread may ask (the egress and WAL
+        # threads poll ``pending()``), and both indexes only grow with
+        # tail >= head at every instant — so a tail read after the head
+        # can only over-count. The other order goes negative when the
+        # reader is descheduled between the two loads, and a negative
+        # __len__ raises in the caller's thread.
+        head = int(self._idx[0])
+        return int(self._idx[_PAD]) - head
 
 
 class WaitGate:

@@ -550,6 +550,9 @@ class BatchCoordinator:
         self.registry.register(node_name, self)
         self.steps = 0
         self.sub_steps = 0  # steps taken on the active-set (sub) path
+        # sharded steps that found a state array off the mesh layout and
+        # had to move it back first (0 while the scatters keep it)
+        self.shard_moves = 0
         self.msgs_processed = 0
 
         # pipelined wave loop (docs/INTERNALS.md §15): the threaded run
@@ -581,6 +584,9 @@ class BatchCoordinator:
         self._detector = threading.Thread(
             target=self._detect_loop, name=f"ra-batch-det-{node_name}", daemon=True
         )
+        # detector passes that raised (the loop keeps running; the first
+        # one is logged with its traceback)
+        self.detector_errors = 0
         self._started = False
 
     # -- node-registry interface (same duck type as RaNode) ---------------
@@ -854,6 +860,65 @@ class BatchCoordinator:
             self._started = True
             self._step_thread.start()
             self._detector.start()
+
+    def warm_steps(self) -> int:
+        """Run every step program this coordinator dispatches in steady
+        service once, on a scratch state of its own shape, so that none
+        of them compiles in the middle of traffic: on a started
+        coordinator each new active-set width or election batch size is
+        otherwise a compile under the state lock, with the command
+        watchdog and the election timers running. At 10,240 x 3 on the
+        chip, cold, that doubled the election (12.2 s against 6.2 s)
+        and put 5 compilations into the serving window (19.2 s against
+        13.2 s), though it struck no watchdog (chip_smoke.py, PR 21).
+        Covers the full-width fused step, the active-set step at each
+        power-of-two sub-batch width, the role scatter at each padded
+        batch size and the health scan's copies. Rare paths (snapshot
+        install, forced elections, mixed-term appends) still compile on
+        first use. Returns the number of programs run."""
+        cap = self.capacity
+        scratch = C.make_group_state(
+            cap, self.P, self.state.term_suffix.shape[1]
+        )
+
+        def pads(n):
+            return jnp.full((n,), cap, jnp.int32)  # dropped by scatters
+
+        def mbox(width):
+            packed = np.zeros((self._NROWS, width), np.int32)
+            self._fill_scat(packed, None, None)
+            return jnp.asarray(packed)
+
+        ran = 1
+        if self._shard_state is not None:
+            scratch = jax.device_put(scratch, self._shard_state)
+            scratch, eg = C.consensus_step_packed(
+                scratch, jax.device_put(mbox(cap), self._shard_mbox)
+            )
+        else:
+            scratch, eg = C.consensus_step_packed_scat(scratch, mbox(cap))
+            if self.active_set != "never":
+                most = cap if self.active_set == "always" else cap >> 2
+                width = min(256, cap)
+                while True:
+                    scratch, eg = C.consensus_step_packed_sub_scat(
+                        scratch, mbox(width), pads(width)
+                    )
+                    ran += 1
+                    if width >= most:
+                        break
+                    width <<= 1
+        width = 1
+        while True:
+            scratch = C.set_roles(scratch, pads(width), pads(width))
+            ran += 1
+            if width >= cap:
+                break
+            width <<= 1
+        for a in scratch:
+            jnp.copy(a)
+        np.asarray(eg)  # dispatch is async: wait for the last program
+        return ran
 
     def stop(self) -> None:
         self.running = False
@@ -1831,9 +1896,17 @@ class BatchCoordinator:
                     )
                     self.state = C.record_written(self.state, gids, idxs)
                 packed, consumed, mbox_buf = self._build_mailbox(None, None)
-                # re-pin before the fused step so it executes SPMD over
-                # the mesh (no-op when the layout is already right)
-                self.state = jax.device_put(self.state, self._shard_state)
+                # the jitted scatters keep the mesh layout; an eager
+                # host-side row update (membership, snapshot install)
+                # may hand back another one — move the state back only
+                # then, and count it: a move on every wave would be the
+                # whole state crossing the interconnect per step
+                if not all(
+                    a.sharding.is_equivalent_to(self._shard_state, a.ndim)
+                    for a in self.state
+                ):
+                    self.shard_moves += 1
+                    self.state = jax.device_put(self.state, self._shard_state)
                 packed = jax.device_put(packed, self._shard_mbox)
                 self.state, eg_packed = C.consensus_step_packed(
                     self.state, packed
@@ -4587,8 +4660,12 @@ class BatchCoordinator:
                                 (g.name, self.name), ElectionTimeout(now),
                                 None,
                             )
-            except Exception:  # noqa: BLE001
-                pass
+            except Exception:  # noqa: BLE001 — the detector must keep running
+                self.detector_errors += 1
+                if self.detector_errors == 1:
+                    logger.exception(
+                        "coordinator %s: detector pass failed", self.name
+                    )
             time.sleep(self._detector_poll_s)
 
     def _lane_watchdog(
@@ -4722,6 +4799,7 @@ class BatchCoordinator:
             "groups": self.n_groups,
             "steps": self.steps,
             "sub_steps": self.sub_steps,
+            "shard_moves": self.shard_moves,
             "msgs": self.msgs_processed,
             "commit_rate": self.counters.get("commit_rate"),
             "counters": self.counters.to_dict(),

@@ -153,3 +153,28 @@ def derive_dir(base: str, *parts: str) -> str:
     p = os.path.join(base, *parts)
     os.makedirs(p, exist_ok=True)
     return p
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry-point
+    script (chip_smoke.py, bench.py, profile_wave.py; never the tests)
+    and return its directory. The directory is placed from outside:
+    where ``JAX_COMPILATION_CACHE_DIR`` is set JAX already reads it and
+    no code sets another; otherwise it is the fixed
+    ``<checkout>/.jax_cache`` — the path is part of the cache key, so a
+    temporary name would never hit. The compile-time and entry-size
+    thresholds go to zero: the scatters and the small sub-batch steps
+    compile in under JAX's default one second and would never be
+    stored."""
+    import jax
+
+    directory = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not directory:
+        checkout = os.path.dirname(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        )
+        directory = os.path.join(checkout, ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", directory)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    return directory

@@ -2,11 +2,12 @@
 # Build the native acceleration libraries (docs/INTERNALS.md §18) and
 # verify every entry point loads:
 #
-#   ra_tpu/native/wal_native.so  - WAL batch frame + write + fsync
-#   ra_tpu/native/rt_native.so   - hot-loop runtime: drain-classify,
-#                                  mailbox pack scatter, egress seal
+#   ra_tpu/native/wal_native.<digest>.so  - WAL batch frame + write + fsync
+#   ra_tpu/native/rt_native.<digest>.so   - hot-loop runtime: drain-classify,
+#                                           mailbox pack scatter, egress seal
 #
-# The Python loader builds these lazily on first use; CI/tier-1 runs
+# The Python loader builds these on first use, named by the digest of
+# their source, and this script builds through it; CI/tier-1 runs
 # this FIRST so a broken build fails the job loudly instead of every
 # test silently taking the Python fallback. Exits nonzero when a
 # compiler is present but the build or load fails.
@@ -19,9 +20,6 @@ if ! command -v g++ >/dev/null; then
     exit 0
 fi
 
-g++ -O2 -shared -fPIC -o ra_tpu/native/wal_native.so ra_tpu/native/wal_native.cpp
-g++ -O2 -shared -fPIC -o ra_tpu/native/rt_native.so ra_tpu/native/rt_native.cpp
-
 python - <<'EOF'
 import sys
 from ra_tpu import native
@@ -29,8 +27,8 @@ from ra_tpu import native
 eps = native.entry_points()
 print("native entry points:", eps)
 if not all(eps.values()):
-    print("build_native: built .so files but entry points failed to "
-          "load", file=sys.stderr)
+    print("build_native: g++ is present but entry points failed to "
+          "build or load", file=sys.stderr)
     sys.exit(1)
 EOF
 echo "build_native: OK"

@@ -10,7 +10,6 @@ set -euo pipefail
 cd "$(dirname "$0")/.."
 
 export JAX_PLATFORMS=cpu
-export PYTHONPATH=
 
 echo "== obs smoke: bench + exposition scrape =="
 python scripts/obs_smoke.py "$@"

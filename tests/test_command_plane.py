@@ -104,6 +104,54 @@ def test_ring_capacity_rounds_to_power_of_two():
 # WaitGate
 
 
+def test_ring_length_never_negative_for_a_third_thread():
+    """``len(ring)`` is asked by threads that are neither the producer
+    nor the consumer (the coordinator's egress and WAL threads poll
+    ``pending()``). Descheduled between its two index loads, a reader
+    that loads the tail first sees a head that has overtaken it: a
+    negative ``__len__`` raises ValueError and kills the asking thread
+    (seen on the 13-core chip host, never on the 1-core sandbox)."""
+    import sys
+
+    ring = SpscRing(64)
+    stop = threading.Event()
+    errors = []
+
+    def producer():
+        i = 0
+        while not stop.is_set():
+            i += ring.try_push(i)
+
+    def consumer():
+        out = []
+        while not stop.is_set():
+            ring.pop_many(out)
+            out.clear()
+
+    def observer():
+        try:
+            while not stop.is_set():
+                len(ring)  # ValueError when negative
+        except Exception as e:  # noqa: BLE001 — reported by the assert below
+            errors.append(e)
+
+    prev = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    threads = [threading.Thread(target=f, daemon=True)
+               for f in (producer, consumer, observer, observer, observer)]
+    try:
+        for t in threads:
+            t.start()
+        time.sleep(1.0)
+    finally:
+        stop.set()
+        for t in threads:
+            t.join(timeout=5)
+        sys.setswitchinterval(prev)
+    assert not any(t.is_alive() for t in threads)
+    assert not errors, errors[0]
+
+
 def test_wait_gate_wakes_parked_waiter_once():
     g = WaitGate()
     e = g.waiter()

@@ -538,7 +538,7 @@ def test_rt_lib_vanishing_midflight_counts_fallback(monkeypatch):
 
 def test_build_negative_cache_and_single_warning(tmp_path, monkeypatch,
                                                  capsys):
-    """A failed build is cached per source mtime: no rebuild storm on
+    """A failed build is cached per source digest: no rebuild storm on
     every import, exactly one stderr warning carrying the compiler
     error, and a CHANGED source retries."""
     src = tmp_path / "broken.cpp"
@@ -559,13 +559,51 @@ def test_build_negative_cache_and_single_warning(tmp_path, monkeypatch,
     err = capsys.readouterr().err
     assert err.count("build of broken.cpp failed") == 1
     assert "expected ')'" in err
-    # a changed source invalidates the cached failure
+    # a newer mtime on the same bytes is the same source ...
     st = os.stat(src)
     os.utime(src, (st.st_atime, st.st_mtime + 10))
+    assert native._build(str(src), str(so)) is None
+    assert len(calls) == 1
+    # ... a changed source invalidates the cached failure
+    src.write_text("int main( { //")
     assert native._build(str(src), str(so)) is None
     assert len(calls) == 2
     # ... but warns only once per source
     assert "failed" not in capsys.readouterr().err
+
+
+def test_build_keyed_on_source_content(tmp_path, monkeypatch):
+    """Which library loads is decided by the bytes of the source, not by
+    mtimes: the .so files are git-ignored and travel with a copied tree
+    (the chip tool copies the disk), where an old library can be newer
+    than the source it no longer matches."""
+    src = tmp_path / "lib.cpp"
+    so = tmp_path / "lib.so"
+    src.write_text("// v1")
+    so.write_bytes(b"built from some older source, but newer by mtime")
+    calls = []
+
+    def fake_gxx(cmd, **kw):
+        calls.append(cmd)
+        with open(cmd[cmd.index("-o") + 1], "wb") as f:
+            f.write(b"\x7fELF")
+
+    monkeypatch.setattr(native.subprocess, "run", fake_gxx)
+    v1 = native._build(str(src), str(so))
+    assert len(calls) == 1 and v1 != str(so) and os.path.exists(v1)
+    # same bytes, any mtime: reused without a build
+    os.utime(src, (0, 0))
+    assert native._build(str(src), str(so)) == v1
+    os.utime(src, None)
+    assert native._build(str(src), str(so)) == v1
+    assert len(calls) == 1
+    # new bytes: a new library, and the old one goes
+    src.write_text("// v2")
+    v2 = native._build(str(src), str(so))
+    assert len(calls) == 2 and v2 != v1
+    assert os.path.exists(v2) and not os.path.exists(v1)
+    assert sorted(os.listdir(tmp_path)) == sorted(
+        ["lib.cpp", "lib.so", os.path.basename(v2)])
 
 
 def test_build_missing_compiler_warns_gplusplus(tmp_path, monkeypatch,

@@ -73,7 +73,7 @@ def test_configure_pallas_backend_in_full_step():
 
     ref_st, _ = C.consensus_step(jax.tree.map(jnp.copy, st), mb)
     try:
-        C.configure(quorum_backend="pallas")
+        C.configure(quorum_backend="pallas", pallas_interpret=True)
         pal_st, _ = C.consensus_step(jax.tree.map(jnp.copy, st), mb)
     finally:
         C.configure(quorum_backend="sort")

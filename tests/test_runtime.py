@@ -156,8 +156,15 @@ def test_add_and_remove_member(cluster, tmp_path):
 def test_transfer_leadership(cluster):
     leader = api.wait_for_leader("add")
     target = next(sid for sid in cluster if sid != leader)
+    # transfer_leadership refuses targets that are not provably caught
+    # up (match_index + 1 == next_index), and the chosen follower may
+    # not be in the commit quorum yet — retry until it catches up
+    deadline = time.monotonic() + 5
     out = api.transfer_leadership(cluster[0], target)
-    assert out[0] == "ok"
+    while out[0] != "ok" and time.monotonic() < deadline:
+        time.sleep(0.05)
+        out = api.transfer_leadership(cluster[0], target)
+    assert out[0] == "ok", out
     deadline = time.monotonic() + 3
     while time.monotonic() < deadline:
         if leaderboard.lookup_leader("add") == target:
