@@ -15,7 +15,7 @@ and the WAL rows run on their own threads (concurrent with the loop);
 they are listed for attribution, not added to the share denominator.
 
 Usage: python profile_wave.py [groups] [cmds] [--top N] [--cprofile]
-       [--trace out.json] [--native on|off|both]
+       [--native on|off|both]
 
 Runs on whatever device JAX finds and prints its platform: on the
 machine with the chip that is the TPU; set ``JAX_PLATFORMS=cpu`` from
@@ -26,10 +26,10 @@ control back to back (histograms reset between) and prints both phase
 tables plus the throughput/latency comparison line — the per-round
 verification surface for docs/INTERNALS.md §18.
 
-``--trace out.json`` additionally records every wave phase as a
-timeline span and dumps Chrome/Perfetto trace JSON (load in
-chrome://tracing or ui.perfetto.dev) — the view that shows wave-phase
-OVERLAP, which the share table cannot.
+For the timeline (wave-phase OVERLAP, which the share table cannot
+show) take a profiler trace of the running process with
+``api.profile(path, seconds)``: the program's spans land in it beside
+the device's operations.
 """
 import argparse
 import sys
@@ -149,7 +149,7 @@ def _reset_wave_histograms() -> None:
             h.reset()
 
 
-def main(groups=2048, cmds=24, top=5, cprofile=False, trace=None,
+def main(groups=2048, cmds=24, top=5, cprofile=False,
          pipeline="on", native="on") -> None:
     from ra_tpu.utils.lib import enable_compile_cache
 
@@ -162,13 +162,6 @@ def main(groups=2048, cmds=24, top=5, cprofile=False, trace=None,
     print(f"profile_wave: platform {dev.platform} ({dev.device_kind}, "
           f"{len(jax.devices())} device(s))", file=sys.stderr)
 
-    if trace:
-        # wave-phase timeline spans (Chrome/Perfetto JSON): the view
-        # that shows whether device_step overlaps host_egress — the
-        # verification surface for the step-pipelining refactor
-        from ra_tpu import obs
-
-        obs.trace_buffer().enable()
     # --native both: the A/B attribution pair — the native hot-loop
     # runtime run first, then the Python control, each with its own
     # phase tables (classify_native/pack_native rows appear only in the
@@ -193,13 +186,6 @@ def main(groups=2048, cmds=24, top=5, cprofile=False, trace=None,
         if pr is not None:
             pr.disable()
         dt = time.perf_counter() - t0
-        if trace:
-            from ra_tpu import api
-
-            n_spans = api.dump_trace(trace)
-            print(f"trace: {n_spans} span events -> {trace} "
-                  f"(open in chrome://tracing or ui.perfetto.dev)",
-                  file=sys.stderr)
         print(f"total wall: {dt:.1f}s  result: {out['value']:.0f} cmd/s "
               f"p50={out['p50_ms']}ms p99={out['p99_ms']}ms [{label}]",
               file=sys.stderr)
@@ -234,9 +220,6 @@ if __name__ == "__main__":
     ap.add_argument("--top", type=int, default=5)
     ap.add_argument("--cprofile", action="store_true",
                     help="also run under cProfile (the old default)")
-    ap.add_argument("--trace", metavar="OUT.json", default=None,
-                    help="dump wave-phase spans as Chrome/Perfetto "
-                         "trace JSON to this path")
     ap.add_argument("--pipeline", choices=("on", "off", "threaded"),
                     default="on",
                     help="wave-loop mode (matches bench.py --pipeline); "
@@ -250,4 +233,4 @@ if __name__ == "__main__":
                          "comparison tables")
     args = ap.parse_args(_ARGS)
     main(args.groups, args.cmds, top=args.top, cprofile=args.cprofile,
-         trace=args.trace, pipeline=args.pipeline, native=args.native)
+         pipeline=args.pipeline, native=args.native)

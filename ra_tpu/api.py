@@ -10,11 +10,13 @@ server ids are ``(name, node_name)`` tuples.
 
 from __future__ import annotations
 
+import functools
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 from ra_tpu import leaderboard
+from ra_tpu import obs as _obs
 from ra_tpu.machine import Machine
 from ra_tpu.protocol import Command, ElectionTimeout, RA_JOIN, RA_LEAVE, ServerId, USR
 from ra_tpu.runtime.node import RaNode
@@ -24,11 +26,15 @@ from ra_tpu.utils.lib import partition_parallel
 
 
 class Future:
-    __slots__ = ("_evt", "value")
+    """``t_born`` (``time.monotonic_ns()`` at construction) is where the
+    batch backend's read accounts start (docs/INTERNALS.md §13)."""
+
+    __slots__ = ("_evt", "value", "t_born")
 
     def __init__(self) -> None:
         self._evt = threading.Event()
         self.value: Any = None
+        self.t_born = time.monotonic_ns()
 
     def set_result(self, v: Any) -> None:
         self.value = v
@@ -80,6 +86,23 @@ class RaNoSpace(RaError):
             f"command was not appended — back off and retry"
         )
         self.target = target
+
+
+def _spanned(name: str):
+    """The client call as one span in the profiler's trace, with the
+    addressed server's node as its ``node`` stat."""
+
+    def deco(fn):
+        @functools.wraps(fn)
+        def call(server_id, *args, **kw):
+            if _obs.tracing():
+                with _obs.span(name, node=server_id[1]):
+                    return fn(server_id, *args, **kw)
+            return fn(server_id, *args, **kw)
+
+        return call
+
+    return deco
 
 
 def _node(node_name: str) -> RaNode:
@@ -266,6 +289,7 @@ def _is_running(sid: ServerId) -> bool:
 # commands
 
 
+@_spanned("ra/api/process_command")
 def process_command(
     server_id: ServerId,
     data: Any,
@@ -559,6 +583,7 @@ def leader_query(server_id: ServerId, fn: Callable[[Any], Any], timeout: float =
     )
 
 
+@_spanned("ra/api/consistent_query")
 def consistent_query(
     server_id: ServerId, fn: Callable[[Any], Any], timeout: float = 5.0
 ):
@@ -804,7 +829,6 @@ def system_overview(node_name: str, last_events: int = 100) -> dict:
     flight-recorder events."""
     from ra_tpu import counters as _counters
     from ra_tpu import health as _health
-    from ra_tpu import obs as _obs
 
     return {
         "node": node_name,
@@ -833,7 +857,6 @@ def cluster_health(last_events: int = 0) -> dict:
       events (health transitions line up with elections/WAL failures).
     """
     from ra_tpu import health as _health
-    from ra_tpu import obs as _obs
 
     nodes: Dict[str, dict] = {}
     by_cluster: Dict[str, Dict[str, dict]] = {}
@@ -870,20 +893,25 @@ def cluster_health(last_events: int = 0) -> dict:
     return out
 
 
-def dump_trace(path: str) -> int:
-    """Write the recorded wave-phase spans as Chrome/Perfetto trace
-    JSON (load via chrome://tracing or ui.perfetto.dev). Tracing is off
-    by default: call ``obs.trace_buffer().enable()`` (or run
-    ``profile_wave.py --trace out.json``) first. Returns the number of
-    span events written."""
-    from ra_tpu import obs as _obs
+def profile(path: str, seconds: float) -> str:
+    """Trace this process for ``seconds`` with the JAX profiler and
+    return the ``*.xplane.pb`` it wrote under ``path``: the program's
+    spans (``obs.span``: every wave thread, the WAL writers, the client
+    calls) on plane ``/host:CPU`` above the device's operations, on one
+    clock. Open it in xprof or Perfetto; ``scripts/idle_gaps.py`` puts
+    the device's idle time down to the spans (docs/INTERNALS.md, "Spans
+    in the profiler's trace")."""
+    import jax
 
-    return _obs.trace_buffer().dump(path)
+    jax.profiler.start_trace(path, profiler_options=_obs.profile_options())
+    try:
+        time.sleep(seconds)
+    finally:
+        jax.profiler.stop_trace()
+    return _obs.xplane_path(path)
 
 
 def prometheus_metrics() -> str:
     """Prometheus text exposition of every counter and histogram
     (scrape surface; see scripts/obs_smoke.sh for the CI check)."""
-    from ra_tpu import obs as _obs
-
     return _obs.prometheus_text()

@@ -6,10 +6,13 @@ counter taxonomy (reference: ``src/ra.hrl:266-438``): every server (and the
 WAL / segment writer) registers a fixed-width array of int64 slots, updated
 lock-free on the hot path and readable by observers at any time.
 
-Implementation: one numpy int64 vector per registered object. CPython's
-GIL plus single-writer-per-slot discipline (each slot is only incremented
-from its owner's event loop) makes plain ``arr[i] += n`` safe here; readers
-may see slightly stale values, matching the reference's semantics.
+Implementation: one list of ints per registered object (a list, not a
+numpy vector: ``v[i] += n`` on a list costs a third of what it costs on
+an array, which boxes a scalar each way, and the wave loop increments
+some thirty-five counters a step). CPython's GIL plus
+single-writer-per-slot discipline (each slot is only incremented from its
+owner's event loop) makes the plain ``v[i] += n`` safe here; readers may
+see slightly stale values, matching the reference's semantics.
 """
 
 from __future__ import annotations
@@ -17,7 +20,6 @@ from __future__ import annotations
 import threading
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
-import numpy as np
 
 # (name, kind, help). Kind: "counter" (monotone) or "gauge".
 FieldSpec = Tuple[str, str, str]
@@ -254,6 +256,41 @@ COORDINATOR_FIELDS: List[FieldSpec] = [
      "hot-loop iterations that took the byte-identical Python path "
      "while a native path was switched on (armed failpoints, "
      "out-of-range input, or a load failure after the switch)"),
+    # -- thread-CPU accounts of the wave phases (docs/INTERNALS.md §13):
+    # time.thread_time_ns() at the boundaries the wall phases have, read
+    # on one turn in 16 and booked 16 times (the clock is a system
+    # call); a phase's wall total less its CPU total is what its thread
+    # spent off a core (interpreter lock, state lock, system calls)
+    ("cpu_ns_ingress_drain", "counter",
+     "thread CPU ns inside the wave phase ingress_drain (estimate: one "
+     "turn in 16 is read)"),
+    ("cpu_ns_host_pack", "counter",
+     "thread CPU ns inside the wave phase host_pack (estimate: one "
+     "turn in 16 is read)"),
+    ("cpu_ns_host_egress", "counter",
+     "thread CPU ns inside the wave phase host_egress (estimate: one "
+     "turn in 16 is read)"),
+    ("cpu_ns_aer_fanout", "counter",
+     "thread CPU ns inside the wave phase aer_fanout (dispatch-time "
+     "and commit-driven fan-out, both added when the ticket realises; "
+     "estimate: one turn in 16 is read)"),
+    # -- read accounts (every read; time.monotonic_ns() stamps from the
+    # caller's api.Future birth; docs/INTERNALS.md §13)
+    ("read_registers", "counter",
+     "consistent queries a leader accepted (quorum round started, or "
+     "served under a lease / as the only voter)"),
+    ("read_register_ns", "counter",
+     "future born -> the query's heartbeats queued (or its reply, for "
+     "a lease-served or single-voter read), summed"),
+    ("read_quorum_rounds", "counter",
+     "consistent queries answered after a heartbeat quorum round"),
+    ("read_quorum_ns", "counter",
+     "heartbeats queued -> the quorum's reply issued, summed"),
+    ("state_queries", "counter",
+     "state_query messages answered (kv_get's log fetch, members, "
+     "overview)"),
+    ("state_query_ns", "counter",
+     "future born -> state_query reply issued, summed"),
 ]
 
 # Per-node health-plane vector (name ("health", node_name); written
@@ -395,33 +432,33 @@ SEGMENT_WRITER_FIELDS: List[FieldSpec] = [
 class Counters:
     """A fixed set of int64 slots addressed by field name."""
 
-    __slots__ = ("name", "fields", "_idx", "arr")
+    __slots__ = ("name", "fields", "_idx", "_v")
 
     def __init__(self, name, fields: Sequence[FieldSpec]):
         self.name = name
         self.fields = list(fields)
         self._idx: Dict[str, int] = {f[0]: i for i, f in enumerate(self.fields)}
-        self.arr = np.zeros(len(self.fields), dtype=np.int64)
+        self._v = [0] * len(self.fields)
 
     def incr(self, field: str, n: int = 1) -> None:
-        self.arr[self._idx[field]] += n
+        self._v[self._idx[field]] += n
 
     def put(self, field: str, v: int) -> None:
-        self.arr[self._idx[field]] = v
+        self._v[self._idx[field]] = v
 
     def get(self, field: str) -> int:
-        return int(self.arr[self._idx[field]])
+        return int(self._v[self._idx[field]])
 
     def to_dict(self) -> Dict[str, int]:
-        return {f[0]: int(self.arr[i]) for i, f in enumerate(self.fields)}
+        return {f[0]: int(v) for f, v in zip(self.fields, self._v)}
 
     def describe(self) -> List[Dict[str, object]]:
         """Field metadata + current values: [{name, kind, help, value}]
         — the exposition shape (``overview()`` drops kind/help; scrape
         surfaces need them for TYPE/HELP lines)."""
         return [
-            {"name": f[0], "kind": f[1], "help": f[2], "value": int(self.arr[i])}
-            for i, f in enumerate(self.fields)
+            {"name": f[0], "kind": f[1], "help": f[2], "value": int(v)}
+            for f, v in zip(self.fields, self._v)
         ]
 
 

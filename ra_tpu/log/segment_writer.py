@@ -24,6 +24,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 from ra_tpu import counters as ra_counters
 from ra_tpu import faults
+from ra_tpu import obs as _obs
 from ra_tpu.log.segment import SegmentWriterHandle
 from ra_tpu.protocol import encode_cmd
 from ra_tpu.log.tables import TableRegistry
@@ -56,6 +57,11 @@ class SegmentWriter:
         )
         # failpoint scope label; the owning node sets it to its name
         self.fault_scope: Optional[str] = None
+        # the ``node`` stat of the flush span when no owner has set
+        # ``fault_scope``: every layout the repo builds puts the
+        # segments in ``<node directory>/data``
+        self._dir_node = os.path.basename(
+            os.path.dirname(os.path.normpath(data_dir))) or "segment_writer"
         self._open: Dict[str, SegmentWriterHandle] = {}
         self._cv = threading.Condition()
         self._queue: deque = deque()
@@ -162,7 +168,9 @@ class SegmentWriter:
                 self._inflight = job
             seqs, wal_file, attempt = job
             try:
-                self._flush_job(seqs)
+                with _obs.span("ra/segw/flush", uids=len(seqs),
+                               node=self.fault_scope or self._dir_node):
+                    self._flush_job(seqs)
             except Exception as exc:  # noqa: BLE001
                 # The WAL file is the only durable copy of these entries
                 # until the flush lands in segments: never unlink it on

@@ -38,6 +38,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from ra_tpu import counters as ra_counters
 from ra_tpu import faults
+from ra_tpu import obs as _obs
 from ra_tpu.log.tables import TableRegistry
 from ra_tpu.utils.lib import retry
 from ra_tpu.utils.seq import Seq
@@ -127,8 +128,6 @@ class Wal:
         # fsync-wait and batch-flush histograms (docs/INTERNALS.md §13);
         # keyed by the WAL directory's basename so every WAL in a
         # multi-node process exports its own distribution
-        from ra_tpu import obs as _obs
-
         _norm = os.path.normpath(dir)
         _parent = os.path.basename(os.path.dirname(_norm))
         _scope = (
@@ -136,6 +135,10 @@ class Wal:
             else (os.path.basename(_norm) or "wal")
         )
         self._scope = _scope
+        # the ``node`` stat of this WAL's spans when no owner has set
+        # ``fault_scope``: every layout the repo builds puts the WAL in
+        # ``<node directory>/wal``
+        self._dir_node = _parent or _scope
         # registered vector (scrapeable): the group-commit delay gauge
         # and flush counters ride the same exposition as the histograms
         self.counter = counter or ra_counters.new(
@@ -153,10 +156,6 @@ class Wal:
             help="adaptive group-commit coalescing wait before a flush",
         )
         self._obs_rec = _obs.flight_recorder()
-        # batch flushes land on the wave timeline too (their own lane
-        # per WAL scope) so Perfetto shows fsync work overlapping the
-        # coordinator's device/host phases; one attr check while off
-        self._trace = _obs.trace_buffer()
 
         self._lock = threading.Lock()
         self._cv = threading.Condition(self._lock)
@@ -270,13 +269,7 @@ class Wal:
                 batch = self._take_batch_locked()
             if not batch:
                 return
-            t0 = time.perf_counter_ns()
-            self._write_batch(batch)
-            dt = time.perf_counter_ns() - t0
-            self._h_batch.record(dt)
-            if self._trace.enabled:
-                self._trace.span("wal_batch", f"wal:{self._scope}", t0, dt,
-                                 cat="wal")
+            self._timed_batch(batch)
 
     def close(self) -> None:
         faults.off_arm(self._arm_wake)
@@ -326,14 +319,7 @@ class Wal:
                 batch = self._take_batch_locked()
             if batch:
                 try:
-                    batch = self._coalesce(batch)
-                    t0 = time.perf_counter_ns()
-                    self._write_batch(batch)
-                    dt = time.perf_counter_ns() - t0
-                    self._h_batch.record(dt)
-                    if self._trace.enabled:
-                        self._trace.span("wal_batch", f"wal:{self._scope}",
-                                         t0, dt, cat="wal")
+                    self._timed_batch(self._coalesce(batch))
                 except Exception as exc:  # noqa: BLE001
                     # any unexpected error is a failure episode, same as
                     # a file I/O error: the batch is unacked (servers
@@ -342,6 +328,24 @@ class Wal:
                     # server on the node. BaseExceptions still kill the
                     # thread; the node's infra supervisor revives it.
                     self._fail(exc)
+
+    @property
+    def _span_node(self) -> str:
+        """The ``node`` stat of this WAL's spans."""
+        return self.fault_scope or self._dir_node
+
+    def _timed_batch(self, batch: List[Tuple]) -> None:
+        """``_write_batch`` under its histogram and its span
+        (``ra/wal/batch``; children ``write``, ``fsync``, ``notify``)."""
+        tr = _obs.tracing()
+        t0 = time.perf_counter_ns()
+        if tr:
+            sp = _obs.begin("ra/wal/batch", items=len(batch),
+                            node=self._span_node)
+        self._write_batch(batch)
+        if tr:
+            _obs.end(sp)
+        self._h_batch.record(time.perf_counter_ns() - t0)
 
     def _take_batch_locked(self) -> List[Tuple]:
         batch = []
@@ -385,21 +389,22 @@ class Wal:
         t0 = time.perf_counter_ns()
         deadline = t0 + int(d * 1e9)
         tick = d / 4
-        while True:
-            with self._cv:
-                if self._closed:
+        with _obs.span("ra/wal/hold", node=self._span_node):
+            while True:
+                with self._cv:
+                    if self._closed:
+                        break
+                    if not self._queue:
+                        self._cv.wait(timeout=tick)
+                    got = len(self._queue)
+                    while self._queue and len(batch) < self.max_batch_size:
+                        batch.append(self._queue.popleft())
+                if (
+                    got == 0  # a whole interval brought nothing: burst over
+                    or len(batch) >= self.max_batch_size
+                    or time.perf_counter_ns() >= deadline
+                ):
                     break
-                if not self._queue:
-                    self._cv.wait(timeout=tick)
-                got = len(self._queue)
-                while self._queue and len(batch) < self.max_batch_size:
-                    batch.append(self._queue.popleft())
-            if (
-                got == 0  # a whole interval brought nothing: burst over
-                or len(batch) >= self.max_batch_size
-                or time.perf_counter_ns() >= deadline
-            ):
-                break
         dt = time.perf_counter_ns() - t0
         self._h_flush_wait.record(dt)
         self.counter.incr("group_commit_waits")
@@ -568,6 +573,8 @@ class Wal:
             if info[1]:
                 flush_uid(uid, info)
 
+        tr = _obs.tracing()
+        node = self._span_node
         if records:
             err = None
             n_bytes = None
@@ -589,10 +596,17 @@ class Wal:
                         return  # failed window: batch unacked, drop it
                     try:
                         self._file.flush()
+                        # (on this path ``write`` holds the fdatasync:
+                        # one call, timed inside it for ``_h_fsync``)
+                        if tr:
+                            sp = _obs.begin("ra/wal/batch/write", node=node,
+                                            entries=n_entries)
                         got = native.write_batch(
                             records, self._file.fileno(), self.sync_method,
                             compute_crc=self.compute_checksums,
                         )
+                        if tr:
+                            _obs.end(sp)
                     except (OSError, ValueError) as exc:
                         err = exc
                         got = None
@@ -614,9 +628,12 @@ class Wal:
                     if self._failed:
                         return  # failed window: batch is unacked, drop it
                     try:
-                        faults.checked_write("wal.write", self._file, buf,
-                                             self.fault_scope)
-                        self._sync()
+                        with _obs.span("ra/wal/batch/write", node=node,
+                                       entries=n_entries):
+                            faults.checked_write("wal.write", self._file,
+                                                 buf, self.fault_scope)
+                        with _obs.span("ra/wal/batch/fsync", node=node):
+                            self._sync()
                     except (OSError, ValueError) as exc:
                         err = exc
             if err is not None:
@@ -637,6 +654,8 @@ class Wal:
             self.counter.incr("bytes_written", n_bytes)
             self.counter.put("batch_size", len(batch))
             self._bytes += n_bytes
+        if tr:
+            sp = _obs.begin("ra/wal/batch/notify", node=node)
         if self.notify_many is not None and len(written) > 1:
             # one transport/lock round for the whole batch's written
             # events (a 10k-group batch otherwise pays 10k lock rounds)
@@ -649,6 +668,8 @@ class Wal:
                 self.notify(uid, ("written", term, Seq(pairs)))
         for uid, from_idx in resends:
             self.notify(uid, ("resend_write", from_idx))
+        if tr:
+            _obs.end(sp)
         if self._bytes >= self.max_size_bytes:
             self._rollover()
 

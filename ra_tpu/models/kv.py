@@ -17,6 +17,7 @@ import hashlib
 import pickle
 from typing import Any, Dict, Optional, Tuple
 
+from ra_tpu import obs
 from ra_tpu.effects import ReleaseCursor
 from ra_tpu.machine import Machine
 
@@ -72,6 +73,13 @@ def kv_get(api_mod, member, key, timeout: float = 5.0) -> Optional[Any]:
     state query returns the index and the log read follows). Retries the
     state query when the fetch misses — a concurrent overwrite + snapshot
     may compact the index read in the first round trip."""
+    if obs.tracing():
+        with obs.span("ra/kv/get", node=member[1]):
+            return _kv_get(api_mod, member, key, timeout)
+    return _kv_get(api_mod, member, key, timeout)
+
+
+def _kv_get(api_mod, member, key, timeout):
     for _attempt in range(3):
         out = api_mod.consistent_query(member, lambda st: st.get(key), timeout=timeout)
         if out[0] != "ok" or out[1] is None:

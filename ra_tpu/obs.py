@@ -3,7 +3,7 @@
 The measurement layer for ROADMAP item 2 ("publish a top-5 cost table"):
 before any commit/read optimization ports (arxiv 1905.10786), the wave
 loop and the commit path need per-phase timing and a post-mortem trace.
-Three primitives, all safe on the hot path:
+Four primitives, all safe on the hot path:
 
 - :class:`LogHistogram` — HdrHistogram-style log-bucketed latency
   histogram (power-of-two octaves with linear sub-buckets, int64 numpy
@@ -21,6 +21,11 @@ Three primitives, all safe on the hot path:
   detector, WAL writer, step loop — may record. Reads are best-effort
   snapshots, exactly like counter reads.
 
+- :func:`span` — a host span in the JAX profiler's trace, on the same
+  clock as the device's operations; inert while no profiler session
+  runs. The wave-phase and commit-stage histograms are the accounts
+  that are always on; the spans are their timeline.
+
 - exposition — ``prometheus_text()`` renders every registered counter
   (with the kind/help from its field specs) and histogram (as a summary
   with p50/p90/p99/p99.9 quantiles in seconds) in Prometheus text
@@ -36,6 +41,7 @@ additionally needs distributions (one smoothed gauge cannot answer
 from __future__ import annotations
 
 import itertools
+import os
 import sys
 import threading
 import time
@@ -85,9 +91,14 @@ class LogHistogram:
     plus any coordinator step thread): ``arr[b] += n`` is a
     read-modify-write, so multi-writer updates would lose increments
     and drift ``n``/``total`` from the bucket sums. Recording is
-    sampled on those paths, so the lock is off the per-command cost."""
+    sampled on those paths, so the lock is off the per-command cost.
 
-    __slots__ = ("name", "help", "unit", "arr", "n", "total", "max_v",
+    The buckets are a plain list: ``counts[b] += 1`` on a list costs a
+    third less than on a numpy vector (which boxes a scalar each way),
+    and the wave loop records some twenty values a step. ``arr`` is the
+    int64 vector the readers take, built on demand."""
+
+    __slots__ = ("name", "help", "unit", "_counts", "n", "total", "max_v",
                  "_lock")
 
     def __init__(self, name, help: str = "", unit: str = "ns",
@@ -95,11 +106,16 @@ class LogHistogram:
         self.name = name
         self.help = help
         self.unit = unit
-        self.arr = np.zeros(N_BUCKETS, dtype=np.int64)
+        self._counts = [0] * N_BUCKETS
         self.n = 0
         self.total = 0
         self.max_v = 0
         self._lock = threading.Lock() if locked else None
+
+    @property
+    def arr(self) -> np.ndarray:
+        """The bucket counts as an int64 vector (a copy)."""
+        return np.array(self._counts, dtype=np.int64)
 
     def record(self, v: int, count: int = 1) -> None:
         v = int(v)
@@ -115,13 +131,13 @@ class LogHistogram:
         lock = self._lock
         if lock is not None:
             with lock:
-                self.arr[b] += count
+                self._counts[b] += count
                 self.n += count
                 self.total += v * count
                 if v > self.max_v:
                     self.max_v = v
             return
-        self.arr[b] += count
+        self._counts[b] += count
         self.n += count
         self.total += v * count
         if v > self.max_v:
@@ -138,7 +154,7 @@ class LogHistogram:
         return self.percentiles((p,))[0]
 
     def percentiles(self, ps: Sequence[float]) -> List[int]:
-        counts = self.arr.copy()  # snapshot: writer may race the scan
+        counts = self.arr  # a snapshot: the writer may race the scan
         total = int(counts.sum())
         if total == 0:
             return [0] * len(ps)
@@ -171,20 +187,23 @@ class LogHistogram:
 
     def nonzero_buckets(self) -> List[Tuple[int, int, int]]:
         """(lo, hi, count) for every non-empty bucket (debug/export)."""
-        idx = np.flatnonzero(self.arr)
-        return [(*bucket_bounds(int(b)), int(self.arr[b])) for b in idx]
+        return [(*bucket_bounds(b), c)
+                for b, c in enumerate(list(self._counts)) if c]
 
     def merge(self, other: "LogHistogram") -> None:
         """Fold another histogram's buckets into this one (aggregation
         across nodes/shards; both must use the same unit)."""
-        self.arr += other.arr
+        mine = self._counts
+        for b, c in enumerate(list(other._counts)):
+            if c:
+                mine[b] += c
         self.n += other.n
         self.total += other.total
         if other.max_v > self.max_v:
             self.max_v = other.max_v
 
     def reset(self) -> None:
-        self.arr[:] = 0
+        self._counts[:] = [0] * N_BUCKETS
         self.n = 0
         self.total = 0
         self.max_v = 0
@@ -250,7 +269,10 @@ WAVE_STEP_PHASES = (
     ("ingress_drain", "drain ingress queues + route messages + append "
                       "client commands (includes WAL handoff)"),
     ("host_pack", "apply queued device scatters + pack the mailbox"),
-    ("device_step", "fused consensus step dispatch + egress host sync"),
+    ("device_step", "step dispatched -> egress synced and the state lock "
+                    "held: ticket_queue + egress_sync + egress_lock_wait "
+                    "(overlaps the dispatch-time aer_fanout, which runs "
+                    "inside ticket_queue)"),
     ("host_egress", "realise egress: acks, role changes, apply, replies"),
     ("aer_fanout", "build + send outbound AER batches"),
 )
@@ -263,6 +285,23 @@ WAVE_SUBSET_PHASES = {
                        "samples when the native path is off)",
     "pack_native": "subset of host_pack (GIL-released native mailbox "
                    "scatter; zero samples when the native path is off)",
+    # the three blind phases, split (step thread writes the first four,
+    # the thread that realises tickets the last three)
+    "step_lock_wait": "subset of ingress_drain (classified -> the state "
+                      "lock held)",
+    "scatter_dispatch": "subset of host_pack (queued set_roles / "
+                        "record_appended scatters, staged-run "
+                        "bookkeeping, active-set selection)",
+    "mailbox_build": "subset of host_pack (pack the step's mailbox)",
+    "step_dispatch": "subset of host_pack (the jitted step call: "
+                     "argument transfer + dispatch)",
+    "ticket_queue": "subset of device_step (step dispatched -> the "
+                    "ticket popped for realisation: dispatch-time "
+                    "aer_fanout + the wait in the pipe queue)",
+    "egress_sync": "subset of device_step (np.asarray of the egress: "
+                   "the host's true wait for the device)",
+    "egress_lock_wait": "subset of device_step (egress synced -> the "
+                        "state lock held)",
 }
 WAVE_PHASES = WAVE_STEP_PHASES + tuple(WAVE_SUBSET_PHASES.items())
 
@@ -385,170 +424,103 @@ def record_event(kind: str, node: Optional[str] = None,
 
 
 # ---------------------------------------------------------------------------
-# trace buffer (Chrome/Perfetto trace-event export)
+# spans, in the profiler's trace
 
 
-class TraceBuffer:
-    """Bounded ring of completed phase spans, exported as Chrome trace
-    events (``chrome://tracing`` / Perfetto JSON) so wave-phase overlap
-    is VISIBLE on a timeline — the verification surface the coordinator
-    step-pipelining work (ROADMAP item 2) needs: histograms say how
-    long ``device_step`` takes, the trace shows whether it overlaps
-    ``host_egress`` of the previous step.
+class _NoSpan:
+    """What :func:`span` hands out in a process that has not imported
+    JAX (an actor-only node): no profiler session can run there."""
 
-    Span recording follows the flight-recorder discipline: lock-free
-    appends (atomic slot store + ``itertools.count``), timestamps from
-    ``time.perf_counter_ns()`` (the clock the wave loop already reads),
-    safe from any thread. Disabled by default — the step loop pays one
-    attribute check per step until ``enable()`` (profile_wave --trace,
-    tests, or an operator turning it on live)."""
+    __slots__ = ()
 
-    def __init__(self, capacity: int = 1 << 16):
-        if capacity <= 0:
-            raise ValueError("capacity must be positive")
-        self.capacity = capacity
-        self.enabled = False
-        self._slots: List[Optional[Tuple]] = [None] * capacity
-        self._ctr = itertools.count()
+    def __enter__(self):
+        return self
 
-    def enable(self) -> None:
-        self.enabled = True
-
-    def disable(self) -> None:
-        self.enabled = False
-
-    def clear(self) -> None:
-        self._slots = [None] * self.capacity
-        self._ctr = itertools.count()
-
-    def span(self, name: str, pid: str, ts_ns: int, dur_ns: int,
-             tid: Optional[str] = None, cat: str = "wave") -> None:
-        """Record one completed span (begin at ``ts_ns``, ``dur_ns``
-        long; perf_counter_ns clock). ``pid`` groups lanes per node,
-        ``tid`` is the lane (defaults to the span name)."""
-        n = next(self._ctr)  # atomic in CPython
-        self._slots[n % self.capacity] = (
-            ts_ns, dur_ns, name, pid, tid or name, cat, n
-        )
-
-    def spans(self) -> List[Tuple]:
-        got = [s for s in list(self._slots) if s is not None]
-        got.sort(key=lambda s: (s[0], s[6]))
-        return got
-
-    def to_chrome(self) -> Dict[str, Any]:
-        """Render the ring as a Chrome trace-event document: matched
-        B/E pairs per (pid, tid) lane plus process/thread metadata.
-        Timestamps are microsecond floats relative to the earliest
-        span (the format's expectation)."""
-        spans = self.spans()
-        events: List[Dict[str, Any]] = []
-        pids: Dict[str, int] = {}
-        tids: Dict[Tuple[str, str], int] = {}
-        t0 = spans[0][0] if spans else 0
-        for ts_ns, dur_ns, name, pid_s, tid_s, cat, _n in spans:
-            pid = pids.setdefault(pid_s, len(pids) + 1)
-            tkey = (pid_s, tid_s)
-            if tkey not in tids:
-                tids[tkey] = len(tids) + 1
-            tid = tids[tkey]
-            ts_us = (ts_ns - t0) / 1e3
-            events.append({"name": name, "cat": cat, "ph": "B",
-                           "ts": ts_us, "pid": pid, "tid": tid})
-            events.append({"name": name, "cat": cat, "ph": "E",
-                           "ts": ts_us + max(dur_ns, 0) / 1e3,
-                           "pid": pid, "tid": tid})
-        meta = [
-            {"name": "process_name", "ph": "M", "pid": pid, "tid": 0,
-             "args": {"name": pid_s}}
-            for pid_s, pid in pids.items()
-        ] + [
-            {"name": "thread_name", "ph": "M", "pid": pids[pid_s],
-             "tid": tid, "args": {"name": tid_s}}
-            for (pid_s, tid_s), tid in tids.items()
-        ]
-        return {"traceEvents": meta + events, "displayTimeUnit": "ms"}
-
-    def dump(self, path: str) -> int:
-        """Write the Chrome trace JSON to ``path``; returns the number
-        of span events written (excluding metadata)."""
-        import json
-
-        doc = self.to_chrome()
-        with open(path, "w") as f:
-            json.dump(doc, f)
-        return sum(1 for e in doc["traceEvents"] if e["ph"] != "M")
+    def __exit__(self, *exc):
+        return False
 
 
-_trace = TraceBuffer()
+_NO_SPAN = _NoSpan()
 
 
-def trace_buffer() -> TraceBuffer:
-    return _trace
+def span(name: str, **stats):
+    """A ``jax.profiler.TraceAnnotation``: a host span in the profiler's
+    own trace (plane ``/host:CPU`` of the ``xplane.pb`` that also holds
+    ``/device:TPU:<n>``, on the same clock), with ``stats`` as the
+    event's stats. Inert while no profiler session runs, so there is no
+    switch: ``api.profile`` or any ``jax.profiler.start_trace`` turns
+    every span on.
+
+    Names are ``ra/<thread role>/<what>`` (children add ``/<child>``);
+    every span carries ``node=``, because the trace names every Python
+    thread's line ``python3``. One span per step, batch or client call,
+    never one per group, message or entry inside a wave
+    (docs/INTERNALS.md, "Spans in the profiler's trace").
+
+    Inert is not free: beside the wave loop's real work one annotation
+    built, entered and left costs 2.6 us on the v5e's host (PERF.md
+    section 6, PR 24), so the loops that turn hundreds of times a second
+    ask :func:`tracing` once a turn and :func:`begin` their spans only
+    under it; ``with obs.span(...)`` is for the paths that run a few
+    times a second.
+
+    Call it as ``obs.span(...)``: once JAX is imported the name is
+    rebound to the annotation class itself."""
+    global span
+    if "jax" not in sys.modules:
+        return _NO_SPAN
+    from jax.profiler import TraceAnnotation
+
+    span = TraceAnnotation
+    return TraceAnnotation(name, **stats)
 
 
-def validate_chrome_trace(doc: Any) -> List[str]:
-    """Structural validation of a Chrome trace document (the obs_smoke
-    gate and the tests both run dumped files through this): span events
-    must carry numeric ts/pid/tid, every lane's B/E events must nest
-    and match, and each lane's begin timestamps must be monotone.
-    Returns a list of problems (empty == well-formed)."""
-    errors: List[str] = []
-    if not isinstance(doc, dict) or not isinstance(
-        doc.get("traceEvents"), list
-    ):
-        return ["traceEvents missing or not a list"]
-    lanes: Dict[Tuple, List] = {}
-    for i, e in enumerate(doc["traceEvents"]):
-        ph = e.get("ph")
-        if ph == "M":
-            continue
-        if ph not in ("B", "E", "X", "i", "I"):
-            errors.append(f"event {i}: unknown ph {ph!r}")
-            continue
-        ts = e.get("ts")
-        if not isinstance(ts, (int, float)) or ts != ts or ts < 0:
-            errors.append(f"event {i}: bad ts {ts!r}")
-            continue
-        if not isinstance(e.get("pid"), int) or not isinstance(
-            e.get("tid"), int
-        ):
-            errors.append(f"event {i}: non-int pid/tid")
-            continue
-        lanes.setdefault((e["pid"], e["tid"]), []).append(
-            (ts, ph, e.get("name"), i)
-        )
-    for lane, evts in lanes.items():
-        stack: List[Tuple] = []
-        last_b = -1.0
-        for ts, ph, name, i in evts:  # events are emitted in ts order
-            if ph == "B":
-                if ts < last_b:
-                    errors.append(
-                        f"lane {lane}: non-monotone begin at event {i}"
-                    )
-                last_b = ts
-                stack.append((name, ts))
-            elif ph == "E":
-                if not stack:
-                    errors.append(f"lane {lane}: E without B at event {i}")
-                    continue
-                b_name, b_ts = stack.pop()
-                if name is not None and b_name != name:
-                    errors.append(
-                        f"lane {lane}: mismatched span {b_name!r}/"
-                        f"{name!r} at event {i}"
-                    )
-                if ts < b_ts:
-                    errors.append(
-                        f"lane {lane}: span {name!r} ends before it "
-                        f"begins at event {i}"
-                    )
-        if stack:
-            errors.append(
-                f"lane {lane}: {len(stack)} unmatched B events"
-            )
-    return errors
+def tracing() -> bool:
+    """True while a profiler session records host spans (the
+    profiler's own state, one C call: ``TraceAnnotation.is_enabled``).
+    Call it as ``obs.tracing()``: rebound like :func:`span`."""
+    global tracing
+    if "jax" not in sys.modules:
+        return False
+    from jax.profiler import TraceAnnotation
+
+    tracing = TraceAnnotation.is_enabled
+    return tracing()
+
+
+def begin(name: str, **stats):
+    """Open a :func:`span` without a ``with``: for the hot loops, as
+    ``if tr: sp = obs.begin(...)`` ... ``if tr: obs.end(sp)`` with
+    ``tr = obs.tracing()`` read once a turn. A span left open by an
+    exception ends when its object is collected."""
+    sp = span(name, **stats)
+    sp.__enter__()
+    return sp
+
+
+def end(sp) -> None:
+    sp.__exit__(None, None, None)
+
+
+def profile_options():
+    """The profiler options of ``api.profile`` and of the benchmark's
+    traced runs: host spans (``TraceAnnotation``) and the device's
+    operations, no Python function tracer."""
+    import jax
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    return opts
+
+
+def xplane_path(trace_dir: str) -> str:
+    """The newest ``*.xplane.pb`` the profiler wrote under
+    ``trace_dir``."""
+    import glob
+
+    return sorted(glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb")))[-1]
 
 
 # ---------------------------------------------------------------------------

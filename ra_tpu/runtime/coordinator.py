@@ -56,6 +56,7 @@ from ra_tpu import effects as fx
 from ra_tpu import faults
 from ra_tpu import leaderboard
 from ra_tpu import native as _native
+from ra_tpu import obs as _obs
 from ra_tpu.log.api import LogApi
 from ra_tpu.log.memory import MemoryLog
 from ra_tpu.machine import Machine, normalize_apply_result
@@ -280,8 +281,41 @@ class GroupHost:
         return None
 
 
+class _SpanLock:
+    """The coordinator's state lock as a wave thread takes it: the wait
+    is a span in the profiler's trace, and ``t_held`` is the
+    ``perf_counter_ns`` at which the lock was got (the end of the wave
+    sub-phase ``step_lock_wait``)."""
+
+    __slots__ = ("_lock", "_span", "_node", "t_held")
+
+    def __init__(self, lock, span_name: str, node: str):
+        self._lock = lock
+        self._span = span_name
+        self._node = node
+        self.t_held = 0
+
+    def __enter__(self):
+        if _obs.tracing():
+            with _obs.span(self._span, node=self._node):
+                self._lock.acquire()
+        else:
+            self._lock.acquire()
+        self.t_held = time.perf_counter_ns()
+
+    def __exit__(self, *exc):
+        self._lock.release()
+
+
 class BatchCoordinator:
     """Hosts up to ``capacity`` groups on one node, device-stepped."""
+
+    # the thread-CPU accounts (counters ``cpu_ns_*``) read
+    # ``time.thread_time_ns()`` on one turn in 2**_CPU_SAMPLE_SHIFT and
+    # book that turn's readings times as much: the clock is a system
+    # call, 16 us a read beside the wave loop's work on the v5e's host
+    # (PERF.md section 6, PR 24)
+    _CPU_SAMPLE_SHIFT = 4
 
     def __init__(
         self,
@@ -339,7 +373,6 @@ class BatchCoordinator:
         self.command_deadline_s = command_deadline_s
         from ra_tpu import counters as _counters
         from ra_tpu import health as _health
-        from ra_tpu import obs as _obs
         from ra_tpu.li import LeakyIntegrator
 
         self.counters = _counters.new(
@@ -351,10 +384,6 @@ class BatchCoordinator:
         self._wave_h = _obs.wave_hists(node_name)
         self._commit_h = _obs.commit_hists(node_name)
         self._obs_rec = _obs.flight_recorder()
-        # wave-phase trace spans land here when tracing is enabled
-        # (profile_wave --trace / api.dump_trace); one attribute check
-        # per step while disabled
-        self._trace = _obs.trace_buffer()
         # per-group health scanner (docs/INTERNALS.md §14): fed once
         # per tick from the detector thread with ONE device fetch over
         # the existing mirrors — never from the step loop
@@ -543,6 +572,14 @@ class BatchCoordinator:
         # guards self.state (donated buffers!) between the step thread and
         # add_group callers
         self._state_lock = threading.Lock()
+        # the same lock as the wave threads take it: the wait is a span
+        self._step_lock = _SpanLock(
+            self._state_lock, "ra/step/lock_wait", node_name)
+        self._egress_lock = _SpanLock(
+            self._state_lock, "ra/egress/lock_wait", node_name)
+        # turns of the step loop, and which of them read the thread clock
+        self._cpu_turn = 0
+        self._cpu_mask = (1 << self._CPU_SAMPLE_SHIFT) - 1
 
         self.registry = nodes or node_registry()
         self.transport = InProcTransport(node_name, self.registry)
@@ -1143,7 +1180,12 @@ class BatchCoordinator:
         wake.clear()
         if self._have_work() or not self.running:
             return
+        tr = _obs.tracing()
+        if tr:
+            sp = _obs.begin("ra/step/idle", node=self.name)
         wake.wait()
+        if tr:
+            _obs.end(sp)
         self.counters.incr("step_wakeups")
         if self.running and not self._have_work():
             self.counters.incr("step_spurious_wakeups")
@@ -1199,10 +1241,8 @@ class BatchCoordinator:
             # the WAL writer's wal_notify_many must never wait behind
             # the O(items) classification of a deep burst
             pre = self._drain_classify()
-            with self._state_lock:
-                ticket = self._drain_and_dispatch(
-                    dispatch=not inflight, pre=pre
-                )
+            with self._step_lock:
+                ticket = self._drain_and_dispatch(pre, dispatch=not inflight)
             if inflight:
                 # host staging done while the previous step's device
                 # compute / egress realisation / WAL handoff were in
@@ -1248,18 +1288,20 @@ class BatchCoordinator:
         cv = self._pipe_cv
         while True:
             with cv:
-                while not self._pipe_q and self.running:
-                    cv.wait()
+                if not self._pipe_q and self.running:
+                    tr = _obs.tracing()
+                    if tr:
+                        sp = _obs.begin("ra/egress/wait", node=self.name)
+                    while not self._pipe_q and self.running:
+                        cv.wait()
+                    if tr:
+                        _obs.end(sp)
                 if not self._pipe_q:
                     return  # stopped and drained
                 ticket = self._pipe_q.popleft()
-            eg_np = None
-            if ticket.eg_packed is not None:
-                # device sync OUTSIDE every lock: the step thread stages
-                # and dispatches the next step during this wait
-                eg_np = np.asarray(ticket.eg_packed)
-            with self._state_lock:
-                self._finish_ticket(ticket, eg_np)
+            # device sync OUTSIDE every lock: the step thread stages
+            # and dispatches the next step during this wait
+            self._realise(ticket, self._egress_lock)
             with cv:
                 self._pipe_inflight -= 1
                 cv.notify_all()
@@ -1304,6 +1346,10 @@ class BatchCoordinator:
                 wake.wait()
                 continue
             msgs_n = 0
+            tr = _obs.tracing()
+            if tr:
+                sp = _obs.begin("ra/send/batch", node=self.name,
+                                msgs=sum(len(msgs) for _n, msgs in out))
             for node_name, msgs in out:
                 try:
                     self._send_batch_inline(node_name, msgs)
@@ -1313,6 +1359,8 @@ class BatchCoordinator:
                         self.name, node_name,
                     )
                 msgs_n += len(msgs)
+            if tr:
+                _obs.end(sp)
             self.counters.incr("egress_thread_batches", n)
             self.counters.incr("egress_thread_msgs", msgs_n)
             out.clear()
@@ -1346,29 +1394,21 @@ class BatchCoordinator:
 
     def _step_once_inner(self) -> bool:
         pre = self._drain_classify()  # heavy half, off the state lock
-        with self._state_lock:
+        with self._step_lock:
             prev = self._coop_ticket
             if prev is not None:
                 # flush a leftover pipelined-driver ticket first so
                 # realisation order is preserved across driver modes
                 self._coop_ticket = None
-                eg_np = (
-                    np.asarray(prev.eg_packed)
-                    if prev.eg_packed is not None else None
-                )
-                self._finish_ticket(prev, eg_np)
+                self._realise(prev)
                 # the pre-drained items are NOT lost: hand them to the
                 # dispatch pass the driver's next call runs
-                self._drain_and_dispatch(dispatch=False, pre=pre)
+                self._drain_and_dispatch(pre, dispatch=False)
                 return True
-            ticket = self._drain_and_dispatch(pre=pre)
+            ticket = self._drain_and_dispatch(pre)
             if ticket is None:
                 return False
-            eg_np = (
-                np.asarray(ticket.eg_packed)
-                if ticket.eg_packed is not None else None
-            )
-            self._finish_ticket(ticket, eg_np)
+            self._realise(ticket)
             return True
 
     def step_stage(self) -> bool:
@@ -1387,17 +1427,13 @@ class BatchCoordinator:
 
     def _step_stage_inner(self) -> bool:
         pre = self._drain_classify()  # heavy half, off the state lock
-        with self._state_lock:
+        with self._step_lock:
             prev = self._coop_ticket
             if prev is not None:
                 # driver skipped a finish: realise in order first
                 self._coop_ticket = None
-                eg_np = (
-                    np.asarray(prev.eg_packed)
-                    if prev.eg_packed is not None else None
-                )
-                self._finish_ticket(prev, eg_np)
-            ticket = self._drain_and_dispatch(pre=pre)
+                self._realise(prev)
+            ticket = self._drain_and_dispatch(pre)
             self._coop_ticket = ticket
             return ticket is not None
 
@@ -1412,17 +1448,13 @@ class BatchCoordinator:
             self._coop_done(token)
 
     def _step_finish_inner(self) -> bool:
-        with self._state_lock:
+        with self._egress_lock:
             ticket = self._coop_ticket
             if ticket is None:
                 return False
             self._coop_ticket = None
             t0 = time.perf_counter_ns()
-            eg_np = (
-                np.asarray(ticket.eg_packed)
-                if ticket.eg_packed is not None else None
-            )
-            self._finish_ticket(ticket, eg_np)
+            self._realise(ticket)
             if ticket.stepped:
                 self.counters.incr("pipeline_steps")
                 # host work done between device dispatch and egress
@@ -1453,17 +1485,13 @@ class BatchCoordinator:
 
     def _step_pipelined_inner(self) -> bool:
         pre = self._drain_classify()  # heavy half, off the state lock
-        with self._state_lock:
+        with self._step_lock:
             prev = self._coop_ticket
             self._coop_ticket = None
             if prev is not None:
-                eg_np = (
-                    np.asarray(prev.eg_packed)
-                    if prev.eg_packed is not None else None
-                )
-                self._finish_ticket(prev, eg_np)
+                self._realise(prev)
             t0 = time.perf_counter_ns()
-            ticket = self._drain_and_dispatch(pre=pre)
+            ticket = self._drain_and_dispatch(pre)
             self._coop_ticket = ticket
             if ticket is not None and prev is not None:
                 # staged+dispatched in the same round a previous step
@@ -1475,13 +1503,39 @@ class BatchCoordinator:
                 self.counters.incr("pipeline_steps")
             return ticket is not None or prev is not None
 
+    def _realise(self, ticket, lock=None) -> None:
+        """Realise one dispatched step: sync its egress off the device
+        (``np.asarray``: the host's one true wait for the device), then
+        finish it under the state lock. The egress thread passes its
+        lock and syncs outside it; a cooperative driver holds the lock
+        already."""
+        tr = _obs.tracing()
+        t_pop = time.perf_counter_ns()
+        eg_np = None
+        if ticket.eg_packed is not None:
+            if tr:
+                sp = _obs.begin("ra/egress/sync", node=self.name)
+            eg_np = np.asarray(ticket.eg_packed)
+            if tr:
+                _obs.end(sp)
+        t_sync = time.perf_counter_ns()
+        if lock is None:
+            self._finish_ticket(ticket, eg_np, t_pop, t_sync, tr)
+        else:
+            with lock:
+                self._finish_ticket(ticket, eg_np, t_pop, t_sync, tr)
+
     class _StepTicket:
         """One dispatched-but-unrealised step: the device egress handle
         plus everything realisation needs (who was consumed, the
-        position->gid map, rares, and the staging timestamps)."""
+        position->gid map, rares, the staging timestamps, and the wall
+        and thread-CPU ns of the dispatch-time AER fan-out, which the
+        realising thread books so that each account keeps one writer).
+        ``cpu``: this turn is one whose thread-CPU time is read."""
 
         __slots__ = ("eg_packed", "consumed", "act", "aer_dirty", "rare",
-                     "mbox_buf", "t_in", "t_drain", "t_pack", "stepped")
+                     "mbox_buf", "t_in", "t_drain", "t_pack", "stepped",
+                     "aer0_ns", "aer0_cpu_ns", "cpu")
 
         def __init__(self, **kw):
             for k in self.__slots__:
@@ -1501,9 +1555,27 @@ class BatchCoordinator:
         Only ``by_name`` reads happen here (GIL-safe dict reads; a
         concurrently added group at worst misses one pass, the same
         contract ``deliver`` already has). Returns the pre-drain
-        ``(t_in, n_items, cmd_q, routes, lows)`` consumed by
-        ``_drain_and_dispatch`` under the lock."""
+        ``(stamps, n_items, cmd_q, routes, lows)`` consumed by
+        ``_drain_and_dispatch`` under the lock; ``stamps`` is ``(tr,
+        t_in, cpu_in, t_classified)``: whether a profiler session takes
+        this turn's spans, where the wave phase ``ingress_drain`` and
+        its thread-CPU account start (``cpu_in`` is None on the turns
+        whose CPU time is not read), and where the sub-phase
+        ``step_lock_wait`` starts."""
+        tr = _obs.tracing()
         _t_in = time.perf_counter_ns()
+        self._cpu_turn = turn = self._cpu_turn + 1
+        _c_in = None if turn & self._cpu_mask else time.thread_time_ns()
+        if tr:
+            sp = _obs.begin("ra/step/classify", node=self.name)
+        got = self._classify()
+        if tr:
+            _obs.end(sp)
+        return ((tr, _t_in, _c_in, time.perf_counter_ns()),) + got
+
+    def _classify(self):
+        """``_drain_classify``'s work: ``(n_items, cmd_q, routes,
+        lows)``."""
         buf = self._drain_buf
         # native classify (docs/INTERNALS.md §18): drain the RC_* code
         # sidecar alongside the items and let rt_classify partition the
@@ -1549,7 +1621,7 @@ class BatchCoordinator:
                     self.counters.incr("ingress_ring_msgs", n_items)
                     self.counters.incr("ingress_ring_drains")
                     self._ring_gate.open()
-                    return (_t_in, n_items, cmd_q, routes, lows)
+                    return (n_items, cmd_q, routes, lows)
                 self.counters.incr("native_fallbacks")
             radd = routes.append
             by = self.by_name
@@ -1614,7 +1686,7 @@ class BatchCoordinator:
             self.counters.incr("ingress_ring_drains")
             # space was freed on every lane: wake ring-full waiters
             self._ring_gate.open()
-        return (_t_in, n_items, cmd_q, routes, lows)
+        return (n_items, cmd_q, routes, lows)
 
     def _route_classified(self, buf, part, cmd_q, routes, lows) -> None:
         """Python routing half of the native drain-classify: walk the
@@ -1694,16 +1766,11 @@ class BatchCoordinator:
                 elif name in by:
                     radd(trip)
 
-    def _drain_and_dispatch(
-        self, dispatch: bool = True, pre=None
-    ) -> Optional["BatchCoordinator._StepTicket"]:
-        # caller holds the state lock; ``pre`` is _drain_classify()'s
-        # output taken BEFORE the lock (drivers pre-classify so the
-        # heavy classification never blocks the WAL writer). A None pre
-        # classifies inline (tests / direct step calls).
-        if pre is None:
-            pre = self._drain_classify()
-        _t_in, n_items, cmd_q, routes, lows = pre
+    def _ingest(self, n_items, cmd_q, routes, lows):
+        """Route one classified burst under the state lock: messages to
+        their handlers, commands into the logs and the WAL queue, the
+        appended runs and durable watermarks into the staged scatter
+        dicts. Returns ``(n_items, rare, aer_dirty)``."""
         # fold the step/egress threads' own must-deliver self-publishes
         # (machine Append/Aux effects realized under the state lock —
         # including by the prev-ticket finish that just ran): they are
@@ -1786,7 +1853,28 @@ class BatchCoordinator:
                     self._handle_commands(g, cmds, appended, written, aer_dirty)
         if self._low_dirty:
             self._drain_low_lane(appended, written, aer_dirty)
+        return n_items, rare, aer_dirty
 
+    def _drain_and_dispatch(
+        self, pre, dispatch: bool = True
+    ) -> Optional["BatchCoordinator._StepTicket"]:
+        # caller holds the state lock (taken through ``_step_lock``);
+        # ``pre`` is _drain_classify()'s output taken BEFORE the lock
+        # (drivers pre-classify so the heavy classification never
+        # blocks the WAL writer)
+        (tr, _t_in, _c_in, _t_cls), n_items, cmd_q, routes, lows = pre
+        node = self.name
+        wh = self._wave_h
+        cnt = self.counters
+        cpu = _c_in is not None  # a turn whose thread-CPU time is read
+        shift = self._CPU_SAMPLE_SHIFT
+        if tr:
+            sp = _obs.begin("ra/step/ingress_drain", node=node)
+        n_items, rare, aer_dirty = self._ingest(n_items, cmd_q, routes, lows)
+        if tr:
+            _obs.end(sp)
+        appended = self._staged_app
+        written = self._staged_written
         if not dispatch:
             # ingest-only pass (a ticket is still being realised): the
             # drained work is already folded into the staged scatter
@@ -1798,22 +1886,142 @@ class BatchCoordinator:
             if aer_dirty:
                 # replication fan-out never waits for the next dispatch:
                 # fresh appends ship while the in-flight step realises
+                if tr:
+                    sp = _obs.begin("ra/step/aer_fanout", node=node)
                 self._send_aers(aer_dirty)
+                if tr:
+                    _obs.end(sp)
             if n_items:
-                self.counters.incr("staging_passes")
-                _t_drain = time.perf_counter_ns()
-                self._wave_h["ingress_drain"].record(_t_drain - _t_in)
-                if self._trace.enabled:
-                    self._trace.span("ingress_drain", self.name, _t_in,
-                                     _t_drain - _t_in)
+                cnt.incr("staging_passes")
+                if cpu:
+                    cnt.incr("cpu_ns_ingress_drain",
+                             (time.thread_time_ns() - _c_in) << shift)
+                wh["ingress_drain"].record(time.perf_counter_ns() - _t_in)
+                wh["step_lock_wait"].record(self._step_lock.t_held - _t_cls)
             return None
         if not (
             n_items or self._hot or rare or appended or written
             or self._pending_roles
         ):
             return None
+        if cpu:
+            _c_drain = time.thread_time_ns()
         _t_drain = time.perf_counter_ns()
 
+        stepped = False
+        eg_packed = consumed = act_np = mbox_buf = None
+        if tr:
+            sp_pack = _obs.begin("ra/step/host_pack", node=node)
+            sp = _obs.begin("ra/step/host_pack/scatter_dispatch", node=node)
+        app_rows, act = self._scatter_staged(appended, written)
+        if tr:
+            _obs.end(sp)
+        _t_scat = time.perf_counter_ns()
+        if act is None or act:
+            if tr:
+                sp = _obs.begin("ra/step/host_pack/mailbox_build", node=node)
+            if act is not None:
+                variant = "sub_scat"
+                packed, gidx, act_np, consumed, mbox_buf = (
+                    self._build_mailbox_sub(act, app_rows, written)
+                )
+            elif self._shard_state is not None:
+                # the log-tail scatters went as separate calls
+                # (_scatter_staged)
+                variant = "packed"
+                packed, consumed, mbox_buf = self._build_mailbox(None, None)
+            else:
+                variant = "scat"
+                packed, consumed, mbox_buf = self._build_mailbox(
+                    app_rows, written)
+            _t_build = time.perf_counter_ns()
+            if tr:
+                _obs.end(sp)
+                sp = _obs.begin("ra/step/host_pack/step_dispatch", node=node,
+                                width=int(packed.shape[1]), variant=variant)
+            if variant == "sub_scat":
+                self.state, eg_packed = C.consensus_step_packed_sub_scat(
+                    self.state, packed, gidx
+                )
+                self.sub_steps += 1
+            elif variant == "packed":
+                # the jitted scatters keep the mesh layout; an eager
+                # host-side row update (membership, snapshot install)
+                # may hand back another one — move the state back only
+                # then, and count it: a move on every wave would be the
+                # whole state crossing the interconnect per step
+                if not all(
+                    a.sharding.is_equivalent_to(self._shard_state, a.ndim)
+                    for a in self.state
+                ):
+                    self.shard_moves += 1
+                    self.state = jax.device_put(self.state, self._shard_state)
+                packed = jax.device_put(packed, self._shard_mbox)
+                self.state, eg_packed = C.consensus_step_packed(
+                    self.state, packed
+                )
+            else:
+                self.state, eg_packed = C.consensus_step_packed_scat(
+                    self.state, packed
+                )
+            if tr:
+                _obs.end(sp)
+            stepped = True
+            self.steps += 1
+            self.msgs_processed += len(consumed)
+        if tr:
+            _obs.end(sp_pack)
+        # full-width steps are the shape worth pre-zeroing a spare
+        # mailbox for during the next overlap window (sub-batch buffers
+        # are tiny; zeroing them inline is already free)
+        self._prezero_useful = stepped and act is None
+        if cpu:
+            _c_pack = time.thread_time_ns()
+        _t_pack = time.perf_counter_ns()
+        # dispatch is ASYNC: eg_packed is an in-flight device value; the
+        # ticket's realisation half syncs it (np.asarray) and processes
+        # the egress. The sequential step_once realises inline.
+        # Drain-produced AERs (fresh appends, ack-driven next_index
+        # moves) leave NOW, overlapping the device compute — holding
+        # them for realisation would delay the replication fan-out by a
+        # whole pipeline slot. Egress-produced AERs (commit advances)
+        # ride the ticket.
+        aer0_ns = aer0_cpu_ns = None
+        if aer_dirty:
+            if tr:
+                sp = _obs.begin("ra/step/aer_fanout", node=node)
+            self._send_aers(aer_dirty)
+            if tr:
+                _obs.end(sp)
+            aer_dirty = set()
+            if cpu:
+                aer0_cpu_ns = time.thread_time_ns() - _c_pack
+            aer0_ns = time.perf_counter_ns() - _t_pack
+        wh["ingress_drain"].record(_t_drain - _t_in)
+        wh["step_lock_wait"].record(self._step_lock.t_held - _t_cls)
+        if stepped:
+            wh["host_pack"].record(_t_pack - _t_drain)
+            wh["scatter_dispatch"].record(_t_scat - _t_drain)
+            wh["mailbox_build"].record(_t_build - _t_scat)
+            wh["step_dispatch"].record(_t_pack - _t_build)
+        if cpu:
+            cnt.incr("cpu_ns_ingress_drain", (_c_drain - _c_in) << shift)
+            if stepped:
+                cnt.incr("cpu_ns_host_pack", (_c_pack - _c_drain) << shift)
+        return self._StepTicket(
+            eg_packed=eg_packed, consumed=consumed, act=act_np,
+            aer_dirty=aer_dirty, rare=rare, mbox_buf=mbox_buf, t_in=_t_in,
+            t_drain=_t_drain, t_pack=_t_pack, stepped=stepped,
+            aer0_ns=aer0_ns, aer0_cpu_ns=aer0_cpu_ns, cpu=cpu,
+        )
+
+    def _scatter_staged(self, appended, written):
+        """The head of ``host_pack``: apply the queued role scatter,
+        detach the staged runs and watermarks, send what the mailbox
+        cannot carry as scatters of its own, and choose the step's
+        path. Returns ``(app_rows, act)``: the newest appended run per
+        group for the mailbox, and the sorted active set (None for a
+        full-width step)."""
         if self._pending_roles:
             gids, roles, _ = self._pad3(
                 [(gid, role, 0) for gid, role in self._pending_roles]
@@ -1861,119 +2069,101 @@ class BatchCoordinator:
         # packed mailbox itself (C.MBOX_SCAT_FIELDS rows) and apply
         # inside the fused step — one transfer + one dispatch per step.
         act: Optional[list] = None
-        if self._shard_state is None and self.active_set != "never":
-            cand = self._hot | appended.keys() | written.keys()
-            if self.active_set == "always" or len(cand) <= (self.capacity >> 2):
-                act = sorted(cand)
-        eg_packed = consumed = act_np = mbox_buf = None
-        stepped = False
-        if act is not None:
-            if act:
-                packed, gidx, act_np, consumed, mbox_buf = (
-                    self._build_mailbox_sub(act, app_rows, written)
-                )
-                self.state, eg_packed = C.consensus_step_packed_sub_scat(
-                    self.state, packed, gidx
-                )
-                stepped = True
-                self.steps += 1
-                self.sub_steps += 1
-                self.msgs_processed += len(consumed)
+        if self._shard_state is None:
+            if self.active_set != "never":
+                cand = self._hot | appended.keys() | written.keys()
+                if (self.active_set == "always"
+                        or len(cand) <= (self.capacity >> 2)):
+                    act = sorted(cand)
         else:
-            shard = self._shard_state is not None
-            if shard:
-                # sharded state: the mailbox shards column-wise, which
-                # would split scatter rows across devices — apply the
-                # log-tail scatters as separate (replicated-index) calls
-                if app_rows:
-                    gids, los, his, terms = self._pad4(app_rows)
-                    self.state = C.record_appended_runs(
-                        self.state, gids, los, his, terms
-                    )
-                if written:
-                    gids, idxs, _ = self._pad3(
-                        [(g, i, 0) for g, i in written.items()]
-                    )
-                    self.state = C.record_written(self.state, gids, idxs)
-                packed, consumed, mbox_buf = self._build_mailbox(None, None)
-                # the jitted scatters keep the mesh layout; an eager
-                # host-side row update (membership, snapshot install)
-                # may hand back another one — move the state back only
-                # then, and count it: a move on every wave would be the
-                # whole state crossing the interconnect per step
-                if not all(
-                    a.sharding.is_equivalent_to(self._shard_state, a.ndim)
-                    for a in self.state
-                ):
-                    self.shard_moves += 1
-                    self.state = jax.device_put(self.state, self._shard_state)
-                packed = jax.device_put(packed, self._shard_mbox)
-                self.state, eg_packed = C.consensus_step_packed(
-                    self.state, packed
+            # sharded state: the mailbox shards column-wise, which
+            # would split scatter rows across devices — apply the
+            # log-tail scatters as separate (replicated-index) calls
+            if app_rows:
+                gids, los, his, terms = self._pad4(app_rows)
+                self.state = C.record_appended_runs(
+                    self.state, gids, los, his, terms
                 )
-            else:
-                packed, consumed, mbox_buf = self._build_mailbox(
-                    app_rows, written
+            if written:
+                gids, idxs, _ = self._pad3(
+                    [(g, i, 0) for g, i in written.items()]
                 )
-                self.state, eg_packed = C.consensus_step_packed_scat(
-                    self.state, packed
-                )
-            stepped = True
-            self.steps += 1
-            self.msgs_processed += len(consumed)
-        # full-width steps are the shape worth pre-zeroing a spare
-        # mailbox for during the next overlap window (sub-batch buffers
-        # are tiny; zeroing them inline is already free)
-        self._prezero_useful = stepped and act is None
-        _t_pack = time.perf_counter_ns()
-        # dispatch is ASYNC: eg_packed is an in-flight device value; the
-        # ticket's realisation half syncs it (np.asarray) and processes
-        # the egress. The sequential step_once realises inline.
-        # Drain-produced AERs (fresh appends, ack-driven next_index
-        # moves) leave NOW, overlapping the device compute — holding
-        # them for realisation would delay the replication fan-out by a
-        # whole pipeline slot. Egress-produced AERs (commit advances)
-        # ride the ticket.
-        sent_aers = bool(aer_dirty)
-        if sent_aers:
-            self._send_aers(aer_dirty)
-            aer_dirty = set()
-        _t_aer0 = time.perf_counter_ns()
-        wh = self._wave_h
-        wh["ingress_drain"].record(_t_drain - _t_in)
-        if stepped:
-            wh["host_pack"].record(_t_pack - _t_drain)
-        if sent_aers:
-            wh["aer_fanout"].record(_t_aer0 - _t_pack)
-        tb = self._trace
-        if tb.enabled:
-            node = self.name
-            tb.span("ingress_drain", node, _t_in, _t_drain - _t_in)
-            if stepped:
-                tb.span("host_pack", node, _t_drain, _t_pack - _t_drain)
-            if sent_aers:
-                tb.span("aer_fanout", node, _t_pack, _t_aer0 - _t_pack)
-        return self._StepTicket(
-            eg_packed=eg_packed if stepped else None,
-            consumed=consumed, act=act_np, aer_dirty=aer_dirty, rare=rare,
-            mbox_buf=mbox_buf, t_in=_t_in, t_drain=_t_drain, t_pack=_t_pack,
-            stepped=stepped,
-        )
+                self.state = C.record_written(self.state, gids, idxs)
+        return app_rows, act
 
-    def _finish_ticket(self, ticket, eg_np: Optional[np.ndarray]) -> None:
+    def _finish_ticket(self, ticket, eg_np: Optional[np.ndarray],
+                       t_pop: int, t_sync: int, tr: bool) -> None:
         """Realise one dispatched step: process the synced egress, run
         the rare paths, fan out AERs (caller holds the state lock and
-        has already synced ``eg_np`` — ideally outside the lock)."""
+        has already synced ``eg_np`` — ideally outside the lock).
+        ``t_pop`` / ``t_sync``: when ``_realise`` took the ticket up and
+        when its sync ended (``perf_counter_ns``); ``tr``: a profiler
+        session takes the spans."""
+        node = self.name
         aer_dirty = ticket.aer_dirty
-        _t_dev = None
+        cpu = ticket.cpu
+        _t_dev = time.perf_counter_ns()
+        if cpu:
+            _c_dev = time.thread_time_ns()
         if eg_np is not None:
-            _t_dev = time.perf_counter_ns()
+            if tr:
+                sp = _obs.begin("ra/egress/host_egress", node=node)
             # egress is host-synced: the device has fully consumed the
             # mailbox view, so the pack buffer may be reused
             self._mbox_release(ticket.mbox_buf)
             eg = {name: eg_np[i] for i, name in enumerate(C.EGRESS_FIELDS)}
             self._process_egress(eg, ticket.consumed, aer_dirty,
                                  act=ticket.act)
+            if tr:
+                _obs.end(sp)
+        if ticket.rare:
+            if tr:
+                sp = _obs.begin("ra/egress/rare", node=node)
+            self._handle_rares(ticket.rare)
+            if tr:
+                _obs.end(sp)
+        if cpu:
+            _c_eg = time.thread_time_ns()
+        _t_eg = time.perf_counter_ns()
+        if tr:
+            sp = _obs.begin("ra/egress/aer_fanout", node=node)
+        self._send_aers(aer_dirty)
+        if tr:
+            _obs.end(sp)
+        if cpu:
+            cpu_aer = time.thread_time_ns() - _c_eg
+        _t_aer = time.perf_counter_ns()
+        # apply progress may have released admission-window room: wake
+        # parked rejected clients (no-op attribute check when none)
+        self._adm_gate.open()
+        # per-step wave-phase breakdown (obs.WAVE_PHASES). host_pack
+        # covered queued-scatter application + mailbox build + dispatch
+        # (recorded at dispatch time); device_step runs from the
+        # dispatch to here, and its three sub-phases add up to it;
+        # host_egress includes the rare paths, apply and client replies
+        # (apply also gets its own histogram). The dispatch-time AER
+        # fan-out is booked here too, so that aer_fanout and its CPU
+        # account have one writer.
+        wh = self._wave_h
+        if eg_np is not None:
+            wh["ticket_queue"].record(t_pop - ticket.t_pack)
+            wh["egress_sync"].record(t_sync - t_pop)
+            wh["egress_lock_wait"].record(_t_dev - t_sync)
+            wh["device_step"].record(_t_dev - ticket.t_pack)
+            wh["host_egress"].record(_t_eg - _t_dev)
+        if ticket.aer0_ns is not None:
+            wh["aer_fanout"].record(ticket.aer0_ns)
+        wh["aer_fanout"].record(_t_aer - _t_eg)
+        if cpu:
+            cnt = self.counters
+            shift = self._CPU_SAMPLE_SHIFT
+            if ticket.aer0_cpu_ns is not None:
+                cpu_aer += ticket.aer0_cpu_ns
+            cnt.incr("cpu_ns_aer_fanout", cpu_aer << shift)
+            if eg_np is not None:
+                cnt.incr("cpu_ns_host_egress", (_c_eg - _c_dev) << shift)
+
+    def _handle_rares(self, rares) -> None:
         # rare-path outbound batches per destination ACROSS the whole
         # rare loop: an election storm over 10k groups must land on a
         # peer as a handful of ring items, not one per group — per-group
@@ -1981,7 +2171,7 @@ class BatchCoordinator:
         # overflow was shed as lossy traffic, wedging the un-retried
         # tail of the storm (caught by the 10240-group bench election)
         rare_out: Dict[str, List] = {}
-        for g, msg, from_sid in ticket.rare:
+        for g, msg, from_sid in rares:
             # crash isolation for the slow paths (snapshot transfer
             # decode of untrusted bytes, membership, queries): a
             # poisoned message must not kill the step thread — every
@@ -1997,33 +2187,6 @@ class BatchCoordinator:
                 )
         for node_name, msgs in rare_out.items():
             self._send_batch(node_name, msgs)
-        _t_eg = time.perf_counter_ns()
-        self._send_aers(aer_dirty)
-        _t_aer = time.perf_counter_ns()
-        # apply progress may have released admission-window room: wake
-        # parked rejected clients (no-op attribute check when none)
-        self._adm_gate.open()
-        # per-step wave-phase breakdown (obs.WAVE_PHASES). host_pack
-        # covered queued-scatter application + mailbox build + dispatch
-        # (recorded at dispatch time); device_step is the egress host
-        # sync (the device-compute wait); host_egress includes apply
-        # and client replies (apply also gets its own histogram).
-        wh = self._wave_h
-        if _t_dev is not None:
-            wh["device_step"].record(_t_dev - ticket.t_pack)
-            wh["host_egress"].record(_t_eg - _t_dev)
-        wh["aer_fanout"].record(_t_aer - _t_eg)
-        tb = self._trace
-        if tb.enabled:
-            # same timestamps the histograms just consumed, as timeline
-            # spans: one lane per phase per node, so step-pipelining
-            # overlap (or its absence) is visible in Perfetto
-            node = self.name
-            if _t_dev is not None:
-                tb.span("device_step", node, ticket.t_pack,
-                        _t_dev - ticket.t_pack)
-                tb.span("host_egress", node, _t_dev, _t_eg - _t_dev)
-            tb.span("aer_fanout", node, _t_eg, _t_aer - _t_eg)
 
     def _stage_app(self, gid: int, lo: int, hi: int, term: int) -> None:
         """Stage an appended run for the next dispatching pass's device
@@ -3626,8 +3789,6 @@ class BatchCoordinator:
 
     def _staleness_hist(self):
         if self._stale_h is None:
-            from ra_tpu import obs as _obs
-
             self._stale_h = _obs.staleness_hist(self.name)
         return self._stale_h
 
@@ -4028,6 +4189,11 @@ class BatchCoordinator:
         if isinstance(msg, tuple) and msg and msg[0] == "state_query":
             _, fn, fut = msg
             self._reply(fut, ("ok", fn(g), g.sid_of(g.leader_slot)))
+            born = getattr(fut, "t_born", None)
+            if born is not None:
+                self.counters.incr("state_queries")
+                self.counters.incr(
+                    "state_query_ns", time.monotonic_ns() - born)
             return
         if isinstance(msg, tuple) and msg and msg[0] == "force_shrink":
             # disaster recovery: restrict the cluster to this member and
@@ -4170,8 +4336,15 @@ class BatchCoordinator:
             self._reply(fut, ("redirect", None))
             return
         me = (g.name, self.name)
+        # read accounts (docs/INTERNALS.md §13): every read, from the
+        # caller's future's birth; stamps only, they touch no decision
+        born = getattr(fut, "t_born", None)
+        cnt = self.counters
         if self._voter_count(g) <= 1:
             self._reply(fut, ("ok", fn(g.machine_state), me))
+            if born is not None:
+                cnt.incr("read_registers")
+                cnt.incr("read_register_ns", time.monotonic_ns() - born)
             return
         now = self.clock.monotonic()
         if self.lease_cfg.enabled:
@@ -4188,6 +4361,9 @@ class BatchCoordinator:
             if exp > now:
                 self.counters.incr("read_lease_served")
                 self._reply(fut, ("ok", fn(g.machine_state), me))
+                if born is not None:
+                    cnt.incr("read_registers")
+                    cnt.incr("read_register_ns", time.monotonic_ns() - born)
                 if (
                     exp - now < self.lease_cfg.window_s / 2.0
                     and now - self._lease_renew_t[gid]
@@ -4232,10 +4408,9 @@ class BatchCoordinator:
         g.pending_queries = fresh
         g.query_seq += 1
         qid = g.query_seq
-        g.pending_queries.append(
-            {"qi": g.last_applied, "qid": qid, "fn": fn, "fut": fut,
-             "acks": set(), "t": now}
-        )
+        query = {"qi": g.last_applied, "qid": qid, "fn": fn, "fut": fut,
+                 "acks": set(), "t": now}
+        g.pending_queries.append(query)
         hb = HeartbeatRpc(term=g.term, leader_id=me, query_index=qid)
         outbound: Dict[str, List] = {}
         for s, member in enumerate(g.members):
@@ -4251,6 +4426,10 @@ class BatchCoordinator:
             outbound.setdefault(member[1], []).append((member, hb, me))
         for node_name, msgs in outbound.items():
             self._send_batch(node_name, msgs)
+        if born is not None:
+            query["t_reg"] = t_reg = time.monotonic_ns()
+            cnt.incr("read_registers")
+            cnt.incr("read_register_ns", t_reg - born)
 
     def _adopt_term(self, g: GroupHost, term: int, leader_sid=None) -> None:
         """Adopt a higher term seen outside the device mailbox (call
@@ -4310,6 +4489,11 @@ class BatchCoordinator:
                 if len(q["acks"]) + 1 >= quorum and g.last_applied >= q["qi"]:
                     self._reply(q["fut"], ("ok", q["fn"](g.machine_state), me))
                     done.append(q)
+                    t_reg = q.get("t_reg")
+                    if t_reg is not None:
+                        self.counters.incr("read_quorum_rounds")
+                        self.counters.incr(
+                            "read_quorum_ns", time.monotonic_ns() - t_reg)
         for q in done:
             g.pending_queries.remove(q)
 
@@ -4524,142 +4708,11 @@ class BatchCoordinator:
         # command-lane watchdog state per gid:
         # (applied_seen, oldest_pending_idx, since, strikes)
         lane_watch: Dict[int, Tuple[int, int, float, int]] = {}
-        last_tick = self.clock.monotonic()
+        self._detect_last_tick = self.clock.monotonic()
         while self.running:
             try:
-                now0 = self.clock.monotonic()
-                if now0 - last_tick >= self.tick_interval_s:
-                    last_tick = now0
-                    self._lane_watchdog(lane_watch, now0)
-                    # aggregate commit rate across all groups (the
-                    # batch-backend ra_li feed for system_overview /
-                    # placement decisions)
-                    applied_total = int(
-                        self._applied_np[: self.n_groups].sum()
-                    )
-                    prev = self._commit_li_prev
-                    self._commit_li_prev = (now0, applied_total)
-                    if prev is not None:
-                        rate = self._commit_li.sample(
-                            max(0, applied_total - prev[1]), now0 - prev[0]
-                        )
-                        self.counters.put("commit_rate", int(round(rate)))
-                    # reclaim lanes of exited producer threads, then
-                    # publish the registered-lane gauge (one lane per
-                    # live producer; off the hot drain path)
-                    prune = getattr(self._rings, "prune_dead", None)
-                    if prune is not None:
-                        prune()
-                    self.counters.put(
-                        "ingress_ring_lanes", self._rings.lanes()
-                    )
-                    self._health_scan(now0)
-                    ms = int(self.clock.time() * 1000)
-                    for i in range(self.n_groups):
-                        g = self.groups[i]
-                        if g is None:
-                            continue
-                        if g.has_tick:
-                            self.deliver((g.name, self.name), ("machine_tick", ms), None)
-                        if g.role == C.R_LEADER:
-                            # peers silent for two ticks may have missed
-                            # AERs (drops/partitions advance next_index
-                            # optimistically): probe them so their reject
-                            # hints rewind replication (zero cost while
-                            # acks flow)
-                            stale = [
-                                s for s, m in enumerate(g.members)
-                                if m is not None and s != g.self_slot
-                                and now0 - g.last_ack.get(s, 0.0)
-                                > 2 * self.tick_interval_s
-                            ]
-                            if stale:
-                                self.deliver(
-                                    (g.name, self.name), ("resync", stale), None
-                                )
-                # a stopped node unregisters: include previously-seen
-                # names so disappearance reads as death
-                known = set(self.registry.names()) | set(self._node_status)
-                for other in known:
-                    if other == self.name:
-                        continue
-                    alive = self.transport.node_alive(other)
-                    prev = self._node_status.get(other)
-                    self._node_status[other] = alive
-                    if prev is True and not alive:
-                        self._on_node_down(other)
-                # suspicion sweep. Three leaderless shapes need retry —
-                # without it a partition heal can wedge a group forever
-                # (nobody re-elects once every node is "alive" again):
-                #   1. a stalled election (pre-vote/candidate whose
-                #      messages were lost) — mirror the actor backend's
-                #      state-enter election timer;
-                #   2. a follower with a known leader: a dead leader
-                #      node counts once the follower has ALSO been
-                #      without contact for one election timeout (vote
-                #      grants refresh contact, so a member that just
-                #      endorsed a campaigning rival holds off); an
-                #      alive-but-silent leader (deposed, never re-won)
-                #      times out on lost contact — the resync probe
-                #      guarantees a live leader contacts every peer
-                #      within ~2 ticks;
-                #   3. a follower with NO known leader (term bumped by a
-                #      failed election) — contact timeout, gated on
-                #      term > 0 so fresh clusters still boot quiet until
-                #      explicitly triggered (reference: ra:start_cluster
-                #      calls trigger_election; no idle heartbeats).
-                # window >> the 2-tick probe cadence: device pre-vote
-                # grants have no leader-stickiness, so a trigger-happy
-                # sweep could dethrone a healthy but loaded leader
-                now = self.clock.monotonic()
-                contact_window = max(
-                    5 * self.tick_interval_s, 6 * self.election_timeout_s
-                )
-                for i in range(self.n_groups):
-                    g = self.groups[i]
-                    if g is None or g.role == C.R_LEADER:
-                        continue
-                    if g.voter_status.get(g.self_slot) != "voter":
-                        continue
-                    leader = g.sid_of(g.leader_slot)
-                    if g.role in (C.R_PRE_VOTE, C.R_CANDIDATE):
-                        suspicious = (
-                            now - g.last_contact > 2 * self.election_timeout_s
-                        )
-                    elif leader is not None and leader[1] != self.name:
-                        # a dead leader node is suspicious only once it
-                        # has also been SILENT for an election timeout:
-                        # last_contact refreshes on vote grants, so a
-                        # member that just endorsed a campaigning rival
-                        # holds off instead of racing it (the round-5
-                        # takeover duel)
-                        suspicious = (
-                            not self.transport.node_alive(leader[1])
-                            and now - g.last_contact > self.election_timeout_s
-                        ) or now - g.last_contact > contact_window
-                    else:
-                        suspicious = (
-                            g.term > 0
-                            and now - g.last_contact > contact_window
-                        )
-                    if not suspicious:
-                        armed.pop(i, None)
-                    elif now >= cooldown.get(i, 0.0):
-                        dl = armed.get(i)
-                        if dl is None:
-                            armed[i] = now + self.election_timeout_s * (
-                                0.1 + random.random()
-                            )
-                        elif now >= dl:
-                            armed.pop(i, None)
-                            cooldown[i] = (
-                                now + 2 * self.election_timeout_s
-                                + random.random() * 2 * self.election_timeout_s
-                            )
-                            self.deliver(
-                                (g.name, self.name), ElectionTimeout(now),
-                                None,
-                            )
+                with _obs.span("ra/detect/scan", node=self.name):
+                    self._detect_pass(cooldown, armed, lane_watch)
             except Exception:  # noqa: BLE001 — the detector must keep running
                 self.detector_errors += 1
                 if self.detector_errors == 1:
@@ -4667,6 +4720,144 @@ class BatchCoordinator:
                         "coordinator %s: detector pass failed", self.name
                     )
             time.sleep(self._detector_poll_s)
+
+    def _detect_pass(self, cooldown, armed, lane_watch) -> None:
+        """One pass of the detector thread: the per-tick work (lane
+        watchdog, commit rate, health scan, machine ticks, resync
+        probes), node liveness, and the suspicion sweep."""
+        now0 = self.clock.monotonic()
+        if now0 - self._detect_last_tick >= self.tick_interval_s:
+            self._detect_last_tick = now0
+            self._lane_watchdog(lane_watch, now0)
+            # aggregate commit rate across all groups (the
+            # batch-backend ra_li feed for system_overview /
+            # placement decisions)
+            applied_total = int(
+                self._applied_np[: self.n_groups].sum()
+            )
+            prev = self._commit_li_prev
+            self._commit_li_prev = (now0, applied_total)
+            if prev is not None:
+                rate = self._commit_li.sample(
+                    max(0, applied_total - prev[1]), now0 - prev[0]
+                )
+                self.counters.put("commit_rate", int(round(rate)))
+            # reclaim lanes of exited producer threads, then
+            # publish the registered-lane gauge (one lane per
+            # live producer; off the hot drain path)
+            prune = getattr(self._rings, "prune_dead", None)
+            if prune is not None:
+                prune()
+            self.counters.put(
+                "ingress_ring_lanes", self._rings.lanes()
+            )
+            self._health_scan(now0)
+            ms = int(self.clock.time() * 1000)
+            for i in range(self.n_groups):
+                g = self.groups[i]
+                if g is None:
+                    continue
+                if g.has_tick:
+                    self.deliver((g.name, self.name), ("machine_tick", ms), None)
+                if g.role == C.R_LEADER:
+                    # peers silent for two ticks may have missed
+                    # AERs (drops/partitions advance next_index
+                    # optimistically): probe them so their reject
+                    # hints rewind replication (zero cost while
+                    # acks flow)
+                    stale = [
+                        s for s, m in enumerate(g.members)
+                        if m is not None and s != g.self_slot
+                        and now0 - g.last_ack.get(s, 0.0)
+                        > 2 * self.tick_interval_s
+                    ]
+                    if stale:
+                        self.deliver(
+                            (g.name, self.name), ("resync", stale), None
+                        )
+        # a stopped node unregisters: include previously-seen
+        # names so disappearance reads as death
+        known = set(self.registry.names()) | set(self._node_status)
+        for other in known:
+            if other == self.name:
+                continue
+            alive = self.transport.node_alive(other)
+            prev = self._node_status.get(other)
+            self._node_status[other] = alive
+            if prev is True and not alive:
+                self._on_node_down(other)
+        # suspicion sweep. Three leaderless shapes need retry —
+        # without it a partition heal can wedge a group forever
+        # (nobody re-elects once every node is "alive" again):
+        #   1. a stalled election (pre-vote/candidate whose
+        #      messages were lost) — mirror the actor backend's
+        #      state-enter election timer;
+        #   2. a follower with a known leader: a dead leader
+        #      node counts once the follower has ALSO been
+        #      without contact for one election timeout (vote
+        #      grants refresh contact, so a member that just
+        #      endorsed a campaigning rival holds off); an
+        #      alive-but-silent leader (deposed, never re-won)
+        #      times out on lost contact — the resync probe
+        #      guarantees a live leader contacts every peer
+        #      within ~2 ticks;
+        #   3. a follower with NO known leader (term bumped by a
+        #      failed election) — contact timeout, gated on
+        #      term > 0 so fresh clusters still boot quiet until
+        #      explicitly triggered (reference: ra:start_cluster
+        #      calls trigger_election; no idle heartbeats).
+        # window >> the 2-tick probe cadence: device pre-vote
+        # grants have no leader-stickiness, so a trigger-happy
+        # sweep could dethrone a healthy but loaded leader
+        now = self.clock.monotonic()
+        contact_window = max(
+            5 * self.tick_interval_s, 6 * self.election_timeout_s
+        )
+        for i in range(self.n_groups):
+            g = self.groups[i]
+            if g is None or g.role == C.R_LEADER:
+                continue
+            if g.voter_status.get(g.self_slot) != "voter":
+                continue
+            leader = g.sid_of(g.leader_slot)
+            if g.role in (C.R_PRE_VOTE, C.R_CANDIDATE):
+                suspicious = (
+                    now - g.last_contact > 2 * self.election_timeout_s
+                )
+            elif leader is not None and leader[1] != self.name:
+                # a dead leader node is suspicious only once it
+                # has also been SILENT for an election timeout:
+                # last_contact refreshes on vote grants, so a
+                # member that just endorsed a campaigning rival
+                # holds off instead of racing it (the round-5
+                # takeover duel)
+                suspicious = (
+                    not self.transport.node_alive(leader[1])
+                    and now - g.last_contact > self.election_timeout_s
+                ) or now - g.last_contact > contact_window
+            else:
+                suspicious = (
+                    g.term > 0
+                    and now - g.last_contact > contact_window
+                )
+            if not suspicious:
+                armed.pop(i, None)
+            elif now >= cooldown.get(i, 0.0):
+                dl = armed.get(i)
+                if dl is None:
+                    armed[i] = now + self.election_timeout_s * (
+                        0.1 + random.random()
+                    )
+                elif now >= dl:
+                    armed.pop(i, None)
+                    cooldown[i] = (
+                        now + 2 * self.election_timeout_s
+                        + random.random() * 2 * self.election_timeout_s
+                    )
+                    self.deliver(
+                        (g.name, self.name), ElectionTimeout(now),
+                        None,
+                    )
 
     def _lane_watchdog(
         self, lane_watch: Dict[int, Tuple[int, int, float, int]], now0: float

@@ -1,10 +1,8 @@
 """Observability smoke check (CI): run a short WAL-backed bench
-in-process (filling the wave/commit/WAL histograms under real load,
-with the trace buffer recording), then bring up a live 3-coordinator
-cluster, scrape the Prometheus exposition, the ``system_overview`` and
-``cluster_health`` surfaces, and fail on missing or NaN metrics; a
-dumped wave trace must also validate as well-formed Chrome trace JSON
-(matched B/E spans, monotone per-lane timestamps). Registered next to
+in-process (filling the wave/commit/WAL histograms under real load),
+then bring up a live 3-coordinator cluster, scrape the Prometheus
+exposition, the ``system_overview`` and ``cluster_health`` surfaces,
+and fail on missing or NaN metrics. Registered next to
 scripts/flake_gate.sh — the gate that keeps the instruments we debug
 liveness WITH from silently rotting while the code they instrument
 evolves.
@@ -12,7 +10,6 @@ evolves.
 Usage: JAX_PLATFORMS=cpu python scripts/obs_smoke.py [--groups N] [--cmds N]
 """
 import argparse
-import json
 import math
 import os
 import re
@@ -56,33 +53,11 @@ def main() -> int:
     from ra_tpu.ops import consensus as C
     from ra_tpu.runtime.coordinator import BatchCoordinator
 
-    obs.trace_buffer().enable()  # record wave spans through the bench
     out = bench_pipeline(args.groups, args.cmds, wal=True)
-    obs.trace_buffer().disable()
     print(f"obs_smoke: bench ran at {out['value']:.0f} cmd/s "
           f"(p50 {out['p50_ms']} ms)", file=sys.stderr)
 
     errors: list = []
-
-    # the dumped trace must be well-formed Chrome trace JSON (matched
-    # B/E pairs, monotone per-lane begins) and actually hold spans
-    with tempfile.TemporaryDirectory() as td:
-        trace_path = os.path.join(td, "wave.json")
-        n_spans = api.dump_trace(trace_path)
-        if n_spans == 0:
-            errors.append("trace dump holds no spans after the bench")
-        try:
-            doc = json.load(open(trace_path))
-        except Exception as e:  # noqa: BLE001
-            errors.append(f"trace dump is not JSON: {e}")
-        else:
-            errors.extend(obs.validate_chrome_trace(doc))
-            names = {e["name"] for e in doc["traceEvents"]
-                     if e.get("ph") == "B"}
-            for ph, _h in obs.WAVE_STEP_PHASES:
-                if ph not in names:
-                    errors.append(f"trace has no {ph!r} spans")
-    obs.trace_buffer().clear()
 
     # the bench filled the histograms (they outlive its teardown):
     # every wave phase and all five commit stages must have fired. The

@@ -2,10 +2,9 @@
 scanner's anomaly state machine with hysteresis, nemesis-driven
 end-to-end classification (induced stuck and flapping groups on both
 backends), the sharded-mesh scan smoke, the single-fetch-per-tick
-discipline counter, the Perfetto trace buffer/validator, and the
-phi-accrual detector's exported gauges and transition events."""
+discipline counter, and the phi-accrual detector's exported gauges
+and transition events."""
 
-import json
 import time
 
 import numpy as np
@@ -209,92 +208,6 @@ def test_health_config_rejects_inverted_hysteresis():
         health.HealthConfig(lag_enter=10, lag_exit=10)
     with pytest.raises(ValueError):
         health.HealthConfig(churn_enter=0.1, churn_exit=0.5)
-
-
-# ---------------------------------------------------------------------------
-# trace buffer + validator
-
-
-def test_trace_buffer_chrome_export_round_trip(tmp_path):
-    tb = obs.TraceBuffer(capacity=64)
-    tb.enable()
-    t0 = 1_000_000
-    for k in range(5):
-        tb.span("device_step", "n0", t0 + k * 1000, 400)
-        tb.span("host_egress", "n0", t0 + k * 1000 + 400, 500)
-    tb.span("device_step", "n1", t0, 900)
-    path = str(tmp_path / "t.json")
-    n = tb.dump(path)
-    assert n == 22  # 11 spans -> B+E each
-    doc = json.load(open(path))
-    assert obs.validate_chrome_trace(doc) == []
-    names = {e["args"]["name"] for e in doc["traceEvents"] if e["ph"] == "M"}
-    assert {"n0", "n1", "device_step", "host_egress"} <= names
-
-
-def test_trace_buffer_wraparound_keeps_latest_sorted():
-    tb = obs.TraceBuffer(capacity=8)
-    for k in range(20):
-        tb.span("s", "n", 100 + k, 1)
-    spans = tb.spans()
-    assert len(spans) == 8
-    assert [s[0] for s in spans] == sorted(s[0] for s in spans)
-    assert spans[-1][0] == 119
-
-
-def test_trace_validator_flags_malformed_traces():
-    bad_unmatched = {"traceEvents": [
-        {"name": "a", "ph": "B", "ts": 1.0, "pid": 1, "tid": 1},
-    ]}
-    assert obs.validate_chrome_trace(bad_unmatched)
-    bad_order = {"traceEvents": [
-        {"name": "a", "ph": "B", "ts": 5.0, "pid": 1, "tid": 1},
-        {"name": "a", "ph": "E", "ts": 6.0, "pid": 1, "tid": 1},
-        {"name": "b", "ph": "B", "ts": 2.0, "pid": 1, "tid": 1},
-        {"name": "b", "ph": "E", "ts": 3.0, "pid": 1, "tid": 1},
-    ]}
-    assert any("non-monotone" in e
-               for e in obs.validate_chrome_trace(bad_order))
-    bad_nan = {"traceEvents": [
-        {"name": "a", "ph": "B", "ts": float("nan"), "pid": 1, "tid": 1},
-    ]}
-    assert any("bad ts" in e for e in obs.validate_chrome_trace(bad_nan))
-    assert obs.validate_chrome_trace({"no": "events"})
-    # negative-duration span (E before its B)
-    bad_dur = {"traceEvents": [
-        {"name": "a", "ph": "B", "ts": 5.0, "pid": 1, "tid": 1},
-        {"name": "a", "ph": "E", "ts": 4.0, "pid": 1, "tid": 1},
-    ]}
-    assert any("ends before" in e for e in obs.validate_chrome_trace(bad_dur))
-
-
-def test_coordinator_step_loop_emits_trace_spans(tmp_path):
-    leaderboard.clear()
-    tb = obs.trace_buffer()
-    tb.clear()
-    tb.enable()
-    c = BatchCoordinator("htr0", capacity=4, num_peers=3)
-    c.start()
-    try:
-        sid = ("tg", "htr0")
-        c.add_group("tg", "trcl", [sid], adder())
-        c.deliver(sid, ElectionTimeout(), None)
-        await_(lambda: c.by_name["tg"].role == C.R_LEADER, what="leader")
-        api.process_command(sid, 1)
-        path = str(tmp_path / "wave.json")
-        n = api.dump_trace(path)
-        assert n > 0
-        doc = json.load(open(path))
-        assert obs.validate_chrome_trace(doc) == []
-        span_names = {e["name"] for e in doc["traceEvents"]
-                      if e["ph"] == "B"}
-        assert {"ingress_drain", "device_step", "host_egress",
-                "aer_fanout"} <= span_names
-    finally:
-        tb.disable()
-        tb.clear()
-        c.stop()
-        leaderboard.clear()
 
 
 # ---------------------------------------------------------------------------
