@@ -386,10 +386,12 @@ def check_step_variants(groups: int, peers: int, seed: int) -> dict:
     def host_state(s):
         return {f: np.asarray(a) for f, a in zip(C.GroupState._fields, s)}
 
-    def packed_mbox(cols, app_rows=(), written=None):
+    def packed_mbox(cols, app_rows=(), written=None, index_row=False):
         """The coordinator's packed mailbox over group columns ``cols``
-        (pads: an out-of-range gid, which the scatters drop)."""
-        pk = np.zeros((nrows, len(cols)), np.int32)
+        (pads: an out-of-range gid, which the scatters drop); with
+        ``index_row`` the active-set form, whose last row is ``cols``
+        itself, the gather index."""
+        pk = np.zeros((nrows + index_row, len(cols)), np.int32)
         for r, f in enumerate(C.MBOX_FIELDS):
             pk[r] = np.where(cols < g, mb[f][np.minimum(cols, g - 1)],
                              -1 if f.startswith("host_term") else 0)
@@ -398,6 +400,8 @@ def check_step_variants(groups: int, peers: int, seed: int) -> dict:
             pk[base:base + 4, r] = row
         for r, (gid, idx) in enumerate((written or {}).items()):
             pk[base + 4:base + 6, r] = gid, idx
+        if index_row:
+            pk[-1] = cols
         return jnp.asarray(pk)
 
     def egress_rows(out):
@@ -482,8 +486,8 @@ def check_step_variants(groups: int, peers: int, seed: int) -> dict:
         ("consensus_step_packed_sub_scat", C.consensus_step_packed_sub_scat,
          st3w, eg3, (app_rows, written)),
     ):
-        st5, out5 = fn(dev_state(st), packed_mbox(gidx, *scat),
-                       jnp.asarray(gidx))
+        st5, out5 = fn(dev_state(st),
+                       packed_mbox(gidx, *scat, index_row=True))
         got_eg = {f: a[real] for f, a in egress_rows(out5).items()}
         equal(f"{name} egress", got_eg, {f: a[act] for f, a in want_eg.items()})
         st5 = host_state(st5)

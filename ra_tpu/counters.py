@@ -221,6 +221,10 @@ COORDINATOR_FIELDS: List[FieldSpec] = [
      "mailbox pack buffers pre-zeroed inside the pipeline overlap "
      "window (the dispatch pass then packs into the spare buffer with "
      "no take/zero cost on the critical path)"),
+    ("aer_groups_before_pack", "counter",
+     "groups whose AppendEntries left from a dispatching pass ahead of "
+     "its mailbox pack and device hand-off (the fan-out of an "
+     "ingest-only pass is not counted; docs/INTERNALS.md §15)"),
     ("egress_thread_batches", "counter",
      "per-destination message batches shipped by the dedicated egress "
      "sender thread (off the step loop)"),
@@ -271,7 +275,7 @@ COORDINATOR_FIELDS: List[FieldSpec] = [
      "thread CPU ns inside the wave phase host_egress (estimate: one "
      "turn in 16 is read)"),
     ("cpu_ns_aer_fanout", "counter",
-     "thread CPU ns inside the wave phase aer_fanout (dispatch-time "
+     "thread CPU ns inside the wave phase aer_fanout (pre-pack "
      "and commit-driven fan-out, both added when the ticket realises; "
      "estimate: one turn in 16 is read)"),
     # -- read accounts (every read; time.monotonic_ns() stamps from the

@@ -270,11 +270,11 @@ WAVE_STEP_PHASES = (
                       "client commands (includes WAL handoff)"),
     ("host_pack", "apply queued device scatters + pack the mailbox"),
     ("device_step", "step dispatched -> egress synced and the state lock "
-                    "held: ticket_queue + egress_sync + egress_lock_wait "
-                    "(overlaps the dispatch-time aer_fanout, which runs "
-                    "inside ticket_queue)"),
+                    "held: ticket_queue + egress_sync + egress_lock_wait"),
     ("host_egress", "realise egress: acks, role changes, apply, replies"),
-    ("aer_fanout", "build + send outbound AER batches"),
+    ("aer_fanout", "build + send outbound AER batches (a dispatching "
+                   "pass's, ahead of its host_pack, and the commit-driven "
+                   "one at realisation)"),
 )
 WAVE_SUBSET_PHASES = {
     "apply": "subset of host_egress (machine apply, sampled groups)",
@@ -296,8 +296,8 @@ WAVE_SUBSET_PHASES = {
     "step_dispatch": "subset of host_pack (the jitted step call: "
                      "argument transfer + dispatch)",
     "ticket_queue": "subset of device_step (step dispatched -> the "
-                    "ticket popped for realisation: dispatch-time "
-                    "aer_fanout + the wait in the pipe queue)",
+                    "ticket popped for realisation: the wait in the "
+                    "pipe queue)",
     "egress_sync": "subset of device_step (np.asarray of the egress: "
                    "the host's true wait for the device)",
     "egress_lock_wait": "subset of device_step (egress synced -> the "

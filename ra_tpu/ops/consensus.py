@@ -600,17 +600,19 @@ def _consensus_step_packed_impl(state: GroupState, packed: jax.Array):
 consensus_step_packed = jax.jit(_consensus_step_packed_impl, donate_argnums=(0,))
 
 
-def _consensus_step_packed_sub_impl(
-    state: GroupState, packed: jax.Array, gidx: jax.Array
-):
-    """Active-set step: gather ONLY the rows named by ``gidx`` (an i32
-    vector padded to a power of two with out-of-range ids), run the
-    fused step over the compact sub-batch, scatter results back. Step
-    cost scales with *activity*, not capacity — the batch backend's
-    analog of the reference's per-group process waking only on messages
-    (reference: src/ra_server_proc.erl:457-530). Pad rows gather a
-    clamped row's state but their writes are dropped on the scatter, so
-    they cannot perturb any real group."""
+def _consensus_step_packed_sub_impl(state: GroupState, packed: jax.Array):
+    """Active-set step: gather ONLY the rows named by the gather index,
+    run the fused step over the compact sub-batch, scatter results
+    back. The index rides the packed buffer as its LAST row (an i32
+    vector padded to a power of two with out-of-range ids), after the
+    mailbox rows and, in the ``_scat`` form, the scatter rows: the host
+    hands the step one buffer in one call. Step cost scales with
+    *activity*, not capacity — the batch backend's analog of the
+    reference's per-group process waking only on messages (reference:
+    src/ra_server_proc.erl:457-530). Pad rows gather a clamped row's
+    state but their writes are dropped on the scatter, so they cannot
+    perturb any real group."""
+    gidx = packed[-1]
     sub = jax.tree.map(lambda a: a[gidx], state)
     rows = {name: packed[i] for i, name in enumerate(MBOX_FIELDS)}
     rows["success"] = rows["success"] != 0
@@ -704,14 +706,12 @@ consensus_step_packed_scat = jax.jit(
 )
 
 
-def _consensus_step_packed_sub_scat_impl(
-    state: GroupState, packed: jax.Array, gidx: jax.Array
-):
+def _consensus_step_packed_sub_scat_impl(state: GroupState, packed: jax.Array):
     # scatters apply to the FULL state before the active-set gather
     # (every appended/written group is in the active set by
     # construction, so the gathered sub-batch sees the new tails)
     state = _apply_packed_scatters(state, packed)
-    return _consensus_step_packed_sub_impl(state, packed, gidx)
+    return _consensus_step_packed_sub_impl(state, packed)
 
 
 consensus_step_packed_sub_scat = jax.jit(

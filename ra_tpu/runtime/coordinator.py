@@ -562,9 +562,10 @@ class BatchCoordinator:
         self._hot: set = set()  # gids with queued inbox msgs / term hints
         self._applied_np = np.zeros(capacity, np.int64)  # last_applied mirror
         # mailbox pack buffers, double-buffered (docs/INTERNALS.md §15):
-        # a build hands out a zero-copy jnp view of one buffer; the
-        # buffer returns to the pool only after that step's egress sync
-        # (np.asarray) proves the device consumed the view. The
+        # a build hands back the numpy buffer itself and the jitted step
+        # takes it as its argument; the buffer returns to the pool only
+        # after that step's egress sync (np.asarray) proves the program
+        # ran, which also holds where the backend aliases it. The
         # sequential loop cycles one buffer; the pipelined loop keeps
         # one in flight while the next step packs the other — the pool
         # is bounded by the single-outstanding-ticket cap.
@@ -909,8 +910,9 @@ class BatchCoordinator:
         and put 5 compilations into the serving window (19.2 s against
         13.2 s), though it struck no watchdog (chip_smoke.py, PR 21).
         Covers the full-width fused step, the active-set step at each
-        power-of-two sub-batch width, the role scatter at each padded
-        batch size and the health scan's copies. Rare paths (snapshot
+        power-of-two sub-batch width (both on a numpy buffer of the
+        wave loop's shape, as the loop hands it over), the role scatter
+        at each padded batch size and the health scan's copies. Rare paths (snapshot
         install, forced elections, mixed-term appends) still compile on
         first use. Returns the number of programs run."""
         cap = self.capacity
@@ -921,25 +923,29 @@ class BatchCoordinator:
         def pads(n):
             return jnp.full((n,), cap, jnp.int32)  # dropped by scatters
 
-        def mbox(width):
-            packed = np.zeros((self._NROWS, width), np.int32)
+        def mbox(width=None):
+            # numpy, as the wave loop hands it over: the argument's kind
+            # is part of what the jitted call keys its fast path on
+            packed = self._mbox_take(width)
             self._fill_scat(packed, None, None)
-            return jnp.asarray(packed)
+            if width is not None:
+                packed[-1].fill(cap)
+            return packed
 
         ran = 1
         if self._shard_state is not None:
             scratch = jax.device_put(scratch, self._shard_state)
             scratch, eg = C.consensus_step_packed(
-                scratch, jax.device_put(mbox(cap), self._shard_mbox)
+                scratch, jax.device_put(mbox(), self._shard_mbox)
             )
         else:
-            scratch, eg = C.consensus_step_packed_scat(scratch, mbox(cap))
+            scratch, eg = C.consensus_step_packed_scat(scratch, mbox())
             if self.active_set != "never":
                 most = cap if self.active_set == "always" else cap >> 2
                 width = min(256, cap)
                 while True:
                     scratch, eg = C.consensus_step_packed_sub_scat(
-                        scratch, mbox(width), pads(width)
+                        scratch, mbox(width)
                     )
                     ran += 1
                     if width >= most:
@@ -1255,18 +1261,19 @@ class BatchCoordinator:
                 # dispatching pass packs into a ready spare with zero
                 # take/zero cost on its critical path
                 if self._prezero_useful and self._spare_mbox is None:
+                    full = (self._NROWS, self.capacity)
                     with self._state_lock:
                         buf = None
                         pool = self._mbox_pool
                         for k, b in enumerate(pool):
-                            if b.shape[1] == self.capacity:
+                            # (an active-set buffer of the same width
+                            # has the index row more)
+                            if b.shape == full:
                                 buf = b
                                 del pool[k]
                                 break
                     if buf is None:
-                        buf = np.zeros(
-                            (self._NROWS, self.capacity), np.int32
-                        )
+                        buf = np.zeros(full, np.int32)
                     else:
                         buf.fill(0)
                     self._spare_mbox = buf
@@ -1472,9 +1479,9 @@ class BatchCoordinator:
         driver round to finish), then stage + dispatch the next one —
         whose drain already sees the realised egress's products, and
         whose device compute overlaps this thread realising the OTHER
-        coordinators in the round-robin. Drain-produced AERs leave at
-        dispatch time (inside ``_drain_and_dispatch``), so replication
-        fan-out never waits a pipeline slot. Same ticket machinery as
+        coordinators in the round-robin. Drain-produced AERs leave
+        ahead of the dispatch (inside ``_drain_and_dispatch``), so
+        replication fan-out never waits a pipeline slot. Same ticket machinery as
         the threaded loop; keep calling until False before reading
         final state, and do not mix with a started loop."""
         token = self._coop_drainer()
@@ -1529,7 +1536,7 @@ class BatchCoordinator:
         """One dispatched-but-unrealised step: the device egress handle
         plus everything realisation needs (who was consumed, the
         position->gid map, rares, the staging timestamps, and the wall
-        and thread-CPU ns of the dispatch-time AER fan-out, which the
+        and thread-CPU ns of the pass's own AER fan-out, which the
         realising thread books so that each account keeps one writer).
         ``cpu``: this turn is one whose thread-CPU time is read."""
 
@@ -1905,8 +1912,30 @@ class BatchCoordinator:
         ):
             return None
         if cpu:
-            _c_drain = time.thread_time_ns()
-        _t_drain = time.perf_counter_ns()
+            _c_ing = _c_drain = time.thread_time_ns()
+        _t_ing = _t_drain = time.perf_counter_ns()
+        # Host work first: drain-produced AERs (fresh appends, ack-driven
+        # next_index moves) leave BEFORE the pack and the device
+        # hand-off, as an ingest-only pass sends them. _send_aers reads
+        # logs and host mirrors only, so the order is free, and the
+        # followers' round starts a whole host_pack earlier. Its wall
+        # and CPU time are neither ingress_drain's nor host_pack's
+        # (which starts after it): they ride the ticket into aer_fanout,
+        # which the realising thread alone writes. Egress-produced AERs
+        # (commit advances) ride the ticket too.
+        aer0_ns = aer0_cpu_ns = None
+        if aer_dirty:
+            if tr:
+                sp = _obs.begin("ra/step/aer_fanout", node=node)
+            cnt.incr("aer_groups_before_pack", self._send_aers(aer_dirty))
+            if tr:
+                _obs.end(sp)
+            aer_dirty = set()
+            _t_drain = time.perf_counter_ns()
+            aer0_ns = _t_drain - _t_ing
+            if cpu:
+                _c_drain = time.thread_time_ns()
+                aer0_cpu_ns = _c_drain - _c_ing
 
         stepped = False
         eg_packed = consumed = act_np = mbox_buf = None
@@ -1920,31 +1949,31 @@ class BatchCoordinator:
         if act is None or act:
             if tr:
                 sp = _obs.begin("ra/step/host_pack/mailbox_build", node=node)
+            # the builders hand back the numpy buffer itself; the jitted
+            # step takes it as its argument and transfers it inside the
+            # call: one entry into JAX per wave on the unsharded path
             if act is not None:
                 variant = "sub_scat"
-                packed, gidx, act_np, consumed, mbox_buf = (
-                    self._build_mailbox_sub(act, app_rows, written)
-                )
+                step = C.consensus_step_packed_sub_scat
+                mbox_buf, act_np, consumed = self._build_mailbox_sub(
+                    act, app_rows, written)
             elif self._shard_state is not None:
                 # the log-tail scatters went as separate calls
                 # (_scatter_staged)
                 variant = "packed"
-                packed, consumed, mbox_buf = self._build_mailbox(None, None)
+                step = C.consensus_step_packed
+                mbox_buf, consumed = self._build_mailbox(None, None)
             else:
                 variant = "scat"
-                packed, consumed, mbox_buf = self._build_mailbox(
-                    app_rows, written)
+                step = C.consensus_step_packed_scat
+                mbox_buf, consumed = self._build_mailbox(app_rows, written)
+            packed = mbox_buf
             _t_build = time.perf_counter_ns()
             if tr:
                 _obs.end(sp)
                 sp = _obs.begin("ra/step/host_pack/step_dispatch", node=node,
                                 width=int(packed.shape[1]), variant=variant)
-            if variant == "sub_scat":
-                self.state, eg_packed = C.consensus_step_packed_sub_scat(
-                    self.state, packed, gidx
-                )
-                self.sub_steps += 1
-            elif variant == "packed":
+            if variant == "packed":
                 # the jitted scatters keep the mesh layout; an eager
                 # host-side row update (membership, snapshot install)
                 # may hand back another one — move the state back only
@@ -1957,17 +1986,13 @@ class BatchCoordinator:
                     self.shard_moves += 1
                     self.state = jax.device_put(self.state, self._shard_state)
                 packed = jax.device_put(packed, self._shard_mbox)
-                self.state, eg_packed = C.consensus_step_packed(
-                    self.state, packed
-                )
-            else:
-                self.state, eg_packed = C.consensus_step_packed_scat(
-                    self.state, packed
-                )
+            self.state, eg_packed = step(self.state, packed)
             if tr:
                 _obs.end(sp)
             stepped = True
             self.steps += 1
+            if act is not None:
+                self.sub_steps += 1
             self.msgs_processed += len(consumed)
         if tr:
             _obs.end(sp_pack)
@@ -1981,23 +2006,7 @@ class BatchCoordinator:
         # dispatch is ASYNC: eg_packed is an in-flight device value; the
         # ticket's realisation half syncs it (np.asarray) and processes
         # the egress. The sequential step_once realises inline.
-        # Drain-produced AERs (fresh appends, ack-driven next_index
-        # moves) leave NOW, overlapping the device compute — holding
-        # them for realisation would delay the replication fan-out by a
-        # whole pipeline slot. Egress-produced AERs (commit advances)
-        # ride the ticket.
-        aer0_ns = aer0_cpu_ns = None
-        if aer_dirty:
-            if tr:
-                sp = _obs.begin("ra/step/aer_fanout", node=node)
-            self._send_aers(aer_dirty)
-            if tr:
-                _obs.end(sp)
-            aer_dirty = set()
-            if cpu:
-                aer0_cpu_ns = time.thread_time_ns() - _c_pack
-            aer0_ns = time.perf_counter_ns() - _t_pack
-        wh["ingress_drain"].record(_t_drain - _t_in)
+        wh["ingress_drain"].record(_t_ing - _t_in)
         wh["step_lock_wait"].record(self._step_lock.t_held - _t_cls)
         if stepped:
             wh["host_pack"].record(_t_pack - _t_drain)
@@ -2005,7 +2014,7 @@ class BatchCoordinator:
             wh["mailbox_build"].record(_t_build - _t_scat)
             wh["step_dispatch"].record(_t_pack - _t_build)
         if cpu:
-            cnt.incr("cpu_ns_ingress_drain", (_c_drain - _c_in) << shift)
+            cnt.incr("cpu_ns_ingress_drain", (_c_ing - _c_in) << shift)
             if stepped:
                 cnt.incr("cpu_ns_host_pack", (_c_pack - _c_drain) << shift)
         return self._StepTicket(
@@ -2141,9 +2150,9 @@ class BatchCoordinator:
         # (recorded at dispatch time); device_step runs from the
         # dispatch to here, and its three sub-phases add up to it;
         # host_egress includes the rare paths, apply and client replies
-        # (apply also gets its own histogram). The dispatch-time AER
-        # fan-out is booked here too, so that aer_fanout and its CPU
-        # account have one writer.
+        # (apply also gets its own histogram). The dispatching pass's
+        # own AER fan-out is booked here too, so that aer_fanout and
+        # its CPU account have one writer.
         wh = self._wave_h
         if eg_np is not None:
             wh["ticket_queue"].record(t_pop - ticket.t_pack)
@@ -2788,28 +2797,37 @@ class BatchCoordinator:
             packed[R["w_idx"], :n] = np.fromiter(written.values(), np.int64, n)
 
     def _mbox_take(self, width: Optional[int] = None) -> np.ndarray:
-        """Pop a zeroed pack buffer from the pool (full-width by
-        default, or a power-of-two sub-batch ``width``); allocates when
-        empty — pool size is bounded by the tickets in flight."""
+        """Pop a zeroed pack buffer from the pool: the full-width
+        ``(_NROWS, capacity)`` mailbox by default, or an active-set
+        buffer of a power-of-two sub-batch ``width``, which has one row
+        more for the gather index (``_NROWS + 1`` rows; its builder
+        fills that row). Allocates when empty — pool size is bounded by
+        the tickets in flight."""
         if width is None:
-            width = self.capacity
+            shape = (self._NROWS, self.capacity)
             spare = self._spare_mbox
             if spare is not None:
                 # double-buffered staging: the spare was pre-zeroed in
                 # the pipeline overlap window — no take/zero cost here
                 self._spare_mbox = None
                 return spare
+        else:
+            shape = (self._NROWS + 1, width)
         pool = self._mbox_pool
         for k, buf in enumerate(pool):
-            if buf.shape[1] == width:
+            if buf.shape == shape:
                 del pool[k]
                 buf.fill(0)
                 return buf
-        return np.zeros((self._NROWS, width), np.int32)
+        return np.zeros(shape, np.int32)
 
     def _mbox_release(self, buf: Optional[np.ndarray]) -> None:
-        """Return a pack buffer once its step's egress sync proves the
-        device consumed the zero-copy view."""
+        """Return a pack buffer to the pool. The jitted step took the
+        numpy array as its argument: where the backend copies it (the
+        TPU's transfer) the host buffer is free once the call returns,
+        where it may alias host memory (the CPU backend) only once the
+        program has run. The callers hold to the stricter: they release
+        after the step's egress sync."""
         if buf is not None and len(self._mbox_pool) < 6:
             self._mbox_pool.append(buf)
 
@@ -2858,15 +2876,17 @@ class BatchCoordinator:
             if g.inbox:
                 self._hot.add(i)  # more queued: stay hot for next step
         self._pack_hot(packed, aer_i, aer_m, aer_s, rep_i, rep_m, rep_s)
-        return jnp.asarray(packed), consumed, packed
+        return packed, consumed
 
     def _build_mailbox_sub(self, act, app_rows=None, written=None):
         """Compact mailbox for the active-set step: one COLUMN PER
-        ACTIVE GROUP (power-of-two padded), plus the gather index vector
-        mapping column -> group id. ``consumed`` is keyed by column
-        position (the egress arrays come back in the same position
-        space). Same pop-one-message-per-group semantics as the
-        full-width builder."""
+        ACTIVE GROUP (power-of-two padded), with the gather index
+        (column -> group id) as the buffer's last row. ``consumed`` is
+        keyed by column position (the egress arrays come back in the
+        same position space). Same pop-one-message-per-group semantics
+        as the full-width builder. Returns ``(packed, act_np,
+        consumed)``; like ``_build_mailbox`` it hands back the numpy
+        buffer itself, which the jitted step takes as it is."""
         n = len(act)
         # pad floor bounds the number of compiled shapes (straggler
         # tails would otherwise walk every power of two down to 1)
@@ -2878,7 +2898,8 @@ class BatchCoordinator:
         R = self._R
         packed[R["host_term_idx"]].fill(-1)
         packed[R["host_term_val"]].fill(-1)
-        gidx = np.full(cap, self.capacity, np.int32)  # pads dropped on scatter
+        gidx = packed[-1]
+        gidx.fill(self.capacity)  # pads dropped on scatter
         gidx[:n] = act
         self._hot = set()
         consumed: Dict[int, Tuple[Any, Any]] = {}
@@ -2915,13 +2936,7 @@ class BatchCoordinator:
             if g.inbox:
                 self._hot.add(i)  # more queued: stay hot for next step
         self._pack_hot(packed, aer_i, aer_m, aer_s, rep_i, rep_m, rep_s)
-        return (
-            jnp.asarray(packed),
-            jnp.asarray(gidx),
-            np.asarray(act, np.int64),
-            consumed,
-            packed,
-        )
+        return packed, np.asarray(act, np.int64), consumed
 
     def _encode(self, g: GroupHost, from_sid, msg, p, i) -> None:
         R = self._R
@@ -3829,9 +3844,15 @@ class BatchCoordinator:
 
     _NEEDS_SNAPSHOT = object()  # rpc-cache sentinel
 
-    def _send_aers(self, aer_dirty) -> None:
+    def _send_aers(self, aer_dirty) -> int:
+        """Build and hand off the AppendEntries of the dirty groups this
+        node leads, one batch per destination. Reads the logs and the
+        host mirrors only (never ``self.state``), so a pass may run it
+        before or after its device hand-off. Returns the number of
+        groups for which an AER left."""
         outbound: Dict[str, List] = {}
         now = self.clock.monotonic()
+        shipped = 0
         for gid in aer_dirty:
             g = self.groups[gid]
             if g is None:
@@ -3853,6 +3874,7 @@ class BatchCoordinator:
             # peers at the same next_index (the steady-state pipeline)
             # share ONE immutable rpc: one entry fetch, one object
             rpc_cache: Dict[int, Any] = {}
+            left = False
             for s, member in enumerate(g.members):
                 if s == g.self_slot or member is None:
                     continue
@@ -3903,6 +3925,7 @@ class BatchCoordinator:
                             sid,
                         ))
                         g.commit_sent[s] = commit
+                        left = True
                         continue
                 rpc = rpc_cache.get(nxt)
                 if rpc is None and ft is not None and nxt >= ft[0]:
@@ -3967,8 +3990,12 @@ class BatchCoordinator:
                 if rpc.entries:
                     g.next_index[s] = rpc.entries[-1].index + 1
                 g.commit_sent[s] = commit
+                left = True
+            if left:
+                shipped += 1
         for node_name, msgs in outbound.items():
             self._send_batch(node_name, msgs)
+        return shipped
 
     # -- rare paths --------------------------------------------------------
 

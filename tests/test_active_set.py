@@ -189,10 +189,19 @@ def test_auto_mode_flip_soak_crosses_saturation_boundary():
             c.stop()
 
 
-def test_active_set_sub_step_matches_full_step_kernel():
+@pytest.mark.parametrize("full_step,sub_step,scat", [
+    ("consensus_step_packed", "consensus_step_packed_sub", False),
+    ("consensus_step_packed_scat", "consensus_step_packed_sub_scat", True),
+])
+def test_active_set_sub_step_matches_full_step_kernel(full_step, sub_step,
+                                                      scat):
     """Kernel-level parity: the same mailbox applied via the sub-batch
     gather/scatter path and via the full-width path must produce
-    identical state and egress rows for the active groups."""
+    identical state and egress rows for the active groups. The
+    sub-batch step reads its gather index from the packed buffer's last
+    row, and takes the numpy buffer as the wave loop hands it over; the
+    ``_scat`` pair carries an appended run and a durable watermark for
+    the active groups in the scatter rows of both buffers."""
     import jax.numpy as jnp
 
     G, P = 32, 3
@@ -205,14 +214,27 @@ def test_active_set_sub_step_matches_full_step_kernel():
     state_b = state_b._replace(last_index=li + 0, written_index=li + 0)
 
     act = [3, 11, 17]
-    # full-width mailbox: one AER per active row
-    full = np.zeros((len(C.MBOX_FIELDS), G), np.int32)
-    Rm = {name: i for i, name in enumerate(C.MBOX_FIELDS)}
-    full[Rm["host_term_idx"]].fill(-1)
-    full[Rm["host_term_val"]].fill(-1)
-    sub = np.zeros((len(C.MBOX_FIELDS), 4), np.int32)
-    sub[Rm["host_term_idx"]].fill(-1)
-    sub[Rm["host_term_val"]].fill(-1)
+    fields = list(C.MBOX_FIELDS) + (C.MBOX_SCAT_FIELDS if scat else [])
+    Rm = {name: i for i, name in enumerate(fields)}
+    # full-width mailbox: one AER per active row; the sub-batch buffer
+    # has the index row more
+    full = np.zeros((len(fields), G), np.int32)
+    sub = np.zeros((len(fields) + 1, 4), np.int32)
+    sub[-1] = G  # pads: dropped by the scatter back
+    sub[-1, :3] = act
+    for arr in (full, sub):
+        arr[Rm["host_term_idx"]].fill(-1)
+        arr[Rm["host_term_val"]].fill(-1)
+        if scat:
+            # scatter rows are a list, not per column: the same in both
+            arr[Rm["a_gid"]].fill(G)
+            arr[Rm["w_gid"]].fill(G)
+            for r, g in enumerate(act):
+                tail = int(li[g])
+                arr[Rm["a_gid"], r] = arr[Rm["w_gid"], r] = g
+                arr[Rm["a_lo"], r] = tail + 1
+                arr[Rm["a_hi"], r] = arr[Rm["w_idx"], r] = tail + 2
+                arr[Rm["a_term"], r] = 1
     for p, g in enumerate(act):
         for arr, col in ((full, g), (sub, p)):
             arr[Rm["msg_type"], col] = C.MSG_AER
@@ -222,16 +244,15 @@ def test_active_set_sub_step_matches_full_step_kernel():
             arr[Rm["num_entries"], col] = 2
             arr[Rm["entries_last_term"], col] = 1
             arr[Rm["leader_commit"], col] = int(li[g]) + 2
-    gidx = np.full(4, G, np.int32)
-    gidx[:3] = act
 
-    new_a, eg_a = C.consensus_step_packed(state_a, jnp.asarray(full))
-    new_b, eg_b = C.consensus_step_packed_sub(
-        state_b, jnp.asarray(sub), jnp.asarray(gidx)
-    )
+    new_a, eg_a = getattr(C, full_step)(state_a, full)
+    new_b, eg_b = getattr(C, sub_step)(state_b, sub)
     eg_a = np.asarray(eg_a)
     eg_b = np.asarray(eg_b)
     for p, g in enumerate(act):
         np.testing.assert_array_equal(eg_a[:, g], eg_b[:, p])
     for fa, fb in zip(new_a, new_b):
         np.testing.assert_array_equal(np.asarray(fa), np.asarray(fb))
+    if scat:
+        assert [int(np.asarray(new_b.written_index)[g]) for g in act] == [
+            int(li[g]) + 2 for g in act]

@@ -82,7 +82,8 @@ def _step_args(variant, p, sh):
         )
         return state, mbox
     if "_sub" in variant:
-        return state, _i32((NROWS, SUB), sh), _i32((SUB,), sh)
+        # the gather index is the packed buffer's last row
+        return state, _i32((NROWS + 1, SUB), sh)
     return state, _i32((NROWS, G), sh)
 
 

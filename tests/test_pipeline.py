@@ -16,7 +16,7 @@ import time
 
 import pytest
 
-from ra_tpu import api, faults, leaderboard
+from ra_tpu import api, faults, leaderboard, obs
 from ra_tpu import native as ra_native
 from ra_tpu.log.log import Log
 from ra_tpu.log.segment_writer import SegmentWriter
@@ -390,10 +390,11 @@ def test_group_commit_bounded_by_max_delay(tmp_path):
 # pipelined drivers: equivalence + overlap proof
 
 
-def _mk_coop(tag, nodes):
+def _mk_coop(tag, nodes, **kw):
     reg = NodeRegistry()
     coords = [
-        BatchCoordinator(f"{tag}{i}", capacity=8, num_peers=3, nodes=reg)
+        BatchCoordinator(f"{tag}{i}", capacity=8, num_peers=3, nodes=reg,
+                         **kw)
         for i in range(3)
     ]
     ids = [("cg", f"{tag}{i}") for i in range(3)]
@@ -489,6 +490,177 @@ def test_threaded_pipelined_loop_commits_and_overlaps():
         assert sum(
             c.counters.get("pipeline_overlap_ns") for c in coords
         ) > 0
+    finally:
+        for c in coords:
+            c.stop()
+
+
+# ---------------------------------------------------------------------------
+# the hand-off to the device (ISSUE 25; docs/INTERNALS.md §15): one host
+# buffer, one entry into JAX per wave, host work first
+
+
+JAX_ENTRIES = ("consensus_step_packed", "consensus_step_packed_scat",
+               "consensus_step_packed_sub", "consensus_step_packed_sub_scat",
+               "record_appended", "record_appended_runs", "record_written",
+               "set_roles")
+
+
+def _count_jax_entries(monkeypatch, entries):
+    """Append ``(name, type of the last argument)`` to ``entries`` for
+    every call the coordinator's module can make into JAX on the wave
+    path: the jitted programs of ``ops/consensus`` and the two explicit
+    transfers."""
+    import jax
+    import jax.numpy as jnp
+
+    def counted(name, fn):
+        def call(*a, **kw):
+            entries.append((name, type(a[-1])))
+            return fn(*a, **kw)
+        return call
+
+    for name in JAX_ENTRIES:
+        monkeypatch.setattr(C, name, counted(name, getattr(C, name)))
+    monkeypatch.setattr(jnp, "asarray", counted("jnp.asarray", jnp.asarray))
+    monkeypatch.setattr(jax, "device_put",
+                        counted("jax.device_put", jax.device_put))
+
+
+def _coop_leader(tag, active_set):
+    """Three cooperative coordinators, ``cg`` led by the first, driven
+    until nothing is left to do (no role scatter pending)."""
+    coords, ids = _mk_coop(tag, 3, active_set=active_set)
+
+    def step():
+        return any([c.step_once() for c in coords])
+
+    coords[0].deliver(ids[0], ElectionTimeout(), None)
+    _drive(coords, step, lambda: coords[0].by_name["cg"].role == C.R_LEADER)
+    while step():
+        pass
+    return coords, ids, step
+
+
+@pytest.mark.parametrize("active_set,variant", [
+    ("always", "consensus_step_packed_sub_scat"),
+    ("never", "consensus_step_packed_scat"),
+])
+def test_dispatching_pass_enters_jax_once_with_a_numpy_buffer(
+        monkeypatch, active_set, variant):
+    """Between ``_ingest`` and the ticket a wave with no pending role
+    scatter makes ONE call into JAX: the jitted step, on the numpy
+    buffer its builder filled (no ``jnp.asarray``, no ``device_put``),
+    which goes back to the pool once the egress is synced."""
+    import numpy as np
+
+    coords, ids, step = _coop_leader("hb" + active_set[0], active_set)
+    try:
+        entries = []
+        _count_jax_entries(monkeypatch, entries)
+        steps0 = sum(c.steps for c in coords)
+        for k in range(3):
+            coords[0].deliver(
+                ids[0], Command(kind=USR, data=1, reply_mode="noreply"),
+                None)
+            _drive(coords, step,
+                   lambda: all(c.by_name["cg"].machine_state == k + 1
+                               for c in coords))
+        waves = sum(c.steps for c in coords) - steps0
+        assert waves >= 6  # leader, followers and the leader again, 3 times
+        assert entries == [(variant, np.ndarray)] * waves
+        width = 8
+        shape = (BatchCoordinator._NROWS + (active_set == "always"), width)
+        for c in coords:
+            assert c._mbox_pool and all(
+                b.shape == shape for b in c._mbox_pool)
+    finally:
+        for c in coords:
+            c.stop()
+
+
+def test_served_waves_compile_nothing_after_warm_steps():
+    """``warm_steps`` runs the shapes and the argument kinds the loop
+    sends (numpy in), so election and traffic compile nothing: counted
+    as the benchmark's set-up line counts compilations."""
+    from benchmark import harness
+
+    coords, ids = _mk_coop("wm", 3)
+    try:
+        # the full-width step, the 8-wide active-set step, set_roles at
+        # 1, 2, 4 and 8 rows
+        assert [c.warm_steps() for c in coords] == [6, 6, 6]
+        stats = harness.CompileStats()
+
+        def step():
+            return any([c.step_once() for c in coords])
+
+        coords[0].deliver(ids[0], ElectionTimeout(), None)
+        _drive(coords, step,
+               lambda: coords[0].by_name["cg"].role == C.R_LEADER)
+        for k in range(4):
+            coords[0].deliver(
+                ids[0], Command(kind=USR, data=1, reply_mode="noreply"),
+                None)
+        _drive(coords, step,
+               lambda: all(c.by_name["cg"].machine_state == 4
+                           for c in coords))
+        assert sum(c.sub_steps for c in coords) > 0
+        assert stats.since()["compilations"] == 0
+    finally:
+        for c in coords:
+            c.stop()
+
+
+def test_idle_leaders_aer_leaves_before_the_device_hand_off(monkeypatch):
+    """Host work first: a command that finds the loop idle is appended
+    and its AppendEntries is on the sender's ring BEFORE the pass packs
+    its mailbox and calls the step; ``aer_groups_before_pack`` counts
+    the group. The fan-out's time rides the ticket into ``aer_fanout``
+    and is in neither ``ingress_drain`` nor ``host_pack``."""
+    from ra_tpu.protocol import AppendEntriesRpc
+
+    coords, ids, step = _coop_leader("hf", "auto")
+    leader = coords[0]
+    try:
+        # as a started loop has it: sends go to the sender thread's ring
+        leader._egress_on = True
+        on_ring_at_dispatch = []
+        real = C.consensus_step_packed_sub_scat
+
+        def dispatching(state, packed):
+            leader._egress_rings.drain(on_ring_at_dispatch)
+            return real(state, packed)
+
+        monkeypatch.setattr(C, "consensus_step_packed_sub_scat", dispatching)
+        before = leader.counters.get("aer_groups_before_pack")
+        phases = {ph: obs.histograms().fetch(("wave", leader.name, ph))
+                  for ph in ("ingress_drain", "host_pack")}
+        wall0 = sum(h.total for h in phases.values())
+        leader.deliver(
+            ids[0], Command(kind=USR, data=7, reply_mode="noreply"), None)
+        assert leader.step_stage()
+        ticket = leader._coop_ticket
+        assert ticket.stepped and ticket.aer0_ns > 0
+        assert not ticket.aer_dirty
+        assert leader.counters.get("aer_groups_before_pack") == before + 1
+        assert sorted(node for node, _ in on_ring_at_dispatch) == [
+            "hf1", "hf2"]
+        for _node, msgs in on_ring_at_dispatch:
+            (_to, rpc, frm), = msgs
+            assert type(rpc) is AppendEntriesRpc and frm == ids[0]
+            assert [e.cmd.data for e in rpc.entries] == [7]
+        # each second of the pass in one phase: ingress_drain, then the
+        # fan-out (booked when the ticket realises), then host_pack
+        assert (sum(h.total for h in phases.values()) - wall0
+                + ticket.aer0_ns) == ticket.t_pack - ticket.t_in
+        leader._egress_on = False
+        for node, msgs in on_ring_at_dispatch:
+            leader._send_batch_inline(node, msgs)
+        monkeypatch.undo()
+        _drive(coords, step,
+               lambda: all(c.by_name["cg"].machine_state == 7
+                           for c in coords))
     finally:
         for c in coords:
             c.stop()

@@ -12,6 +12,7 @@ split the wave phases and the reads (ISSUE 24; docs/INTERNALS.md §13):
 
 import os
 import sys
+import threading
 import time
 
 import jax
@@ -273,7 +274,12 @@ def test_tracing_follows_the_profilers_session(tmp_path):
 def test_thread_cpu_is_read_on_one_turn_in_sixteen(monkeypatch):
     """The thread clock is a system call (16 us a read on the v5e's
     host): a coordinator reads it on one turn in 16 and books that
-    turn's readings 16 times."""
+    turn's readings 16 times. The coordinator under test is driven from
+    this thread and reads a clock that advances 1 us on each read (the
+    real one ticks in 10 ms on some hosts, and a 16th of 160 turns can
+    fall between two ticks); every other thread of the process, the
+    module's traced cluster among them, keeps the real clock and is not
+    counted."""
     leaderboard.clear()
     # (the module's traced cluster reads the clock on every turn)
     monkeypatch.setattr(BatchCoordinator, "_CPU_SAMPLE_SHIFT", 4)
@@ -284,19 +290,24 @@ def test_thread_cpu_is_read_on_one_turn_in_sixteen(monkeypatch):
         c.deliver(sid, ElectionTimeout(), None)
         while c.step_once():
             pass
-        reads = []
+        me = threading.get_ident()
         real = time.thread_time_ns
+        reads = []
+
+        def thread_clock():
+            if threading.get_ident() != me:
+                return real()
+            reads.append(1)
+            return 1000 * len(reads)
+
         before = c.counters.to_dict()
         turns0 = c._cpu_turn
-        time.thread_time_ns = lambda: reads.append(1) or real()
-        try:
-            for _ in range(160):
-                c.deliver(sid, Command(kind=USR, data=("put", "k", b"v")),
-                          None)
-                while c.step_once():
-                    pass
-        finally:
-            time.thread_time_ns = real
+        monkeypatch.setattr(time, "thread_time_ns", thread_clock)
+        for _ in range(160):
+            c.deliver(sid, Command(kind=USR, data=("put", "k", b"v")), None)
+            while c.step_once():
+                pass
+        monkeypatch.undo()
         turns = c._cpu_turn - turns0
         assert turns >= 160
         # at most seven readings on a turn that is read
@@ -305,7 +316,8 @@ def test_thread_cpu_is_read_on_one_turn_in_sixteen(monkeypatch):
         for phase in ("ingress_drain", "host_pack", "host_egress",
                       "aer_fanout"):
             grown = after[f"cpu_ns_{phase}"] - before[f"cpu_ns_{phase}"]
-            assert grown > 0 and grown % 16 == 0, (phase, grown)
+            # whole readings of the fake clock, booked 16 times each
+            assert grown > 0 and grown % 16000 == 0, (phase, grown)
     finally:
         c.stop()
         leaderboard.clear()
