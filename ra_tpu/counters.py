@@ -160,6 +160,14 @@ COORDINATOR_FIELDS: List[FieldSpec] = [
     ("lane_wedges", "counter",
      "watchdog detections of a wedged command lane (accepted command, "
      "no commit progress within the deadline)"),
+    ("lane_deadline_ms", "gauge",
+     "the watchdog's deadline at its last tick: command_deadline_s, "
+     "stretched to _WEDGE_WAVES of the coordinator's waves where they "
+     "are long, by at most _WEDGE_STRETCH_MAX"),
+    ("lane_stall_max_ms", "gauge",
+     "longest the watchdog has seen a lane with pending commands stand "
+     "still (what it holds against the deadline at each tick; a lane "
+     "past it was struck): how near healthy lanes come to a strike"),
     ("lane_recoveries", "counter",
      "watchdog recovery attempts (re-step + peer resync probe)"),
     ("lane_redirects", "counter",
@@ -225,6 +233,18 @@ COORDINATOR_FIELDS: List[FieldSpec] = [
      "groups whose AppendEntries left from a dispatching pass ahead of "
      "its mailbox pack and device hand-off (the fan-out of an "
      "ingest-only pass is not counted; docs/INTERNALS.md §15)"),
+    # -- the WAL writer's durable hand-off (wal_notify_many: one state-
+    # lock round per fsync batch; perf_counter_ns pairs per batch)
+    ("wal_notify_batches", "counter",
+     "state-lock rounds taken to deliver written events (one per WAL "
+     "fsync batch, or one per event where the WAL has no bulk channel)"),
+    ("wal_notify_events", "counter",
+     "written events delivered to their groups under those rounds"),
+    ("wal_notify_wait_ns", "counter",
+     "the WAL writer's wait for the state lock, asked -> held, summed "
+     "(inside every append_durable)"),
+    ("wal_notify_hold_ns", "counter",
+     "the state lock held for those rounds, held -> released, summed"),
     ("egress_thread_batches", "counter",
      "per-destination message batches shipped by the dedicated egress "
      "sender thread (off the step loop)"),

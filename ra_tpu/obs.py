@@ -302,6 +302,14 @@ WAVE_SUBSET_PHASES = {
                    "the host's true wait for the device)",
     "egress_lock_wait": "subset of device_step (egress synced -> the "
                         "state lock held)",
+    # what only a busy fleet works: one record per pass over ALL its
+    # groups (the sampled wal_handoff and apply above time one group)
+    "ingest_append": "subset of ingress_drain (log appends + WAL "
+                     "hand-off of the pass's client commands, all "
+                     "groups; no sample on a pass without commands)",
+    "egress_apply": "subset of host_egress (machine apply + client "
+                    "replies of every group the step committed; no "
+                    "sample on a step that committed nothing)",
 }
 WAVE_PHASES = WAVE_STEP_PHASES + tuple(WAVE_SUBSET_PHASES.items())
 

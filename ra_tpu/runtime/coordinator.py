@@ -316,6 +316,27 @@ class BatchCoordinator:
     # call, 16 us a read beside the wave loop's work on the v5e's host
     # (PERF.md section 6, PR 24)
     _CPU_SAMPLE_SHIFT = 4
+    # the lane watchdog's deadline follows the coordinator's own wave
+    # (drain start -> step realised, smoothed) where waves are long: a
+    # command is committed by the leader's pass, a follower's and the
+    # leader's again, with a WAL batch and a sender's turn between, and
+    # at each hand-off it can miss a whole wave. Measured in the
+    # saturated 10,240 x 3 fleet (PERF.md section 6, PR 26: eleven 40 s
+    # runs, gauges ``lane_stall_max_ms`` / ``lane_deadline_ms``): the
+    # smoothed wave is 0.75-0.93 s and dips to 0.54 s between the
+    # bursts of the closed loop; the median commit takes 4.7 s; the
+    # watchdog sees the slowest lane of a run still for 3.2-4.8 s, and
+    # in two runs of eleven for 5.2 and 6.2 s, lanes that then moved by
+    # themselves: slow, not wedged, yet past ``command_deadline_s`` =
+    # 5 s (four such were struck in one earlier run in thirteen). 6.2 s
+    # at the dip is 11.5 waves; 16 leaves a third of room. The stretch
+    # ends at ``_WEDGE_STRETCH_MAX`` configured deadlines: strike 2, the
+    # client redirect, then comes at four, inside a client's reply
+    # time-out, and a coordinator whose waves grow cannot talk its
+    # detector out of detecting. At light load a wave is milliseconds
+    # and the deadline is the configured one
+    _WEDGE_WAVES = 16
+    _WEDGE_STRETCH_MAX = 2.0
 
     def __init__(
         self,
@@ -578,9 +599,15 @@ class BatchCoordinator:
             self._state_lock, "ra/step/lock_wait", node_name)
         self._egress_lock = _SpanLock(
             self._state_lock, "ra/egress/lock_wait", node_name)
+        self._wal_lock = _SpanLock(
+            self._state_lock, "ra/wal/batch/notify/lock_wait", node_name)
         # turns of the step loop, and which of them read the thread clock
         self._cpu_turn = 0
         self._cpu_mask = (1 << self._CPU_SAMPLE_SHIFT) - 1
+        # a wave's duration on ``self.clock`` (the watchdog's clock),
+        # smoothed over eight: one float, written by the realising
+        # thread and read by the detector's
+        self._wave_s = 0.0
 
         self.registry = nodes or node_registry()
         self.transport = InProcTransport(node_name, self.registry)
@@ -784,8 +811,9 @@ class BatchCoordinator:
         ack it emits is exactly the ack the step-loop path would have
         emitted one wave later."""
         route_out: Dict[str, List] = {}
-        staged = False
-        with self._state_lock:
+        n_written = 0
+        t_ask = time.perf_counter_ns()
+        with self._wal_lock:
             by_get = self.by_name.get
             sw = self._staged_written
             for uid, evt in items:
@@ -795,6 +823,7 @@ class BatchCoordinator:
                 if not (type(evt) is tuple and evt and evt[0] == "written"):
                     self.deliver((uid, self.name), ("log_event", evt), None)
                     continue
+                n_written += 1
                 g.log.handle_event(evt)
                 wi, wt = g.log.last_written()
                 # the device learns the durable watermark at the next
@@ -802,7 +831,6 @@ class BatchCoordinator:
                 # quorum scan)
                 if sw.get(g.gid, 0) < wi:
                     sw[g.gid] = wi
-                staged = True
                 if g.pending_ack is not None and wi >= g.pending_ack[1]:
                     leader_sid, cover = g.pending_ack
                     g.pending_ack = None
@@ -817,6 +845,14 @@ class BatchCoordinator:
                                             at if at is not None else wt),
                          (g.name, self.name))
                     )
+            # the round's accounts, written under the lock every writer
+            # of them holds
+            cnt = self.counters
+            t_held = self._wal_lock.t_held
+            cnt.incr("wal_notify_batches")
+            cnt.incr("wal_notify_events", n_written)
+            cnt.incr("wal_notify_wait_ns", t_held - t_ask)
+            cnt.incr("wal_notify_hold_ns", time.perf_counter_ns() - t_held)
         for node_name, msgs in route_out.items():
             self._send_batch(node_name, msgs)
         # wake the step thread only when the staged watermark is
@@ -827,7 +863,7 @@ class BatchCoordinator:
         # ticket realises, the egress thread's own _have_work check
         # sees the staged state and wakes the loop (its inflight
         # decrement precedes that check, so no release is ever missed).
-        if staged and self._have_work() and not self._wake.is_set():
+        if n_written and self._have_work() and not self._wake.is_set():
             self._wake.set()
 
     def deliver_many(self, msgs) -> None:
@@ -1542,7 +1578,7 @@ class BatchCoordinator:
 
         __slots__ = ("eg_packed", "consumed", "act", "aer_dirty", "rare",
                      "mbox_buf", "t_in", "t_drain", "t_pack", "stepped",
-                     "aer0_ns", "aer0_cpu_ns", "cpu")
+                     "aer0_ns", "aer0_cpu_ns", "cpu", "wave0")
 
         def __init__(self, **kw):
             for k in self.__slots__:
@@ -1773,11 +1809,12 @@ class BatchCoordinator:
                 elif name in by:
                     radd(trip)
 
-    def _ingest(self, n_items, cmd_q, routes, lows):
+    def _ingest(self, n_items, cmd_q, routes, lows, tr=False):
         """Route one classified burst under the state lock: messages to
         their handlers, commands into the logs and the WAL queue, the
         appended runs and durable watermarks into the staged scatter
-        dicts. Returns ``(n_items, rare, aer_dirty)``."""
+        dicts. Returns ``(n_items, rare, aer_dirty)``. ``tr``: a
+        profiler session takes the spans."""
         # fold the step/egress threads' own must-deliver self-publishes
         # (machine Append/Aux effects realized under the state lock —
         # including by the prev-ticket finish that just ran): they are
@@ -1853,13 +1890,25 @@ class BatchCoordinator:
         if route_out:
             for node_name, msgs in route_out.items():
                 self._send_batch(node_name, msgs)
-        if cmd_q:
-            for name, cmds in cmd_q.items():
-                g = by_get(name)
-                if g is not None:
-                    self._handle_commands(g, cmds, appended, written, aer_dirty)
-        if self._low_dirty:
-            self._drain_low_lane(appended, written, aer_dirty)
+        if cmd_q or self._low_dirty:
+            # the pass's client commands into the logs and the WAL
+            # queue: sub-phase ingest_append, one record for all groups
+            if tr:
+                sp = _obs.begin("ra/step/ingress_drain/ingest_append",
+                                node=self.name)
+            _t_app = time.perf_counter_ns()
+            if cmd_q:
+                for name, cmds in cmd_q.items():
+                    g = by_get(name)
+                    if g is not None:
+                        self._handle_commands(g, cmds, appended, written,
+                                              aer_dirty)
+            if self._low_dirty:
+                self._drain_low_lane(appended, written, aer_dirty)
+            self._wave_h["ingest_append"].record(
+                time.perf_counter_ns() - _t_app)
+            if tr:
+                _obs.end(sp)
         return n_items, rare, aer_dirty
 
     def _drain_and_dispatch(
@@ -1870,6 +1919,7 @@ class BatchCoordinator:
         # (drivers pre-classify so the heavy classification never
         # blocks the WAL writer)
         (tr, _t_in, _c_in, _t_cls), n_items, cmd_q, routes, lows = pre
+        wave0 = self.clock.monotonic()
         node = self.name
         wh = self._wave_h
         cnt = self.counters
@@ -1877,7 +1927,8 @@ class BatchCoordinator:
         shift = self._CPU_SAMPLE_SHIFT
         if tr:
             sp = _obs.begin("ra/step/ingress_drain", node=node)
-        n_items, rare, aer_dirty = self._ingest(n_items, cmd_q, routes, lows)
+        n_items, rare, aer_dirty = self._ingest(n_items, cmd_q, routes, lows,
+                                                tr)
         if tr:
             _obs.end(sp)
         appended = self._staged_app
@@ -2021,7 +2072,7 @@ class BatchCoordinator:
             eg_packed=eg_packed, consumed=consumed, act=act_np,
             aer_dirty=aer_dirty, rare=rare, mbox_buf=mbox_buf, t_in=_t_in,
             t_drain=_t_drain, t_pack=_t_pack, stepped=stepped,
-            aer0_ns=aer0_ns, aer0_cpu_ns=aer0_cpu_ns, cpu=cpu,
+            aer0_ns=aer0_ns, aer0_cpu_ns=aer0_cpu_ns, cpu=cpu, wave0=wave0,
         )
 
     def _scatter_staged(self, appended, written):
@@ -2150,11 +2201,14 @@ class BatchCoordinator:
         # (recorded at dispatch time); device_step runs from the
         # dispatch to here, and its three sub-phases add up to it;
         # host_egress includes the rare paths, apply and client replies
-        # (apply also gets its own histogram). The dispatching pass's
+        # (the step's applies also get egress_apply, one sampled group's
+        # apply its own histogram). The dispatching pass's
         # own AER fan-out is booked here too, so that aer_fanout and
         # its CPU account have one writer.
         wh = self._wave_h
         if eg_np is not None:
+            self._wave_s += (
+                self.clock.monotonic() - ticket.wave0 - self._wave_s) / 8
             wh["ticket_queue"].record(t_pop - ticket.t_pack)
             wh["egress_sync"].record(t_sync - t_pop)
             wh["egress_lock_wait"].record(_t_dev - t_sync)
@@ -3084,6 +3138,10 @@ class BatchCoordinator:
             nh2_l = needs_host[ti].tolist()
             ag_l = eg["agreed_idx"][ti].tolist()
             now_roles = self.clock.monotonic()
+            # machine apply and client replies of every group this step
+            # committed: each in its place, their clock pairs added up
+            apply_ns = 0
+            clock_ns = time.perf_counter_ns
             for p, pos in enumerate(touched):
                 i = pos if act is None else int(act[pos])
                 g = groups[i]
@@ -3129,7 +3187,9 @@ class BatchCoordinator:
                     self._on_became_leader(g, aer_dirty)
                 ci2 = ca_l[p]
                 if ci2 > g.last_applied:
+                    _t_app = clock_ns()
                     self._apply_group(g, ci2)
+                    apply_ns += clock_ns() - _t_app
                     aer_dirty.add(i)
                 if nh2_l[p] and g.host_term_hint is None:
                     # quorum term lookup outside the device window (the
@@ -3141,6 +3201,9 @@ class BatchCoordinator:
                     if t2 is not None:
                         g.host_term_hint = (agreed, t2)
                         self._hot.add(i)
+            if apply_ns:
+                # sub-phase egress_apply: the step's applies, one record
+                self._wave_h["egress_apply"].record(apply_ns)
 
         for node_name, msgs in outbound.items():
             self._send_batch(node_name, msgs)
@@ -4892,11 +4955,20 @@ class BatchCoordinator:
         """Per-command-deadline lane watchdog (runs on the detector
         thread, once per tick): a group holding pending client futures
         whose apply floor AND oldest pending index both sat still for
-        ``command_deadline_s`` is a wedged lane. Strike 1 recovers
+        ``command_deadline_s`` (or ``_WEDGE_WAVES`` of this
+        coordinator's waves if that is longer, up to
+        ``_WEDGE_STRETCH_MAX`` deadlines) is a wedged lane.
+        Strike 1 recovers
         (device re-step + peer resync probe); a further strike bounds
         the failure by redirecting the stuck clients. Turns the round-5
         class of bug (accepted command, no commit, silent 10 s client
-        hang) into a detected, counted, bounded event."""
+        hang) into a detected, counted, bounded event. Gauges
+        ``lane_deadline_ms`` and ``lane_stall_max_ms`` say how near to
+        being struck the lanes have come."""
+        deadline = min(
+            max(self.command_deadline_s, self._WEDGE_WAVES * self._wave_s),
+            self._WEDGE_STRETCH_MAX * self.command_deadline_s)
+        stall_max = 0.0
         for i in range(self.n_groups):
             g = self.groups[i]
             if g is None:
@@ -4913,7 +4985,10 @@ class BatchCoordinator:
             if st is None or st[0] != g.last_applied or st[1] != oldest:
                 lane_watch[i] = (g.last_applied, oldest, now0, 0)
                 continue
-            if now0 - st[2] <= self.command_deadline_s:
+            still = now0 - st[2]
+            if still > stall_max:
+                stall_max = still
+            if still <= deadline:
                 continue
             strikes = st[3] + 1
             lane_watch[i] = (g.last_applied, oldest, now0, strikes)
@@ -4934,6 +5009,10 @@ class BatchCoordinator:
                 ("lane_recover",) if strikes == 1 else ("lane_fail",),
                 None,
             )
+        cnt = self.counters
+        cnt.put("lane_deadline_ms", round(deadline * 1e3))
+        if stall_max * 1e3 > cnt.get("lane_stall_max_ms"):
+            cnt.put("lane_stall_max_ms", round(stall_max * 1e3))
 
     def _health_scan(self, now: float) -> None:
         """Per-group health pass (docs/INTERNALS.md §14), once per tick

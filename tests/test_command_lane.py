@@ -392,6 +392,83 @@ def test_watchdog_bounds_wedged_lane(mode):
             c.stop()
 
 
+@pytest.mark.parametrize("wave_s,quiet_at,struck_at,deadline_ms", [
+    (0.001, (0.2,), 0.35, 300),       # light load: the configured deadline
+    (0.025, (0.2, 0.39), 0.45, 400),  # sixteen waves of 25 ms
+    (0.1, (0.35, 0.59), 0.65, 600),   # sixteen would be 1.6 s: capped at two
+    (3.0, (0.59,), 0.65, 600),        # waves that grow cannot unbound it
+])
+def test_watchdog_deadline_follows_the_wave_up_to_two_deadlines(
+        wave_s, quiet_at, struck_at, deadline_ms):
+    """Where the coordinator's own wave is long (a saturated fleet's
+    lasts a second), a lane still for ``command_deadline_s`` is slow,
+    not wedged: the deadline stretches to sixteen waves, and never past
+    two configured deadlines. The gauges say what it was and how long
+    a lane has been seen standing still."""
+    c = BatchCoordinator("wd_wave", capacity=8, num_peers=3,
+                         command_deadline_s=0.3)
+    try:
+        c.add_group("g", "cl", [("g", "wd_wave")], DictKv())
+        g = c.by_name["g"]
+        g.pending_replies[7] = api.Future()
+        c._wave_s = wave_s
+        watch = {}
+        c._lane_watchdog(watch, 0.0)  # first seen still
+        for now in quiet_at:
+            c._lane_watchdog(watch, now)
+            assert c.counters.get("lane_wedges") == 0, now
+        assert c.counters.get("lane_deadline_ms") == deadline_ms
+        # the longest it has seen the lane still, at or under the deadline
+        assert c.counters.get("lane_stall_max_ms") == round(quiet_at[-1] * 1e3)
+        c._lane_watchdog(watch, struck_at)
+        assert c.counters.get("lane_wedges") == 1
+        assert c.counters.get("lane_stall_max_ms") == round(struck_at * 1e3)
+    finally:
+        c.stop()
+
+
+def test_started_loops_measure_their_wave_for_the_watchdog():
+    """The live path: a started cluster that commits commands moves
+    ``_wave_s`` on every node that stepped (written by the realising
+    thread, read by the detector's), its watchdog publishes the deadline
+    it used, and at this light load that is the configured one."""
+    names = [f"wv{i}" for i in range(3)]
+    coords = [
+        BatchCoordinator(nm, capacity=8, num_peers=3,
+                         election_timeout_s=0.05, detector_poll_s=0.02,
+                         tick_interval_s=0.05, command_deadline_s=2.0)
+        for nm in names
+    ]
+    ids = [("g", nm) for nm in names]
+    try:
+        for c in coords:
+            c.add_group("g", "cl", ids, DictKv())
+            c.start()
+        coords[0].deliver(ids[0], ElectionTimeout(), None)
+        deadline = time.monotonic() + 20
+        while coords[0].by_name["g"].role != C.R_LEADER:
+            assert time.monotonic() < deadline, "no leader"
+            time.sleep(0.01)
+        for k in range(5):
+            fut = api.Future()
+            coords[0].deliver(
+                ids[0],
+                Command(kind=USR, data=("put", "k", k),
+                        reply_mode="await_consensus", from_ref=fut),
+                None,
+            )
+            assert fut.result(timeout=10)[0] == "ok"
+        time.sleep(0.15)  # a few watchdog ticks
+        for c in coords:
+            assert 0.0 < c._wave_s < 1.0, (c.name, c._wave_s)
+            assert c.counters.get("lane_deadline_ms") == 2000
+        assert coords[0].counters.get("lane_wedges") == 0
+        assert coords[0].counters.get("lane_stall_max_ms") < 2000
+    finally:
+        for c in coords:
+            c.stop()
+
+
 # -- election-duel damping --------------------------------------------------
 
 

@@ -39,7 +39,8 @@ WRITES = 150
 
 SPANS = {
     "step": {"ra/step/classify", "ra/step/lock_wait", "ra/step/ingress_drain",
-             "ra/step/host_pack", "ra/step/host_pack/scatter_dispatch",
+             "ra/step/ingress_drain/ingest_append", "ra/step/host_pack",
+             "ra/step/host_pack/scatter_dispatch",
              "ra/step/host_pack/mailbox_build",
              "ra/step/host_pack/step_dispatch", "ra/step/aer_fanout",
              "ra/step/idle"},
@@ -48,7 +49,8 @@ SPANS = {
                "ra/egress/aer_fanout"},
     "send": {"ra/send/batch"},
     "detect": {"ra/detect/scan"},
-    "wal": {"ra/wal/batch", "ra/wal/batch/write", "ra/wal/batch/notify"},
+    "wal": {"ra/wal/batch", "ra/wal/batch/write", "ra/wal/batch/notify",
+            "ra/wal/batch/notify/lock_wait"},
     "segw": {"ra/segw/flush"},
     "caller": {"ra/api/process_command", "ra/api/consistent_query",
                "ra/kv/get"},
@@ -219,6 +221,35 @@ def test_host_pack_and_ingress_sub_phases_stay_inside(traced):
     assert abs(sum(wave[p][1] for p in parts) - total) <= 0.05 * total
     assert wave["step_lock_wait"][0] == wave["ingress_drain"][0]
     assert wave["step_lock_wait"][1] <= wave["ingress_drain"][1]
+    # one record per pass that had client commands, per step that
+    # committed: all groups of the pass, inside the phase
+    for part, whole in (("ingest_append", "ingress_drain"),
+                        ("egress_apply", "host_egress")):
+        assert 0 < wave[part][0] <= wave[whole][0]
+        assert 0 < wave[part][1] <= wave[whole][1]
+    assert wave["ingest_append"][0] <= WRITES  # every put on its own pass
+    # ingest_append is one stretch of a pass and has its span (the
+    # histograms were read just outside the profiler session);
+    # egress_apply adds up applies that lie apart, and has none
+    n = len(traced["spans"]["ra/step/ingress_drain/ingest_append"])
+    assert 0.9 * wave["ingest_append"][0] <= n <= wave["ingest_append"][0]
+    assert not any("egress_apply" in name for name in traced["spans"])
+
+
+def test_wal_notify_accounts_one_round_per_batch(traced):
+    cnt, got = traced["counters"], traced["spans"]
+    rounds = cnt["wal_notify_batches"]
+    assert rounds > 0
+    # a round per WAL batch that had written events to deliver, each
+    # with its wait as a span under the batch's notify
+    assert rounds <= len(got["ra/wal/batch/notify"]) + 3
+    assert 0.9 * rounds <= len(got["ra/wal/batch/notify/lock_wait"]) <= rounds
+    # every put is written on a quorum before the next is sent (a
+    # lagging third replica may cover two with one event); a round may
+    # carry several events
+    assert cnt["wal_notify_events"] >= 2 * WRITES
+    assert cnt["wal_notify_events"] >= rounds
+    assert cnt["wal_notify_wait_ns"] >= 0 and cnt["wal_notify_hold_ns"] > 0
 
 
 def test_thread_cpu_never_exceeds_the_wall_phase(traced):
