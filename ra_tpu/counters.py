@@ -245,6 +245,18 @@ COORDINATOR_FIELDS: List[FieldSpec] = [
      "(inside every append_durable)"),
     ("wal_notify_hold_ns", "counter",
      "the state lock held for those rounds, held -> released, summed"),
+    # -- the cyclic collector while the process serves (runtime/heap.py:
+    # one collector hook, booked on ONE started coordinator of the
+    # process, because a pause stops every node in it)
+    ("gc_collections", "counter",
+     "collections of the cyclic collector, any generation, while a "
+     "coordinator of this process was started (on the first started "
+     "one only: do not add the nodes of a process up twice)"),
+    ("gc_full_collections", "counter",
+     "those of the oldest generation (the unfrozen heap walked whole)"),
+    ("gc_pause_ns", "counter",
+     "time inside those collections, every Python thread stopped, "
+     "summed (perf_counter_ns, one pair a collection)"),
     ("egress_thread_batches", "counter",
      "per-destination message batches shipped by the dedicated egress "
      "sender thread (off the step loop)"),

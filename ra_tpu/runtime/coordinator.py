@@ -98,6 +98,7 @@ from ra_tpu.protocol import (
     ServerId,
     USR,
 )
+from ra_tpu.runtime import heap as _heap
 from ra_tpu.runtime.transport import InProcTransport, NodeRegistry, registry as node_registry
 
 logger = logging.getLogger("ra_tpu")
@@ -932,6 +933,9 @@ class BatchCoordinator:
     def start(self) -> None:
         if not self._started:
             self._started = True
+            # the collector's policy of a serving process: what is
+            # built is frozen, the young generations fit a wave
+            _heap.enter(self)
             self._step_thread.start()
             self._detector.start()
 
@@ -1026,6 +1030,7 @@ class BatchCoordinator:
             # a device fetch at interpreter exit can crash the XLA
             # runtime's C++ teardown
             self._detector.join(timeout=5)
+        _heap.leave(self)  # the last coordinator out gives it back
         from ra_tpu import counters as _counters
         from ra_tpu import health as _health
 

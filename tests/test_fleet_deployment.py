@@ -3,7 +3,7 @@
 three started, WAL-backed coordinators, one command in flight per
 group, judged by ``reference/ra_bench.py``. Holds the accounts that only
 a busy fleet works (ISSUE 26) to what the run really did: the WAL
-writers' state-lock rounds, the two per-pass sub-phases, and the six
+writers' state-lock rounds, the two per-pass sub-phases, and the seven
 per-layer readers that read them.
 
 The cell is read from ``BENCHMARK.json``, as the driver reads it; the
@@ -35,7 +35,7 @@ WINDOW_S = 2.0
 NODES = ("bench0", "bench1", "bench2")
 READERS = ("step_roofline", "full_width_steps_pct", "wal_entries_per_fsync",
            "wal_notify_wait_ms_per_kop", "ingest_append_ms_per_kop",
-           "egress_apply_ms_per_kop")
+           "egress_apply_ms_per_kop", "gc_pause_ms_per_kop")
 
 
 def _bench():
@@ -234,11 +234,11 @@ def test_reader_reads_a_float_here_and_nothing_from_an_empty_run(
     # a program without the new accounts (the parent's) reads as nothing
     # where the metric needs them, never as an error
     if name in ("wal_notify_wait_ms_per_kop", "ingest_append_ms_per_kop",
-                "egress_apply_ms_per_kop"):
+                "egress_apply_ms_per_kop", "gc_pause_ms_per_kop"):
         def without(snap):
             return {**snap,
                     "coordinator": {k: v for k, v in snap["coordinator"].items()
-                                    if not k.startswith("wal_notify_")},
+                                    if not k.startswith(("wal_notify_", "gc_"))},
                     "wave": {k: v for k, v in snap["wave"].items()
                              if k not in ("ingest_append", "egress_apply")}}
 
