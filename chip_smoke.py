@@ -522,17 +522,55 @@ def check_pallas(groups: int, seed: int) -> dict:
     return {"groups": groups, "peers": [3, 5, 7]}
 
 
-def phase_kernels(groups: int, seed: int, stats: CompileStats) -> None:
-    from bench import bench_decisions
+def bench_decisions(groups: int, steps: int) -> dict:
+    """``steps`` fused decision steps in one ``lax.scan`` on the device,
+    every group taking one AER a step: a count and the seconds the
+    second (compiled) run took."""
+    import jax
+    import jax.numpy as jnp
 
+    from ra_tpu.ops.consensus import (
+        MSG_AER,
+        consensus_step_impl,
+        empty_mailbox,
+        make_group_state,
+    )
+
+    G, T = groups, steps
+    state = make_group_state(G, PEERS)
+    mbox = empty_mailbox(G)._replace(
+        msg_type=jnp.full((G,), MSG_AER, jnp.int32),
+        term=jnp.ones((G,), jnp.int32),
+        num_entries=jnp.ones((G,), jnp.int32),
+        entries_last_term=jnp.ones((G,), jnp.int32),
+    )
+
+    def many_steps(state, mbox):
+        def body(st, _):
+            mb = mbox._replace(prev_idx=st.last_index, prev_term=st.last_term)
+            st2, eg = consensus_step_impl(st, mb)
+            return st2, eg.success.sum()
+
+        return jax.lax.scan(body, state, None, length=T)
+
+    run = jax.jit(many_steps, donate_argnums=(0,))
+    st, sums = run(jax.tree.map(jnp.copy, state), mbox)
+    jax.block_until_ready(sums)
+    t0 = time.perf_counter()
+    st, sums = run(jax.tree.map(jnp.copy, state), mbox)
+    jax.block_until_ready(sums)
+    dt = time.perf_counter() - t0
+    return {"decisions": G * T, "seconds": round(dt, 6)}
+
+
+def phase_kernels(groups: int, seed: int, stats: CompileStats) -> None:
     mark, t0 = stats.mark(), time.perf_counter()
     variants = check_step_variants(groups, PEERS, seed)
     pallas = check_pallas(groups, seed)
     dec = bench_decisions(groups, 200)
     emit(
         "kernels", step_variants=variants, pallas_compiled=pallas,
-        bench_decisions={"decisions": dec["decisions"],
-                         "seconds": dec["seconds"]},
+        bench_decisions=dec,
         seconds=round(time.perf_counter() - t0, 3), **stats.since(mark),
     )
 
@@ -571,6 +609,46 @@ def fsync_median_ms(directory: str, n: int = 32) -> float:
     return statistics.median(took)
 
 
+def wal_storage(coords, base: str):
+    """Put every coordinator of ``coords`` on real storage under
+    ``base/<coordinator name>``: one shared WAL + segment writer + table
+    registry per coordinator, so every group's appends ride the same
+    file and the same batched fsync (one gen_batch_server WAL per
+    system, docs/internals/INTERNALS.md:16-19), written events handled
+    on the WAL writer thread itself (docs/INTERNALS.md §15). Returns
+    ``(storage, mk_log)``: ``storage`` rows are ``(tables, wal,
+    segment_writer, dir)`` in ``coords`` order (hand them to
+    ``close_storage``), and ``mk_log(i, uid)`` opens group ``uid``'s
+    ``Log`` on coordinator ``i``'s WAL."""
+    from ra_tpu.log.log import Log
+    from ra_tpu.log.segment_writer import SegmentWriter
+    from ra_tpu.log.tables import TableRegistry
+    from ra_tpu.log.wal import Wal
+
+    storage = []
+    for c in coords:
+        d = os.path.join(base, c.name)
+        tables = TableRegistry()
+        sw = SegmentWriter(os.path.join(d, "data"), tables, c.wal_notify)
+        w = Wal(os.path.join(d, "wal"), tables, c.wal_notify,
+                segment_writer=sw)
+        # bulk written-event channel: one lock round per fsync batch
+        w.notify_many = c.wal_notify_many
+        storage.append((tables, w, sw, d))
+
+    def mk_log(i, uid):
+        tables, w, _sw, d = storage[i]
+        return Log(uid, os.path.join(d, "data", uid), tables, w)
+
+    return storage, mk_log
+
+
+def close_storage(storage) -> None:
+    for _tables, w, sw, _d in storage:
+        w.close()
+        sw.close()
+
+
 def _wait(done, budget_s: float, what: str, poll_s: float = 0.02) -> float:
     """Poll ``done`` (sparingly: the poller shares the interpreter lock
     with the coordinators' threads) until it holds; seconds waited."""
@@ -589,7 +667,6 @@ def phase_cluster(groups: int, per_group: int, seed: int, workdir: str,
     plain fold on all three replicas. Returns the phase record."""
     import numpy as np
 
-    from bench import close_storage, wal_storage
     from ra_tpu import api, leaderboard, obs
     from ra_tpu.machine import SimpleMachine
     from ra_tpu.ops import consensus as C

@@ -196,14 +196,13 @@ COORDINATOR_FIELDS: List[FieldSpec] = [
     ("read_local_bounded", "counter",
      "local queries served under an explicit max_staleness_s bound"),
     ("pipeline_steps", "counter",
-     "device steps dispatched via the pipelined wave loop (stage/"
-     "finish drivers or the started two-stage loop); pair with "
-     "pipeline_overlap_ns for how much host work each hid"),
+     "device steps dispatched by the started two-stage wave loop; "
+     "pair with pipeline_overlap_ns for how much host work each hid"),
     ("pipeline_overlap_ns", "counter",
      "host staging time (ingress drain + pack + dispatch) spent while "
      "a previous step's device compute / egress realisation was still "
-     "in flight — the overlap the pipelined wave loop creates; 0 on "
-     "the sequential loop (docs/INTERNALS.md §15)"),
+     "in flight — the overlap the started wave loop creates; 0 "
+     "under step_once (docs/INTERNALS.md §15)"),
     # -- async command plane (docs/INTERNALS.md §16) --------------------
     ("ingress_ring_msgs", "counter",
      "items drained from the lock-free ingress rings (a bulk fan-out "

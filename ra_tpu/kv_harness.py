@@ -156,7 +156,6 @@ def run(
     disk_full: bool = False,
     slow_disk: bool = False,
     overload: bool = False,
-    rings: bool = True,
     workload: str = "kv",
     combined: bool = False,
     native: str = "auto",
@@ -183,14 +182,11 @@ def run(
     own seeded rng, so the nemesis schedule is replayable from the seed
     alone. ``workload`` picks the machine under test ("kv" | "fifo").
 
-    ``rings=False`` runs the batch backend on the lock+deque control
-    command plane instead of the lock-free ingress rings (docs/
-    INTERNALS.md §16) — the soak's A/B escape hatch; the actor backend
-    ignores it. ``native`` selects the batch coordinator's native
-    hot-loop runtime paths (docs/INTERNALS.md §18; "auto"/"off" or a
-    comma list of pack,classify,egress) — the soak grid runs both so
-    the disk-fault/torn-write failpoints are proven to bite through the
-    native fallback seam.
+    ``native`` selects the batch coordinator's native hot-loop runtime
+    paths (docs/INTERNALS.md §18; "auto"/"off" or a comma list of
+    pack,classify,egress) — the soak grid runs both so the disk-fault/
+    torn-write failpoints are proven to bite through the native
+    fallback seam.
 
     ``disk_full=True`` adds the storage-pressure survival dimension
     (docs/INTERNALS.md §21): persistent ENOSPC/EDQUOT storms against a
@@ -234,7 +230,7 @@ def run(
                           op_timeout, rescue, restarts=restarts,
                           disk_faults=disk_faults, disk_full=disk_full,
                           slow_disk=slow_disk, data_dir=data_dir,
-                          overload=overload, rings=rings, workload=workload,
+                          overload=overload, workload=workload,
                           combined=combined, native=native, lease=lease)
     raise ValueError(f"unknown backend {backend!r}")
 
@@ -1076,7 +1072,7 @@ def _dump_on_failure(failures, label: str, anomalies=None,
 def _run_batch(seed, n_ops, nodes, partitions, membership, op_timeout,
                rescue=False, restarts=False, disk_faults=False,
                disk_full=False, slow_disk=False,
-               data_dir=None, overload=False, rings=True, workload="kv",
+               data_dir=None, overload=False, workload="kv",
                combined=False, native="auto", lease=False) -> HarnessResult:
     import tempfile
 
@@ -1177,7 +1173,6 @@ def _run_batch(seed, n_ops, nodes, partitions, membership, op_timeout,
             meta=storage[n]["meta"] if use_disk else None,
             max_command_backlog=(
                 _OVERLOAD_BACKLOG if (overload or combined) else 4096),
-            rings=rings,
             native=native,
             send_msg_cb=fifo_sink,
             lease=lease,
@@ -1586,9 +1581,6 @@ if __name__ == "__main__":  # pragma: no cover — ops entry point
     ap.add_argument("--no-membership", dest="membership",
                     action="store_false", default=True,
                     help="drop the membership-churn dimension")
-    ap.add_argument("--rings", choices=("on", "off"), default="on",
-                    help="off: batch backend runs the lock+deque "
-                         "control command plane (A/B escape hatch)")
     ap.add_argument("--native", default="auto",
                     help="batch backend native hot-loop runtime paths: "
                          "auto (default), off, or a comma list of "
@@ -1603,7 +1595,7 @@ if __name__ == "__main__":  # pragma: no cover — ops entry point
               restarts=args.restarts, disk_faults=args.disk_faults,
               disk_full=args.disk_full, slow_disk=args.slow_disk,
               partitions=args.partitions, membership=args.membership,
-              overload=args.overload, rings=args.rings == "on",
+              overload=args.overload,
               workload=args.workload, combined=args.combined,
               native=args.native, lease=args.lease)
     print(f"ops={res.ops} consistent={res.consistent}")

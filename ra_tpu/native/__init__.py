@@ -348,7 +348,7 @@ def crc32(data: bytes) -> Optional[int]:
 # -- hot-loop runtime bindings (rt_native.so) -------------------------------
 
 # number of ring item classes (ra_tpu.protocol RC_* codes)
-N_CLASSES = 6
+N_CLASSES = 4
 
 
 def classify(codes, n: int) -> Optional[Tuple[np.ndarray, np.ndarray]]:

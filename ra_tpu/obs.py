@@ -263,8 +263,7 @@ def histogram(name, help: str = "", unit: str = "ns",
 # WAVE_STEP_PHASES are DISJOINT slices of one coordinator step — they
 # sum to the step-loop wall time and are the share denominator in
 # attribution tools; WAVE_SUBSET_PHASES are finer-grained views RECORDED
-# WITHIN a step phase (never added to the denominator). profile_wave.py
-# derives its tables from these, so a new phase lands there for free.
+# WITHIN a step phase (never added to the denominator).
 WAVE_STEP_PHASES = (
     ("ingress_drain", "drain ingress queues + route messages + append "
                       "client commands (includes WAL handoff)"),

@@ -397,6 +397,6 @@ class FromPeer:
 # §18). Producers stamp one per published ring item so the native
 # drain-classify pass (ra_tpu.native.classify) can partition a drained
 # burst with the GIL released; the Python routing half walks the
-# partitions. RC_CMD_LOW / RC_CMDS_LOW carry the producer-side priority
-# split that the classify loop would otherwise compute per item.
-RC_MSG, RC_CMD, RC_CMD_LOW, RC_CMDS, RC_CMDS_LOW, RC_BATCH = range(6)
+# partitions. RC_CMD_LOW carries the producer-side priority split that
+# the classify loop would otherwise compute per item.
+RC_MSG, RC_CMD, RC_CMD_LOW, RC_BATCH = range(4)

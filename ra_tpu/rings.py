@@ -23,7 +23,6 @@ control messages) — a full ring NEVER silently drops.
 from __future__ import annotations
 
 import threading
-from collections import deque
 from typing import Dict, List, Optional
 
 import numpy as np
@@ -163,7 +162,7 @@ class IngressRings:
     ``wake`` (a threading.Event) is set after every successful publish:
     the publish-then-set order plus the consumer's clear-then-check-
     then-wait order makes lost wakeups impossible (see the step-loop
-    idle protocol in coordinator._run_pipelined).
+    idle protocol in coordinator._run).
     """
 
     def __init__(self, lane_slots: int = 8192,
@@ -266,45 +265,3 @@ class IngressRings:
             if pruned:
                 self._lane_list = list(self._lanes.values())
         return pruned
-
-
-class LockedLanes:
-    """Condition-free lock+deque control implementation of the same
-    interface — the ``rings=off`` A/B control (the pre-ring command
-    plane's single guarded queue, minus its 50 ms timed polls so the
-    control isolates the ring/lock difference, not the wakeup change).
-    Unbounded, like the deque it replaces."""
-
-    def __init__(self, lane_slots: int = 8192,
-                 wake: Optional[threading.Event] = None,
-                 max_lanes: Optional[int] = None):
-        self._lock = threading.Lock()
-        self._q: deque = deque()
-        self._qc: deque = deque()  # class-code sidecar, in step with _q
-        self._wake = wake
-
-    def publish(self, item, code: int = 0) -> bool:
-        with self._lock:
-            self._q.append(item)
-            self._qc.append(code)
-        w = self._wake
-        if w is not None and not w.is_set():
-            w.set()
-        return True
-
-    def drain(self, out: List, codes: Optional[bytearray] = None) -> int:
-        with self._lock:
-            n = len(self._q)
-            if n:
-                out.extend(self._q)
-                self._q.clear()
-                if codes is not None:
-                    codes.extend(self._qc)
-                self._qc.clear()
-        return n
-
-    def pending(self) -> bool:
-        return bool(self._q)
-
-    def lanes(self) -> int:
-        return 1

@@ -83,8 +83,6 @@ from ra_tpu.protocol import (
     RC_BATCH,
     RC_CMD,
     RC_CMD_LOW,
-    RC_CMDS,
-    RC_CMDS_LOW,
     RC_MSG,
     REJECT_NOSPACE,
     REJECT_OVERLOADED,
@@ -344,7 +342,6 @@ class BatchCoordinator:
         node_name: str,
         capacity: int = 1024,
         num_peers: int = 3,
-        suffix_k: int = 32,
         nodes: Optional[NodeRegistry] = None,
         aer_batch_size: int = 128,
         election_timeout_s: float = 0.15,
@@ -358,10 +355,7 @@ class BatchCoordinator:
         max_pipeline_count: int = 4096,
         max_command_backlog: int = 4096,
         command_deadline_s: float = 5.0,
-        pipeline: bool = True,
-        rings: bool = True,
         ingress_ring_slots: int = 8192,
-        egress_async: bool = True,
         native: str = "auto",
         clock=None,
         lease: bool = False,
@@ -435,7 +429,7 @@ class BatchCoordinator:
         # compact gather of just the groups with pending device work
         # whenever they number at most capacity/4 (power-of-two padded
         # sub-batches), falling back to the full-width step at
-        # saturation; "always"/"never" pin a path (tests/bench). Step
+        # saturation; "always"/"never" pin a path (tests). Step
         # cost then scales with ACTIVITY, not capacity — a lone commit
         # round trip at 10k-group capacity no longer pays ~10 full-width
         # steps (the reference's per-group process wakes only on
@@ -444,7 +438,7 @@ class BatchCoordinator:
             raise ValueError(f"unknown active_set mode {active_set!r}")
         self.active_set = active_set
 
-        self.state = C.make_group_state(capacity, num_peers, suffix_k)
+        self.state = C.make_group_state(capacity, num_peers)
         # groups not yet registered must never act: mark inactive
         self.state = self.state._replace(
             active=jnp.zeros((capacity, num_peers), dtype=jnp.bool_),
@@ -482,15 +476,12 @@ class BatchCoordinator:
         # multi-lane pass by the step thread. No sender ever contends
         # with the step loop; the step thread blocks on _wake (an
         # Event set by every publish / WAL notify / egress realisation)
-        # instead of 50 ms timed polls. rings=False swaps in the
-        # lock+deque control implementation (the --rings=off A/B).
-        from ra_tpu.rings import IngressRings, LockedLanes, WaitGate
+        # instead of 50 ms timed polls.
+        from ra_tpu.rings import IngressRings, WaitGate
 
         self._wake = threading.Event()
-        ring_cls = IngressRings if rings else LockedLanes
-        self.rings = rings
-        self._rings = ring_cls(lane_slots=ingress_ring_slots,
-                               wake=self._wake)
+        self._rings = IngressRings(lane_slots=ingress_ring_slots,
+                                   wake=self._wake)
         # ring-full backpressure gate: opened on every drain that freed
         # space; ring-full-rejected clients wait on it instead of
         # sleeping (the ingress analog of the per-group admission gate)
@@ -500,7 +491,7 @@ class BatchCoordinator:
         # (api.process_command) instead of a fixed 10 ms sleep poll
         self._adm_gate = WaitGate()
         # idents of the threads that DRAIN the rings (step + egress loop
-        # threads, plus whichever thread is inside a cooperative step_*
+        # threads, plus whichever thread is inside a ``step_once``
         # call): a full-ring publish from one of these must divert to
         # _internal_q — gate-waiting would deadlock on itself
         self._drainer_idents: set = set()
@@ -547,14 +538,14 @@ class BatchCoordinator:
         # prezero only while full-width steps are the live shape (the
         # active-set sub path zeroes tiny buffers — not worth staging)
         self._prezero_useful = False
-        # dedicated egress sender thread (started pipelined loop only):
-        # AER/ack fan-out hands (node, msgs) batches to a bounded ring
-        # consumed off the step loop; overflow falls back to inline send
-        self._egress_async = egress_async
+        # dedicated egress sender thread (started loop only; a
+        # coordinator driven by ``step_once`` sends inline): AER/ack
+        # fan-out hands (node, msgs) batches to a bounded ring consumed
+        # off the step loop; overflow falls back to inline send
         self._egress_on = False
         self._egress_wake = threading.Event()
-        self._egress_rings = ring_cls(lane_slots=4096,
-                                      wake=self._egress_wake)
+        self._egress_rings = IngressRings(lane_slots=4096,
+                                          wake=self._egress_wake)
         self._sender_thread: Optional[threading.Thread] = None
         # clock-bound leader leases, vectorized over the group axis
         # (docs/INTERNALS.md §20): per-slot oldest-outstanding-send
@@ -587,8 +578,8 @@ class BatchCoordinator:
         # a build hands back the numpy buffer itself and the jitted step
         # takes it as its argument; the buffer returns to the pool only
         # after that step's egress sync (np.asarray) proves the program
-        # ran, which also holds where the backend aliases it. The
-        # sequential loop cycles one buffer; the pipelined loop keeps
+        # ran, which also holds where the backend aliases it.
+        # ``step_once`` cycles one buffer; the started loop keeps
         # one in flight while the next step packs the other — the pool
         # is bounded by the single-outstanding-ticket cap.
         self._mbox_pool: List[np.ndarray] = []
@@ -621,15 +612,14 @@ class BatchCoordinator:
         self.shard_moves = 0
         self.msgs_processed = 0
 
-        # pipelined wave loop (docs/INTERNALS.md §15): the threaded run
+        # pipelined wave loop (docs/INTERNALS.md §15): the started run
         # loop splits each step into host staging (ingress drain + pack
         # + device dispatch, step thread) and realisation (egress sync
         # + process + AER fan-out, egress thread), overlapping step
         # N+1's staging with step N's device compute / egress sync.
-        # ``step_once`` (tests, cooperative bench driver) is always the
-        # sequential two-halves-inline form; callers must not mix it
-        # with a STARTED pipelined loop (ticket order would invert).
-        self.pipeline = pipeline
+        # ``step_once`` (the tests' driver) is the sequential
+        # two-halves-inline form; callers must not mix it with a
+        # STARTED loop (ticket order would invert).
         self._pipe_cv = threading.Condition()
         self._pipe_q: deque = deque()
         self._pipe_inflight = 0  # tickets dispatched but not finished
@@ -640,8 +630,6 @@ class BatchCoordinator:
         # staged scatter dicts, their canonical form; AER fan-out
         # never parks — ingest passes ship it immediately)
         self._pending_rare: List[Tuple] = []
-        # outstanding ticket of the cooperative pipelined driver form
-        self._coop_ticket: Optional[BatchCoordinator._StepTicket] = None
         self._step_thread = threading.Thread(
             target=self._run, name=f"ra-batch-{node_name}", daemon=True
         )
@@ -661,9 +649,9 @@ class BatchCoordinator:
     def procs(self) -> Dict[str, Any]:
         return self.by_name
 
-    # ring item tags: generic message | single command | bulk command
-    # fan-out | per-node batch of (name, from_sid, msg) triples
-    _R_MSG, _R_CMD, _R_CMDS, _R_BATCH = 0, 1, 2, 3
+    # ring item tags: generic message | single command | per-node
+    # batch of (name, from_sid, msg) triples
+    _R_MSG, _R_CMD, _R_BATCH = 0, 1, 2
 
     def deliver(self, to: ServerId, msg: Any, from_sid: Optional[ServerId]) -> bool:
         """Lock-free ingress: publish onto this thread's SPSC lane. A
@@ -725,12 +713,12 @@ class BatchCoordinator:
 
     def _publish_blocking(self, item, code: int = RC_MSG) -> bool:
         """Bounded-wait publish for must-deliver BULK CLIENT traffic
-        (deliver_commands / deliver_many — the producers there are
-        client/driver threads, where waiting IS the backpressure): wait
-        on the ring gate (opened by every space-freeing drain) and
-        retry. A drainer thread (step/egress loop, or a cooperative
-        step_* call) must never gate-wait on itself — its must-deliver
-        traffic rides ``_internal_q`` into its own next drain instead.
+        (deliver_many — the producers there are client/driver threads,
+        where waiting IS the backpressure): wait on the ring gate
+        (opened by every space-freeing drain) and retry. A drainer
+        thread (step/egress loop, or a ``step_once`` call) must never
+        gate-wait on itself — its must-deliver traffic rides
+        ``_internal_q`` into its own next drain instead.
         Never used for traffic that may originate on ANOTHER
         coordinator's drainer thread (see _publish_overflow)."""
         if threading.get_ident() in self._drainer_idents:
@@ -746,9 +734,9 @@ class BatchCoordinator:
             self._ring_gate.waiter().wait(0.05)
         # still full after the bounded wait: in cooperative (non-
         # started) mode the only drainer may be THIS thread between
-        # step_* calls — spinning here would livelock until an external
-        # stop(). Fall back to the overflow queue: delivered on the
-        # next drain, never spun on, never shed.
+        # ``step_once`` calls — spinning here would livelock until an
+        # external stop(). Fall back to the overflow queue: delivered
+        # on the next drain, never spun on, never shed.
         return self._publish_overflow(item, code)
 
     def _publish_overflow(self, item, code: int = RC_MSG) -> bool:
@@ -778,17 +766,6 @@ class BatchCoordinator:
             self._internal_q.append((self._R_CMD, name, msg))
         else:
             self._internal_q.append((self._R_MSG, name, None, msg))
-
-    def deliver_commands(self, names, cmd: Command) -> None:
-        """Bulk ingress for ONE command fanned to many groups (the
-        pipelined-bench shape: one wave = the same no-op command to
-        every group leader). One ring slot for the whole wave; the
-        per-group regrouping runs at drain time on the step thread,
-        off every client lock. ``names`` must not be mutated after the
-        call. Blocks (gate-paced) when the lane is full — the bulk
-        producer is the natural place to absorb backpressure."""
-        code = RC_CMDS_LOW if cmd.priority == "low" else RC_CMDS
-        self._publish_bulk((self._R_CMDS, names, cmd), code)
 
     def wal_notify(self, uid: str, evt) -> None:
         """Log-event entry point for WAL / segment-writer notify
@@ -859,8 +836,8 @@ class BatchCoordinator:
         # wake the step thread only when the staged watermark is
         # actionable NOW: with a ticket in flight the idle predicate
         # ignores staged work (an ingest-only pass cannot scatter it),
-        # so an unconditional set here woke the loop for nothing — the
-        # spurious wakeups BENCH_THREADED recorded. When the in-flight
+        # so an unconditional set here woke the loop for nothing (the
+        # ``step_spurious_wakeups`` counter). When the in-flight
         # ticket realises, the egress thread's own _have_work check
         # sees the staged state and wakes the loop (its inflight
         # decrement precedes that check, so no release is ever missed).
@@ -870,20 +847,16 @@ class BatchCoordinator:
     def deliver_many(self, msgs) -> None:
         """Batch ingress: ONE ring slot for many ``(to_sid, msg,
         from_sid)`` triples (unknown group names are dropped at drain,
-        as in ``deliver``). Blocks gate-paced when the lane is full."""
-        triples = [(to[0], frm, m) for to, m, frm in msgs]
-        self._publish_bulk((self._R_BATCH, triples), RC_BATCH)
-
-    def _publish_bulk(self, item, code: int = RC_MSG) -> None:
-        """Bulk client publish: keep arrival order (never overtake
-        parked overflow work — the overflow queue folds after the lane
-        drain) WITHOUT giving up pacing. While overflow is pending,
-        gate-wait a bounded window for the drain to clear it; only if
-        it persists does the wave park on the overflow queue too —
-        producers stay paced at the gate cadence instead of appending
-        unbounded waves at line rate (the failure mode an unconditional
-        divert would reintroduce under exactly the overload the bounded
-        rings exist for)."""
+        as in ``deliver``). Blocks gate-paced when the lane is full.
+        Keeps arrival order (never overtakes parked overflow work — the
+        overflow queue folds after the lane drain) WITHOUT giving up
+        pacing: while overflow is pending, gate-wait a bounded window
+        for the drain to clear it; only if it persists does the wave
+        park on the overflow queue too — producers stay paced at the
+        gate cadence instead of appending unbounded waves at line rate
+        (the failure mode an unconditional divert would reintroduce
+        under exactly the overload the bounded rings exist for)."""
+        item = (self._R_BATCH, [(to[0], frm, m) for to, m, frm in msgs])
         if self._overflow_q:
             ident = threading.get_ident()
             for _ in range(4):
@@ -893,11 +866,11 @@ class BatchCoordinator:
                 if not self._overflow_q:
                     break
             if self._overflow_q:
-                self._publish_overflow(item, code)
+                self._publish_overflow(item, RC_BATCH)
                 return
-        if not self._rings.publish(item, code):
+        if not self._rings.publish(item, RC_BATCH):
             self.counters.incr("ingress_ring_full")
-            self._publish_blocking(item, code)
+            self._publish_blocking(item, RC_BATCH)
 
     def ingest_batch(self, triples) -> int:
         """Peer-coordinator bulk ingress (the _send_batch fast path):
@@ -1238,16 +1211,6 @@ class BatchCoordinator:
             self.counters.incr("step_spurious_wakeups")
 
     def _run(self) -> None:
-        self._drainer_idents.add(threading.get_ident())
-        if self.pipeline:
-            self._run_pipelined()
-            return
-        while self.running:
-            worked = self.step_once()
-            if not worked:
-                self._idle_wait()
-
-    def _run_pipelined(self) -> None:
         """Two-stage pipelined wave loop (docs/INTERNALS.md §15). This
         thread owns host STAGING: ingress drain, command append + WAL
         handoff, queued scatters, mailbox pack, async device dispatch.
@@ -1261,18 +1224,18 @@ class BatchCoordinator:
         buffer bound); tickets are realised strictly in dispatch order
         (egress fields are absolute per-step snapshots — out-of-order
         realisation would regress role/term mirrors)."""
+        self._drainer_idents.add(threading.get_ident())
         self._egress_thread = threading.Thread(
             target=self._egress_loop, name=f"ra-batch-eg-{self.name}",
             daemon=True,
         )
         self._egress_thread.start()
-        if self._egress_async:
-            self._sender_thread = threading.Thread(
-                target=self._sender_loop, name=f"ra-batch-snd-{self.name}",
-                daemon=True,
-            )
-            self._sender_thread.start()
-            self._egress_on = True
+        self._sender_thread = threading.Thread(
+            target=self._sender_loop, name=f"ra-batch-snd-{self.name}",
+            daemon=True,
+        )
+        self._sender_thread.start()
+        self._egress_on = True
         cv = self._pipe_cv
         while self.running:
             t0 = time.perf_counter_ns()
@@ -1415,7 +1378,7 @@ class BatchCoordinator:
 
     def _coop_drainer(self):
         """Register the calling thread as a drainer for the span of one
-        cooperative step_* call (its self-publishes divert to
+        ``step_once`` call (its self-publishes divert to
         ``_internal_q`` instead of gate-waiting on a ring it is itself
         responsible for draining). Returns a token for ``_coop_done``."""
         ident = threading.get_ident()
@@ -1431,131 +1394,26 @@ class BatchCoordinator:
     def step_once(self) -> bool:
         """One SEQUENTIAL coordinator iteration: drain ingress, scatter
         host log updates, run the fused device step, realise egress.
-        Returns False when there was nothing to do. Deterministic-test
-        and cooperative-driver entry point — never call it on a started
-        pipelined coordinator (realisation order would invert)."""
+        Returns False when there was nothing to do. The deterministic
+        driver of the tests — never call it on a started coordinator
+        (realisation order would invert)."""
         token = self._coop_drainer()
         try:
-            return self._step_once_inner()
-        finally:
-            self._coop_done(token)
-
-    def _step_once_inner(self) -> bool:
-        pre = self._drain_classify()  # heavy half, off the state lock
-        with self._step_lock:
-            prev = self._coop_ticket
-            if prev is not None:
-                # flush a leftover pipelined-driver ticket first so
-                # realisation order is preserved across driver modes
-                self._coop_ticket = None
-                self._realise(prev)
-                # the pre-drained items are NOT lost: hand them to the
-                # dispatch pass the driver's next call runs
-                self._drain_and_dispatch(pre, dispatch=False)
+            pre = self._drain_classify()  # heavy half, off the state lock
+            with self._step_lock:
+                ticket = self._drain_and_dispatch(pre)
+                if ticket is None:
+                    return False
+                self._realise(ticket)
                 return True
-            ticket = self._drain_and_dispatch(pre)
-            if ticket is None:
-                return False
-            self._realise(ticket)
-            return True
-
-    def step_stage(self) -> bool:
-        """Cooperative-pipeline half A: drain ingress, append commands,
-        ship drain-produced AERs, and DISPATCH the fused device step
-        (async), parking the ticket for ``step_finish``. A multi-
-        coordinator driver stages every coordinator first, then
-        finishes every coordinator — each device step then computes
-        while the driver stages the others (the single-thread form of
-        the wave pipeline, docs/INTERNALS.md §15)."""
-        token = self._coop_drainer()
-        try:
-            return self._step_stage_inner()
         finally:
             self._coop_done(token)
-
-    def _step_stage_inner(self) -> bool:
-        pre = self._drain_classify()  # heavy half, off the state lock
-        with self._step_lock:
-            prev = self._coop_ticket
-            if prev is not None:
-                # driver skipped a finish: realise in order first
-                self._coop_ticket = None
-                self._realise(prev)
-            ticket = self._drain_and_dispatch(pre)
-            self._coop_ticket = ticket
-            return ticket is not None
-
-    def step_finish(self) -> bool:
-        """Cooperative-pipeline half B: realise the ticket parked by
-        ``step_stage`` (egress sync + processing + commit-driven AERs).
-        Counts the staged-while-in-flight overlap."""
-        token = self._coop_drainer()
-        try:
-            return self._step_finish_inner()
-        finally:
-            self._coop_done(token)
-
-    def _step_finish_inner(self) -> bool:
-        with self._egress_lock:
-            ticket = self._coop_ticket
-            if ticket is None:
-                return False
-            self._coop_ticket = None
-            t0 = time.perf_counter_ns()
-            self._realise(ticket)
-            if ticket.stepped:
-                self.counters.incr("pipeline_steps")
-                # host work done between device dispatch and egress
-                # sync (AER fan-out + the other coordinators' staging):
-                # the window the device step computed inside
-                hidden = t0 - ticket.t_pack
-                if hidden > 0:
-                    self.counters.incr("pipeline_overlap_ns", hidden)
-            return True
-
-    def step_pipelined(self) -> bool:
-        """One cooperative PIPELINED iteration (single-driver-thread
-        form of the wave pipeline, docs/INTERNALS.md §15): realise the
-        PREVIOUSLY dispatched step (its device compute had the whole
-        driver round to finish), then stage + dispatch the next one —
-        whose drain already sees the realised egress's products, and
-        whose device compute overlaps this thread realising the OTHER
-        coordinators in the round-robin. Drain-produced AERs leave
-        ahead of the dispatch (inside ``_drain_and_dispatch``), so
-        replication fan-out never waits a pipeline slot. Same ticket machinery as
-        the threaded loop; keep calling until False before reading
-        final state, and do not mix with a started loop."""
-        token = self._coop_drainer()
-        try:
-            return self._step_pipelined_inner()
-        finally:
-            self._coop_done(token)
-
-    def _step_pipelined_inner(self) -> bool:
-        pre = self._drain_classify()  # heavy half, off the state lock
-        with self._step_lock:
-            prev = self._coop_ticket
-            self._coop_ticket = None
-            if prev is not None:
-                self._realise(prev)
-            t0 = time.perf_counter_ns()
-            ticket = self._drain_and_dispatch(pre)
-            self._coop_ticket = ticket
-            if ticket is not None and prev is not None:
-                # staged+dispatched in the same round a previous step
-                # was realised: the new device step runs while the
-                # driver services the other coordinators
-                self.counters.incr(
-                    "pipeline_overlap_ns", time.perf_counter_ns() - t0
-                )
-                self.counters.incr("pipeline_steps")
-            return ticket is not None or prev is not None
 
     def _realise(self, ticket, lock=None) -> None:
         """Realise one dispatched step: sync its egress off the device
         (``np.asarray``: the host's one true wait for the device), then
         finish it under the state lock. The egress thread passes its
-        lock and syncs outside it; a cooperative driver holds the lock
+        lock and syncs outside it; ``step_once`` holds the lock
         already."""
         tr = _obs.tracing()
         t_pop = time.perf_counter_ns()
@@ -1674,7 +1532,7 @@ class BatchCoordinator:
             radd = routes.append
             by = self.by_name
             cq_get = cmd_q.get
-            R_MSG, R_CMD, R_CMDS = self._R_MSG, self._R_CMD, self._R_CMDS
+            R_MSG, R_CMD = self._R_MSG, self._R_CMD
             for item in buf:
                 tag = item[0]
                 if tag == R_CMD:
@@ -1693,21 +1551,6 @@ class BatchCoordinator:
                     _, name, from_sid, msg = item
                     if name in by:
                         radd((name, from_sid, msg))
-                elif tag == R_CMDS:
-                    _, names, cmd = item
-                    if cmd.priority == "low":
-                        for name in names:
-                            if name in by:
-                                lows.append((name, cmd))
-                        continue
-                    for name in names:
-                        q = cq_get(name)
-                        if q is None:
-                            if name not in by:
-                                continue
-                            cmd_q[name] = [cmd]
-                        else:
-                            q.append(cmd)
                 else:  # R_BATCH: pre-normalized (name, from_sid, msg)
                     for trip in item[1]:
                         name = trip[0]
@@ -1746,12 +1589,12 @@ class BatchCoordinator:
         Ordering contract (docs/INTERNALS.md §18): order is preserved
         WITHIN each class; classes may reorder against each other.
         That is safe because any producer's causally-ordered commands
-        ride a single class (clients publish R_CMD, bulk drivers
-        R_CMDS, peer forwards R_BATCH) and protocol traffic is
+        ride a single class (clients publish R_CMD, peer forwards and
+        bulk senders R_BATCH) and protocol traffic is
         reorder-tolerant by the transport contract."""
         idx, counts = part
         ilist = idx.tolist()
-        c_msg, c_cmd, c_cmd_low, c_cmds, c_cmds_low, c_batch = counts.tolist()
+        c_msg, c_cmd, c_cmd_low, c_batch = counts.tolist()
         by = self.by_name
         cq_get = cmd_q.get
         radd = routes.append
@@ -1778,23 +1621,6 @@ class BatchCoordinator:
             if name in by:
                 ladd((name, cmd))
         o += c_cmd_low
-        for k in ilist[o:o + c_cmds]:
-            _, names, cmd = buf[k]
-            for name in names:
-                q = cq_get(name)
-                if q is None:
-                    if name not in by:
-                        continue
-                    cmd_q[name] = [cmd]
-                else:
-                    q.append(cmd)
-        o += c_cmds
-        for k in ilist[o:o + c_cmds_low]:
-            _, names, cmd = buf[k]
-            for name in names:
-                if name in by:
-                    ladd((name, cmd))
-        o += c_cmds_low
         for k in ilist[o:o + c_batch]:
             for trip in buf[k][1]:
                 name = trip[0]
@@ -1854,7 +1680,7 @@ class BatchCoordinator:
             # ingress_ring_msgs — these never touched a ring
             n_items += n_internal
         # seed rares / AER-dirty gids parked by earlier ingest-only
-        # passes (pipelined loop).
+        # passes (started loop).
         # ALWAYS detach (aliasing trap): _route_one appends into it,
         # so keeping an alias of the live (empty) container would
         # re-seed — and re-process — this pass's rares on the next pass
@@ -3711,7 +3537,7 @@ class BatchCoordinator:
             fut(value)
 
     def _send_batch(self, node_name: str, msgs) -> None:
-        """Per-destination batch send. With the started pipelined loop,
+        """Per-destination batch send. With the started loop,
         the fan-out hands off to the dedicated sender thread through a
         bounded ring — the step/egress/WAL threads never pay transport
         cost; a full handoff ring falls back to an inline send (bounded
@@ -4840,9 +4666,7 @@ class BatchCoordinator:
             # reclaim lanes of exited producer threads, then
             # publish the registered-lane gauge (one lane per
             # live producer; off the hot drain path)
-            prune = getattr(self._rings, "prune_dead", None)
-            if prune is not None:
-                prune()
+            self._rings.prune_dead()
             self.counters.put(
                 "ingress_ring_lanes", self._rings.lanes()
             )

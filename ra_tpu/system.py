@@ -129,15 +129,6 @@ class SystemConfig:
     # all: bump machine version when leader supports it; quorum: when a
     # quorum of members support it (reference: src/ra_server.erl:223-233).
     machine_upgrade_strategy: str = "all"
-    # NOTE (async command plane, docs/INTERNALS.md §16): the tpu_batch
-    # command-plane knobs — lock-free ingress rings on/off, per-lane
-    # slot count, dedicated egress sender thread — are constructor
-    # kwargs of runtime.coordinator.BatchCoordinator (``rings``,
-    # ``ingress_ring_slots``, ``egress_async``), surfaced as
-    # ``bench.py --rings`` and ``kv_harness --rings``. They are NOT
-    # SystemConfig fields: nothing constructs a BatchCoordinator from
-    # a SystemConfig today, and a config field nothing reads would be
-    # a silent no-op trap for operators.
     # Server execution backend: per_group_actor (scalar oracle path) or
     # tpu_batch (batching coordinator with device-resident decision state).
     server_impl: str = "per_group_actor"

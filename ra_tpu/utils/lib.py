@@ -157,7 +157,7 @@ def derive_dir(base: str, *parts: str) -> str:
 
 def enable_compile_cache() -> str:
     """Turn on JAX's persistent compilation cache for an entry-point
-    script (chip_smoke.py, bench.py, profile_wave.py; never the tests)
+    script (chip_smoke.py, benchmark/run.py; never the tests)
     and return its directory. The directory is placed from outside:
     where ``JAX_COMPILATION_CACHE_DIR`` is set JAX already reads it and
     no code sets another; otherwise it is the fixed

@@ -1,11 +1,11 @@
-"""Observability smoke check (CI): run a short WAL-backed bench
-in-process (filling the wave/commit/WAL histograms under real load),
-then bring up a live 3-coordinator cluster, scrape the Prometheus
-exposition, the ``system_overview`` and ``cluster_health`` surfaces,
-and fail on missing or NaN metrics. Registered next to
-scripts/flake_gate.sh — the gate that keeps the instruments we debug
-liveness WITH from silently rotting while the code they instrument
-evolves.
+"""Observability smoke check (CI): serve a short burst on a small
+started, WAL-backed cluster in-process (filling the wave/commit/WAL
+histograms under real load), then bring up a live 3-coordinator
+cluster, scrape the Prometheus exposition, the ``system_overview`` and
+``cluster_health`` surfaces, and fail on missing or NaN metrics.
+Registered next to scripts/flake_gate.sh — the gate that keeps the
+instruments we debug liveness WITH from silently rotting while the code
+they instrument evolves.
 
 Usage: JAX_PLATFORMS=cpu python scripts/obs_smoke.py [--groups N] [--cmds N]
 """
@@ -13,6 +13,7 @@ import argparse
 import math
 import os
 import re
+import shutil
 import sys
 import tempfile
 import time
@@ -47,106 +48,100 @@ def main() -> int:
     args = ap.parse_args()
 
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    from bench import bench_pipeline
+    import chip_smoke
     from ra_tpu import api, counters, leaderboard, obs
+    from ra_tpu import native as _native
     from ra_tpu.machine import SimpleMachine
     from ra_tpu.ops import consensus as C
+    from ra_tpu.protocol import Command, ElectionTimeout, USR
     from ra_tpu.runtime.coordinator import BatchCoordinator
-
-    out = bench_pipeline(args.groups, args.cmds, wal=True)
-    print(f"obs_smoke: bench ran at {out['value']:.0f} cmd/s "
-          f"(p50 {out['p50_ms']} ms)", file=sys.stderr)
+    from ra_tpu.runtime.transport import NodeRegistry
 
     errors: list = []
 
-    # the bench filled the histograms (they outlive its teardown):
-    # every wave phase and all five commit stages must have fired. The
-    # adaptive group-commit flush_wait family must EXIST (a short smoke
-    # burst may legitimately never clear the coalescing gate, so its
-    # count may be 0 — presence is the gate). The native hot-loop
+    # the served path at a small size: three started coordinators on
+    # their own WALs (chip_smoke's storage, as the benchmark's cells
+    # wire it), ``--groups`` groups led by pipe0, ``--cmds`` stamped
+    # commands a group and wave. Every wave phase and all five commit
+    # stages must fire, and the started loop must PROVE overlap —
+    # staging while the previous step was still in flight — via the
+    # counter the pipeline exists for. Kept alive until the scrape
+    # below so the families are present in the exposition.
+    pipe_dir = tempfile.mkdtemp(prefix="obs_smoke_pipe_")
+    pipe_reg = NodeRegistry()
+    pipe_coords = [
+        BatchCoordinator(f"pipe{i}", capacity=args.groups, num_peers=3,
+                         nodes=pipe_reg)
+        for i in range(3)
+    ]
+    pipe_storage, pipe_log = chip_smoke.wal_storage(pipe_coords, pipe_dir)
+    names = [f"pp{g}" for g in range(args.groups)]
+    for i, c in enumerate(pipe_coords):
+        c.add_groups([
+            (n, f"ppcl{g}", [(n, k.name) for k in pipe_coords],
+             SimpleMachine(lambda cm, s: s + cm, 0), pipe_log(i, n))
+            for g, n in enumerate(names)
+        ])
+        c.start()
+
+    def _pipe_wait(cond, what):
+        deadline = time.time() + 60
+        while time.time() < deadline:
+            if cond():
+                return
+            time.sleep(0.005)
+        errors.append(f"pipe cluster: timeout waiting for {what}")
+
+    lead = pipe_coords[0]
+    lead.deliver_many(
+        [((n, lead.name), ElectionTimeout(), None) for n in names])
+    _pipe_wait(lambda: all(lead.by_name[n].role == C.R_LEADER
+                           for n in names), "leaders")
+    t0 = time.perf_counter()
+    waves = 0
+    while waves < args.cmds or (
+        lead.counters.get("pipeline_overlap_ns") <= 0 and waves < 200
+    ):
+        waves += 1
+        lead.deliver_many([
+            ((n, lead.name),
+             Command(kind=USR, data=1, ts=time.monotonic_ns()), None)
+            for n in names
+        ])
+        _pipe_wait(lambda: all(c.by_name[n].machine_state == waves
+                               for c in pipe_coords for n in names),
+                   f"wave {waves} on every replica")
+        if errors:
+            break
+    print(f"obs_smoke: {waves} waves x {args.groups} groups served in "
+          f"{time.perf_counter() - t0:.2f} s", file=sys.stderr)
+    if lead.counters.get("pipeline_overlap_ns") <= 0:
+        errors.append("started loop recorded no staging overlap")
+
+    # The adaptive group-commit flush_wait family must EXIST (a short
+    # smoke burst may legitimately never clear the coalescing gate, so
+    # its count may be 0 — presence is the gate). The native hot-loop
     # phases (docs/INTERNALS.md §18) record only when rt_native.so
     # loaded — without a compiler they are excluded, with one they must
     # be NONZERO (the native paths silently never engaging is exactly
     # the rot this gate exists to catch).
-    from ra_tpu import native as _native
-
     rt_loaded = _native.entry_points()["classify"]
     _native_phases = {"classify_native", "pack_native"}
     if rt_loaded:
-        nc = out.get("native_counters", {})
         for k in ("native_classify_batches", "native_pack_batches"):
-            if nc.get(k, 0) <= 0:
-                errors.append(f"bench ran with rt_native loaded but {k}=0 "
-                              f"(native path never engaged)")
-    required_bench = (
-        [rf"ra_wave_bench0_{ph}_seconds_count (\d+)"
+            if lead.counters.get(k) <= 0:
+                errors.append(f"pipe0 served with rt_native loaded but "
+                              f"{k}=0 (native path never engaged)")
+    required_pipe = (
+        [rf"ra_wave_pipe0_{ph}_seconds_count (\d+)"
          for ph, _ in obs.WAVE_PHASES
          if rt_loaded or ph not in _native_phases]
-        + [rf"ra_commit_bench0_{st}_seconds_count (\d+)"
+        + [rf"ra_commit_pipe0_{st}_seconds_count (\d+)"
            for st, _ in obs.COMMIT_STAGES]
         + [r"ra_wal_\w+_fsync_seconds_count (\d+)",
            r"ra_wal_\w+_batch_seconds_count (\d+)",
            r"ra_wal_\w+_flush_wait_seconds_count \d+"]
     )
-
-    # pipelined wave loop (docs/INTERNALS.md §15): a short cooperative
-    # stage/finish burst must PROVE overlap — staging/dispatching while
-    # the previous step was still in flight — via the counter the
-    # pipeline exists for. Kept alive (with one registered WAL) until
-    # the scrape below so the families are present in the exposition.
-    from ra_tpu.machine import SimpleMachine as _SM
-    from ra_tpu.protocol import Command, ElectionTimeout, USR
-    from ra_tpu.runtime.transport import NodeRegistry
-
-    pipe_reg = NodeRegistry()
-    pipe_coords = [
-        BatchCoordinator(f"pipe{i}", capacity=8, num_peers=3, nodes=pipe_reg)
-        for i in range(3)
-    ]
-    pipe_ids = [("pp", f"pipe{i}") for i in range(3)]
-    for c in pipe_coords:
-        c.add_group("pp", "ppcl", pipe_ids, _SM(lambda cm, s: s + cm, 0))
-
-    def _pipe_round():
-        worked = False
-        for c in pipe_coords:
-            worked = c.step_stage() or worked
-        for c in pipe_coords:
-            worked = c.step_finish() or worked
-        return worked
-
-    pipe_coords[0].deliver(pipe_ids[0], ElectionTimeout(), None)
-    deadline = time.time() + 30
-    while time.time() < deadline and (
-        pipe_coords[0].by_name["pp"].role != C.R_LEADER
-    ):
-        if not _pipe_round():
-            time.sleep(0.001)
-    for _ in range(5):
-        pipe_coords[0].deliver(
-            pipe_ids[0], Command(kind=USR, data=1, reply_mode="noreply"),
-            None,
-        )
-    while time.time() < deadline and not all(
-        c.by_name["pp"].machine_state == 5 for c in pipe_coords
-    ):
-        if not _pipe_round():
-            time.sleep(0.001)
-    if pipe_coords[0].counters.get("pipeline_overlap_ns") <= 0:
-        errors.append("pipelined burst recorded no staging overlap")
-
-    # one live registered WAL so the group-commit / native counter
-    # families are scrapeable (bench WALs unregister on teardown)
-    import pickle
-
-    from ra_tpu.log.tables import TableRegistry
-    from ra_tpu.log.wal import Wal
-
-    _wal_dir = tempfile.mkdtemp(prefix="obs_smoke_wal_")
-    smoke_wal = Wal(os.path.join(_wal_dir, "wal"), TableRegistry(),
-                    lambda u, e: None, threaded=False)
-    smoke_wal.write("su", 1, 1, pickle.dumps("x"))
-    smoke_wal.flush()
 
     # live cluster: counter vectors (deleted when a coordinator stops)
     # and the one-call system_overview surface
@@ -162,8 +157,6 @@ def main() -> int:
         for c in coords:
             c.add_group("og0", "obscl", members,
                         SimpleMachine(lambda cm, s: s + cm, 0))
-        from ra_tpu.protocol import ElectionTimeout
-
         coords[0].deliver(("og0", "obs0"), ElectionTimeout(), None)
         deadline = time.time() + 30
         while (
@@ -276,16 +269,16 @@ def main() -> int:
         _sp.counter.incr("brownout_exited")
 
         text = api.prometheus_metrics()
-        required_live = required_bench + [
+        required_live = required_pipe + [
             r"# TYPE ra_commit_rate gauge",
             r"# TYPE ra_commands_rejected counter",
             r"ra_lane_wedges",  # presence only: 0 is the healthy value
-            # pipelined wave loop: the coop burst above must show
+            # pipelined wave loop: the pipe cluster above must show
             # overlap > 0 (the (\d+)-zero check enforces nonzero)
             r"ra_pipeline_overlap_ns\{[^}]*pipe0[^}]*\} (\d+)",
             r"ra_pipeline_steps\{[^}]*pipe0[^}]*\} (\d+)",
             # adaptive group-commit gauge family (wal counters register
-            # per-scope; the smoke WAL below keeps one alive to scrape)
+            # per-scope; the pipe cluster's WALs are alive at the scrape)
             r"# TYPE ra_group_commit_delay_us gauge",
             r"# TYPE ra_group_commit_waits counter",
             r"# TYPE ra_native_batches counter",
@@ -458,17 +451,12 @@ def main() -> int:
             c.stop()
         for c in pipe_coords:
             c.stop()
+        chip_smoke.close_storage(pipe_storage)
+        shutil.rmtree(pipe_dir, ignore_errors=True)
         try:
             _sp.delete()
         except Exception:  # noqa: BLE001
             pass
-        try:
-            smoke_wal.close()
-        except Exception:  # noqa: BLE001
-            pass
-        import shutil
-
-        shutil.rmtree(_wal_dir, ignore_errors=True)
         leaderboard.clear()
 
     if errors:

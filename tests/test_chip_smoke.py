@@ -19,7 +19,6 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-import bench  # noqa: E402
 import chip_smoke  # noqa: E402
 from ra_tpu.ops import consensus as C  # noqa: E402
 from ra_tpu.utils import lib  # noqa: E402
@@ -127,17 +126,6 @@ def test_main_reports_nothing_without_a_tpu(capsys):
         chip_smoke.main([])
     assert exc.value.code not in (0, None)
     assert '"ok"' not in capsys.readouterr().out
-
-
-def test_bench_fails_on_a_planted_wrong_state(tmp_path, monkeypatch):
-    """bench.py has no fallback left: a pass that ends in the wrong
-    machine state raises, on whatever device it ran on."""
-    from ra_tpu.models.bench_machine import BenchMachine
-
-    monkeypatch.setattr(
-        BenchMachine, "apply_many", lambda self, meta, cmds, s: s + len(cmds) + 1)
-    with pytest.raises(RuntimeError, match="wrong state"):
-        bench.bench_pipeline(16, 2, wal=True, workdir=str(tmp_path))
 
 
 @pytest.fixture

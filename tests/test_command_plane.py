@@ -5,10 +5,9 @@ Deterministic coverage for the concurrency the command plane
 introduced: SPSC ring wraparound and full-ring behavior, the
 multi-lane ingress fuzz (8 producer threads over 3 shared lanes), the
 full-ring -> admission-reject integration (with the gate waiter woken
-by the drain, not a sleep), failpoints fired during ring handoff with
-the pipeline on and off, stage/finish ≡ step_once equivalence with
-rings enabled and with the lock+deque control plane, and the
-zero-spurious-wakeups invariant of the idle step loop.
+by the drain, not a sleep), failpoints fired during ring handoff of
+both mailbox shapes, step_once ≡ started loop on a seeded command
+sequence, and the zero-spurious-wakeups invariant of the idle step loop.
 """
 
 import os
@@ -25,7 +24,7 @@ from ra_tpu.log.wal import Wal
 from ra_tpu.machine import SimpleMachine
 from ra_tpu.ops import consensus as C
 from ra_tpu.protocol import Command, ElectionTimeout, HeartbeatReply, USR
-from ra_tpu.rings import IngressRings, LockedLanes, SpscRing, WaitGate
+from ra_tpu.rings import IngressRings, SpscRing, WaitGate
 from ra_tpu.runtime.coordinator import BatchCoordinator
 from ra_tpu.runtime.transport import NodeRegistry
 
@@ -243,18 +242,6 @@ def test_concurrent_producer_fuzz_8_threads_3_lanes():
         assert seqs == sorted(seqs), f"producer {tid} order broken"
 
 
-def test_locked_lanes_control_same_interface():
-    lanes = LockedLanes(lane_slots=16)
-    assert lanes.publish("a")
-    assert lanes.publish("b")
-    assert lanes.pending()
-    out = []
-    assert lanes.drain(out) == 2
-    assert out == ["a", "b"]
-    assert lanes.lanes() == 1
-    assert not lanes.pending()
-
-
 # ---------------------------------------------------------------------------
 # full-ring backpressure -> admission integration
 
@@ -390,93 +377,43 @@ def test_drainer_self_publish_diverts_to_internal_queue():
 
 
 # ---------------------------------------------------------------------------
-# stage/finish ≡ step_once equivalence, rings on and control plane
+# WAL-backed cluster scaffolding
 
 
-@pytest.mark.parametrize("rings", [True, False])
-@pytest.mark.parametrize("pipelined", [False, True])
-def test_drivers_commit_identically_with_and_without_rings(pipelined, rings):
-    tag = f"eq{int(pipelined)}{int(rings)}"
-    reg = NodeRegistry()
-    coords = [
-        BatchCoordinator(f"{tag}{i}", capacity=8, num_peers=3, nodes=reg,
-                         rings=rings)
-        for i in range(3)
-    ]
-    ids = [("eg", f"{tag}{i}") for i in range(3)]
-    for c in coords:
-        c.add_group("eg", f"{tag}cl", ids,
-                    SimpleMachine(lambda cm, s: s + cm, 0))
-
-    if pipelined:
-        def step():
-            worked = False
-            for c in coords:
-                worked = c.step_stage() or worked
-            for c in coords:
-                worked = c.step_finish() or worked
-            return worked
-    else:
-        def step():
-            worked = False
-            for c in coords:
-                worked = c.step_once() or worked
-            return worked
-
-    def drive(cond):
-        deadline = time.monotonic() + 20
-        while time.monotonic() < deadline:
-            worked = step()
-            if cond():
-                return
-            if not worked:
-                time.sleep(0.001)
-        raise AssertionError("drive timeout")
-
-    try:
-        coords[0].deliver(ids[0], ElectionTimeout(), None)
-        drive(lambda: coords[0].by_name["eg"].role == C.R_LEADER)
-        for _ in range(5):
-            coords[0].deliver(
-                ids[0], Command(kind=USR, data=1, reply_mode="noreply"), None
-            )
-        drive(lambda: all(c.by_name["eg"].machine_state == 5
-                          for c in coords))
-        assert [c.by_name["eg"].machine_state for c in coords] == [5, 5, 5]
-        if rings:
-            assert coords[0].counters.get("ingress_ring_msgs") > 0
-            assert coords[0].counters.get("ingress_ring_drains") > 0
-        if pipelined:
-            assert coords[0].counters.get("pipeline_overlap_ns") > 0
-    finally:
-        for c in coords:
-            c.stop()
+def _wal_backed(c, d):
+    """Put coordinator ``c`` on its own Wal + SegmentWriter under ``d``,
+    written events handled on the WAL writer's thread."""
+    tables = TableRegistry()
+    sw = SegmentWriter(os.path.join(d, "data"), tables, c.wal_notify)
+    sw.fault_scope = c.name
+    wal = Wal(os.path.join(d, "wal"), tables, c.wal_notify,
+              segment_writer=sw)
+    wal.notify_many = c.wal_notify_many
+    wal.fault_scope = c.name
+    return tables, wal, sw, d
 
 
-# ---------------------------------------------------------------------------
-# failpoints during ring handoff (pipeline on/off)
+def _close_storage(storage):
+    for _t, wal, sw, _d in storage:
+        try:
+            wal.close()
+            sw.close()
+        except Exception:  # noqa: BLE001
+            pass
 
 
 class _WalCluster:
-    def __init__(self, tmp_path, tag, pipeline=True):
+    def __init__(self, tmp_path, tag, active_set="auto"):
         self.names = [f"{tag}{i}" for i in range(3)]
         self.coords = []
         self.storage = {}
         for n in self.names:
             c = BatchCoordinator(
-                n, capacity=8, num_peers=3, pipeline=pipeline,
+                n, capacity=8, num_peers=3, active_set=active_set,
                 election_timeout_s=0.15, detector_poll_s=0.05,
                 tick_interval_s=0.2,
             )
-            d = str(tmp_path / n)
-            tables = TableRegistry()
-            sw = SegmentWriter(os.path.join(d, "data"), tables, c.wal_notify)
-            sw.fault_scope = n
-            wal = Wal(os.path.join(d, "wal"), tables, c.wal_notify,
-                      segment_writer=sw)
-            wal.notify_many = c.wal_notify_many
-            wal.fault_scope = n
-            self.storage[n] = (tables, wal, sw, d)
+            self.storage[n] = _wal_backed(c, str(tmp_path / n))
             self.coords.append(c)
         self.ids = [("wg", n) for n in self.names]
         for i, c in enumerate(self.coords):
@@ -504,13 +441,135 @@ class _WalCluster:
     def stop(self):
         for c in self.coords:
             c.stop()
-        for n in self.names:
-            _t, wal, sw, _d = self.storage[n]
-            try:
-                wal.close()
-                sw.close()
-            except Exception:  # noqa: BLE001
-                pass
+        _close_storage(self.storage.values())
+
+
+# ---------------------------------------------------------------------------
+# step_once ≡ the started loop: the one test driver is faithful to the
+# served path
+
+
+_EQ_GROUPS = 6
+_EQ_CMDS = 60
+_EQ_MOD = 1_000_003
+
+
+def _eq_sequence(seed):
+    """The seeded command sequence, as bursts of ``(group, payload)``,
+    and what a plain fold of it leaves in each group."""
+    import random
+
+    rng = random.Random(seed)
+    cmds = [(rng.randrange(_EQ_GROUPS), rng.randrange(1, 1000))
+            for _ in range(_EQ_CMDS)]
+    bursts, k = [], 0
+    while k < len(cmds):
+        n = rng.randrange(1, 9)
+        bursts.append(cmds[k:k + n])
+        k += n
+    fold = [0] * _EQ_GROUPS
+    count = [0] * _EQ_GROUPS
+    for g, x in cmds:
+        fold[g] = (fold[g] * 31 + x) % _EQ_MOD
+        count[g] += 1
+    return bursts, fold, count
+
+
+@pytest.mark.parametrize("logs", ["memory", "wal"])
+@pytest.mark.parametrize("active_set", ["auto", "never"])
+@pytest.mark.parametrize("driver", ["step_once", "started"])
+def test_step_once_and_the_started_loop_commit_identically(
+        tmp_path, driver, active_set, logs):
+    """The same seeded command sequence, through ``step_once`` and
+    through ``start()``-ed coordinators, on the sub-width program
+    ("auto": what a lightly loaded node runs) and the full-width one
+    ("never"), on memory logs and on WAL-backed logs: every case ends
+    with the plain fold's machine state and the same last index and
+    term on all three replicas, so the cases equal one another."""
+    tag = f"eq{driver[2]}{active_set[0]}{logs[0]}"
+    reg = NodeRegistry()
+    # six groups of 32: never more than capacity / 4 busy, so "auto"
+    # always takes the sub-width program. No election but the one asked
+    # for: term 1 in every case
+    coords = [
+        BatchCoordinator(f"{tag}{i}", capacity=32, num_peers=3, nodes=reg,
+                         active_set=active_set, election_timeout_s=100.0)
+        for i in range(3)
+    ]
+    names = [f"eg{g}" for g in range(_EQ_GROUPS)]
+    storage = []
+    if logs == "wal":
+        storage = [_wal_backed(c, str(tmp_path / c.name)) for c in coords]
+
+    def log_of(i, n):
+        if not storage:
+            return None
+        tables, wal, _sw, d = storage[i]
+        return Log(n, os.path.join(d, "data", n), tables, wal)
+
+    for i, c in enumerate(coords):
+        c.add_groups([
+            (n, f"{tag}cl{g}", [(n, k.name) for k in coords],
+             SimpleMachine(lambda cm, s: (s * 31 + cm) % _EQ_MOD, 0),
+             log_of(i, n))
+            for g, n in enumerate(names)
+        ])
+
+    if driver == "started":
+        for c in coords:
+            c.start()
+
+        def step():
+            return False
+    else:
+        def step():
+            return any([c.step_once() for c in coords])
+
+    def drive(cond, what):
+        deadline = time.monotonic() + 30
+        while time.monotonic() < deadline:
+            worked = step()
+            if cond():
+                return
+            if not worked:
+                time.sleep(0.001)
+        raise AssertionError(f"timeout waiting for {what}")
+
+    bursts, fold, count = _eq_sequence(7)
+    try:
+        coords[0].deliver_many(
+            [((n, coords[0].name), ElectionTimeout(), None) for n in names])
+        drive(lambda: all(coords[0].by_name[n].role == C.R_LEADER
+                          for n in names), "leaders")
+        for burst in bursts:
+            for g, x in burst:
+                coords[0].deliver(
+                    (names[g], coords[0].name),
+                    Command(kind=USR, data=x, reply_mode="noreply"), None)
+            step()
+        drive(lambda: all(c.by_name[n].machine_state == fold[g]
+                          for c in coords for g, n in enumerate(names)),
+              "every replica at the fold")
+        # the leader's noop, then the group's commands, all in term 1
+        want = [(count[g] + 1, 1) for g in range(_EQ_GROUPS)]
+        drive(lambda: all(
+            [c.by_name[n].log.last_index_term() for n in names] == want
+            for c in coords), "every log at the same last index and term")
+        assert coords[0].counters.get("ingress_ring_msgs") > 0
+        for c in coords:
+            assert c.sub_steps == (c.steps if active_set == "auto" else 0)
+        if driver == "started":
+            assert sum(c.counters.get("pipeline_steps") for c in coords) > 0
+        else:
+            assert all(c.counters.get("pipeline_steps") == 0 for c in coords)
+    finally:
+        for c in coords:
+            c.stop()
+        _close_storage(storage)
+
+
+# ---------------------------------------------------------------------------
+# failpoints during ring handoff
 
 
 def _commit_n(cl, n, start=0):
@@ -527,14 +586,14 @@ def _commit_n(cl, n, start=0):
     return total
 
 
-@pytest.mark.parametrize("pipeline", [True, False])
-def test_fsync_failpoint_during_ring_handoff(tmp_path, pipeline):
+@pytest.mark.parametrize("active_set", ["auto", "never"])
+def test_fsync_failpoint_during_ring_handoff(tmp_path, active_set):
     """An fsync failure injected while commands stream through the
     ingress rings poisons the WAL un-acked, commits keep flowing on the
-    quorum, and reopen() heals — identically pipeline on/off, with the
-    ring counters proving the rings actually carried the traffic."""
-    tag = "rf" if pipeline else "rs"
-    cl = _WalCluster(tmp_path, tag, pipeline=pipeline)
+    quorum, and reopen() heals — on the sub-width and on the full-width
+    mailbox, with the ring counters proving the rings actually carried
+    the traffic."""
+    cl = _WalCluster(tmp_path, "rf" + active_set[0], active_set=active_set)
     try:
         total = _commit_n(cl, 2)
         victim = cl.leader()[1]
@@ -625,12 +684,7 @@ def test_election_storm_wider_than_lane_fully_elects():
         ])
 
         def step_all():
-            w = False
-            for c in coords:
-                w = c.step_stage() or w
-            for c in coords:
-                w = c.step_finish() or w
-            return w
+            return any([c.step_once() for c in coords])
 
         deadline = time.monotonic() + 60
         idle = 0

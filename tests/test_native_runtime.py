@@ -30,8 +30,6 @@ from ra_tpu.protocol import (
     RC_BATCH,
     RC_CMD,
     RC_CMD_LOW,
-    RC_CMDS,
-    RC_CMDS_LOW,
     RC_MSG,
     USR,
     AppendEntriesReply,
@@ -120,12 +118,12 @@ def test_classify_fuzz_vs_python_reference():
 def test_classify_bytearray_and_oversized_sidecar():
     """The coordinator hands a reusable bytearray scratch, possibly
     longer than the drained burst — only the first n codes count."""
-    codes = bytearray([1, 0, 2, 5, 3, 4]) + bytearray(64)
+    codes = bytearray([1, 0, 2, 3, 3, 1]) + bytearray(64)
     out = native.classify(codes, 6)
     assert out is not None
     idx, counts = out
-    assert counts.tolist() == [1, 1, 1, 1, 1, 1]
-    assert idx.tolist() == [1, 0, 2, 4, 5, 3]
+    assert counts.tolist() == [1, 2, 1, 2]
+    assert idx.tolist() == [1, 0, 5, 2, 3, 4]
 
 
 @needs_rt
@@ -167,11 +165,10 @@ def _apply_ops(c, ops):
         elif kind == "msg":
             _, gname, payload = op
             c.deliver((gname, c.name), payload, ext)
-        elif kind == "cmds":
+        elif kind == "cmds":  # one command fanned to many groups
             _, gnames, data, prio = op
-            c.deliver_commands(
-                list(gnames), Command(kind=USR, data=data, priority=prio)
-            )
+            cmd = Command(kind=USR, data=data, priority=prio)
+            c.deliver_many([((gname, c.name), cmd, None) for gname in gnames])
         elif kind == "many":
             _, trips = op
             c.deliver_many(

