@@ -131,6 +131,17 @@ WAL_FIELDS: List[FieldSpec] = [
      "permanent flips off the native path (lib lost or framing format "
      "mismatch after construction) — nonzero means the Python fallback "
      "took over mid-run"),
+    ("writer_cpu_ns", "counter",
+     "thread CPU of the writer inside its batches, bookkeeping, frame + "
+     "write + fsync and the written hand-off included (one clock pair a "
+     "batch)"),
+    ("runs", "counter",
+     "append requests flushed (a contiguous run or a single write; "
+     "truncate markers and sparse writes are none)"),
+    ("runs_in_place", "counter",
+     "of those, the ones that lay above the snapshot floor and above "
+     "all the file held of their writer, and so extended the file's "
+     "index in place (the rest filtered, rewound or asked for a resend)"),
 ]
 
 # Flow-control / liveness counters for a batch coordinator's command

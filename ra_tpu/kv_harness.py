@@ -1144,10 +1144,10 @@ def _run_batch(seed, n_ops, nodes, partitions, membership, op_timeout,
                 # written events are handled on the WAL writer thread
                 c.wal_notify(uid, evt)
 
-        def notify_many(items):
+        def notify_many(rows):
             c = coord_ref.get("c")
             if c is not None:
-                c.wal_notify_many(items)
+                c.wal_notify_many(rows)
 
         sw = SegmentWriter(f"{d}/data", tables, notify)
         sw.fault_scope = n

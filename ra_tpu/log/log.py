@@ -190,15 +190,8 @@ class Log(LogApi):
         tag = evt[0]
         if tag == "written":
             _, term, seq = evt
-            if seq is None or seq.is_empty():
-                return []
-            last = seq.last()
-            # stale-write check: the entry at `last` must still carry the
-            # term that was written (it may have been overwritten since)
-            t = self.fetch_term(last)
-            if t == term and last > self._written_index:
-                self._written_index = min(last, self._last_index)
-                self._written_term = term
+            if seq is not None and not seq.is_empty():
+                self.note_written(term, seq.last())
             return []
         if tag == "segments":
             _, tid_seqs, refs = evt
@@ -221,6 +214,17 @@ class Log(LogApi):
             self._resend(self._written_index + 1, force=True)
             return []
         return []
+
+    def note_written(self, term: int, last: int) -> int:
+        """The WAL holds this log's entries up to ``last``, written at
+        ``term`` (what a ``written`` event says, without the event):
+        advance the durable watermark, and return its index."""
+        # stale-write check: the entry at `last` must still carry the
+        # term that was written (it may have been overwritten since)
+        if last > self._written_index and self.fetch_term(last) == term:
+            self._written_index = min(last, self._last_index)
+            self._written_term = term
+        return self._written_index
 
     def _resend(self, from_idx: int, force: bool = False) -> None:
         now = time.monotonic()

@@ -17,6 +17,10 @@ from ra_tpu.log.memtable import MemTable
 from ra_tpu.utils.seq import Seq
 
 
+# the snapshot state of a uid that has none
+_NO_SNAPSHOT: Tuple[int, int, Seq] = (0, 1, Seq.empty())
+
+
 class TableRegistry:
     def __init__(self) -> None:
         self._lock = threading.Lock()
@@ -52,15 +56,15 @@ class TableRegistry:
             self._snap[uid] = (snapshot_idx, smallest_live, live_indexes)
 
     def snapshot_index(self, uid: str) -> int:
-        return self._snap.get(uid, (0, 1, Seq.empty()))[0]
+        return self._snap.get(uid, _NO_SNAPSHOT)[0]
 
     def smallest_live_index(self, uid: str) -> int:
         """Writes below this index are dead and may be dropped by the WAL
         and skipped by the segment writer."""
-        return self._snap.get(uid, (0, 1, Seq.empty()))[1]
+        return self._snap.get(uid, _NO_SNAPSHOT)[1]
 
     def live_indexes(self, uid: str) -> Seq:
-        return self._snap.get(uid, (0, 1, Seq.empty()))[2]
+        return self._snap.get(uid, _NO_SNAPSHOT)[2]
 
     def delete_snapshot_state(self, uid: str) -> None:
         with self._lock:

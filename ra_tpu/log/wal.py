@@ -22,8 +22,9 @@ a clean EOF (torn final write), matching standard WAL recovery rules.
 Threading: producers call ``write``/``truncate_write`` from any thread; a
 single writer thread drains the queue in batches of up to
 ``max_batch_size``, performs one write+fsync, then fires the per-writer
-``("written", term, seq)`` notifications. ``threaded=False`` gives tests
-a deterministic ``flush()``-driven mode.
+``("written", term, seq)`` notifications (or hands a ``notify_many``
+hook the same events as one list of ``(uid, term, lo, hi)`` rows).
+``threaded=False`` gives tests a deterministic ``flush()``-driven mode.
 """
 
 from __future__ import annotations
@@ -69,6 +70,28 @@ _UID_HDR = struct.Struct("<BHH")
 _TRUNC_HDR = struct.Struct("<BHQ")
 
 NotifyFn = Callable[[str, Any], None]
+# one written event of a batch in the bulk hook's form
+WrittenRow = Tuple[str, int, int, int]
+
+
+def _clip(held: list, idx: int) -> None:
+    """Keep only indexes <= ``idx`` of one uid's entry in ``_file_seqs``
+    (``Seq.limit`` on every table's ranges, in place)."""
+    for ranges in held[1].values():
+        while ranges and ranges[-1][0] > idx:
+            ranges.pop()
+        if ranges and ranges[-1][1] > idx:
+            ranges[-1][1] = idx
+    if held[0] > idx:
+        held[0] = idx
+
+
+def _written_seq(p: List[int]) -> Seq:
+    """The seq of one written event from its flat ``[lo, hi, ...]``
+    pairs: one range (the steady case) is normalised as it stands."""
+    if len(p) == 2:
+        return Seq.from_range(p[0], p[1])
+    return Seq(list(zip(p[::2], p[1::2])))
 
 
 class Wal:
@@ -92,11 +115,13 @@ class Wal:
         os.makedirs(dir, exist_ok=True)
         self.tables = tables
         self.notify = notify
-        # optional bulk channel: called with [(uid, event), ...] once
-        # per batch instead of one notify() per writer (hosts that route
-        # events through a shared lock set this — e.g. a coordinator's
-        # deliver_many)
-        self.notify_many: Optional[Callable[[List[Tuple[str, Any]]], None]] = None
+        # optional bulk channel: called once per batch, instead of one
+        # notify() per writer, with the batch's written events as rows
+        # ``(uid, term, lo, hi)`` — entries lo..hi of uid, all of term,
+        # are durable — in the order notify() would have carried them
+        # (hosts that route events through a shared lock set this —
+        # e.g. a coordinator's wal_notify_many)
+        self.notify_many: Optional[Callable[[List[WrittenRow]], None]] = None
         self.segment_writer = segment_writer
         self.max_size_bytes = max_size_bytes
         self.max_batch_size = max_batch_size
@@ -182,8 +207,12 @@ class Wal:
         self._file_path: Optional[str] = None
         self._bytes = 0
         self._uid_refs: Dict[str, int] = {}
-        # what this file holds: per uid, per memtable table id
-        self._file_seqs: Dict[str, Dict[int, Seq]] = {}
+        # what this file holds: uid -> [last index in any table,
+        # {memtable table id: ascending, non-adjacent [lo, hi] ranges}]
+        # — a Seq's normal form kept mutable, so an in-sequence run
+        # extends its table's tail range in place; a Seq is built where
+        # one is read (``_rollover``)
+        self._file_seqs: Dict[str, list] = {}
         # per-writer last contiguous idx (gap detection)
         self._last_idx: Dict[str, int] = {}
 
@@ -339,12 +368,16 @@ class Wal:
         (``ra/wal/batch``; children ``write``, ``fsync``, ``notify``)."""
         tr = _obs.tracing()
         t0 = time.perf_counter_ns()
+        # the writer thread's CPU, notify included: one clock pair a
+        # batch (a system call each), never one per entry
+        cpu0 = time.thread_time_ns()
         if tr:
             sp = _obs.begin("ra/wal/batch", items=len(batch),
                             node=self._span_node)
         self._write_batch(batch)
         if tr:
             _obs.end(sp)
+        self.counter.incr("writer_cpu_ns", time.thread_time_ns() - cpu0)
         self._h_batch.record(time.perf_counter_ns() - t0)
 
     def _take_batch_locked(self) -> List[Tuple]:
@@ -419,68 +452,51 @@ class Wal:
     def _write_batch(self, batch: List[Tuple]) -> None:
         # first pass: bookkeeping + record collection; second: framing
         # (natively when ra_tpu.native built) + one write/fsync.
-        # Per-(uid, table) index accumulation is BATCH-LEVEL and
-        # RUN-LEVEL: indexes collect into (lo, hi) pair lists and merge
-        # into the file seqs once per uid — per-entry Seq unions (plus
-        # per-entry snapshot floor lookups) dominated the whole WAL at
-        # 10k-group batches, and "r" run items process a whole
-        # contiguous append run with O(1) bookkeeping.
+        # The steady case — an "r" run that continues its writer's
+        # sequence above the snapshot floor — is a few dict lookups: it
+        # extends the file's range for its table and the batch's
+        # written range for its term in place, and builds nothing per
+        # uid (per-uid Seqs and closures were over half of the writer
+        # thread's CPU at 10k-group batches, whose runs are one entry
+        # each). What filters or rewinds (a run over the snapshot
+        # floor, an overwrite, a truncate marker, a sparse write) goes
+        # entry by entry through ``one`` and ``_index``'s slow half.
         records: List[Tuple] = []
-        # (uid, term) -> (lo, hi) pairs written in this batch
-        written: Dict[Tuple[str, int], List[Tuple[int, int]]] = {}
+        # (uid, term) -> flat [lo, hi, lo, hi, ...] written in this batch
+        written: Dict[Tuple[str, int], List[int]] = {}
         resends: List[Tuple[str, int]] = []
-        # uid -> [last_any_idx, {tid: [(lo, hi), ...]}] pending in batch
-        acc: Dict[str, list] = {}
-        # uid -> [snap_idx, live_indexes-or-None] (one lookup per uid)
-        snap_cache: Dict[str, list] = {}
-        n_entries = 0
+        # uid -> live indexes under its snapshot floor (floor overlap only)
+        live: Dict[str, Seq] = {}
+        n_entries = n_runs = n_in_place = 0
+        index = self._index
+        # (asked per item, not per batch: the floor can move under a
+        # snapshot, and the lookup is one dict read)
+        snapshot_index = self.tables.snapshot_index
 
-        def flush_uid(uid: str, info) -> None:
-            per_uid = self._file_seqs.setdefault(uid, {})
-            for t, pairs in info[1].items():
-                cur = per_uid.get(t)
-                add = Seq(pairs)
-                per_uid[t] = add if cur is None or cur.is_empty() else cur.union(add)
-            info[1] = {}
-
-        def get_info(uid: str):
-            info = acc.get(uid)
-            if info is None:
-                per_uid = self._file_seqs.setdefault(uid, {})
-                last_any = max((sq.last() or 0 for sq in per_uid.values()), default=0)
-                info = acc[uid] = [last_any, {}]
-            return info
-
-        def get_snap(uid: str):
-            sc = snap_cache.get(uid)
-            if sc is None:
-                sc = snap_cache[uid] = [self.tables.snapshot_index(uid), None]
-            return sc
-
-        def note_pair(pairs_by_key, key, lo: int, hi: int) -> None:
-            pend = pairs_by_key.get(key)
-            if pend is None:
-                pairs_by_key[key] = [(lo, hi)]
+        def note_written(key, lo: int, hi: int) -> None:
+            p = written.get(key)
+            if p is None:
+                written[key] = [lo, hi]
+            elif p[-1] + 1 == lo:
+                p[-1] = hi
             else:
-                tlo, thi = pend[-1]
-                if thi + 1 == lo:
-                    pend[-1] = (tlo, hi)
-                else:
-                    pend.append((lo, hi))
+                p += (lo, hi)
 
-        def one(kind, uid, idx, term, payload, tid) -> None:
+        def one(kind, uid, idx, term, payload, tid) -> bool:
+            """One entry by the exact rules; True when it extended the
+            file's index in place."""
             nonlocal n_entries
-            sc = get_snap(uid)
-            snap_idx = sc[0]
+            snap_idx = snapshot_index(uid)
             if idx <= snap_idx:
                 # drop writes below the snapshot floor (dead indexes);
                 # they still count as durable for writer bookkeeping
-                if sc[1] is None:
-                    sc[1] = self.tables.live_indexes(uid)
-                if idx not in sc[1]:
-                    note_pair(written, (uid, term), idx, idx)
+                lv = live.get(uid)
+                if lv is None:
+                    lv = live[uid] = self.tables.live_indexes(uid)
+                if idx not in lv:
+                    note_written((uid, term), idx, idx)
                     self._last_idx[uid] = max(self._last_idx.get(uid, 0), idx)
-                    return
+                    return False
             if kind != "s":
                 last = self._last_idx.get(uid)
                 # indexes at or below the snapshot are durable-or-dead, so
@@ -491,36 +507,25 @@ class Wal:
                     # order
                     self.counter.incr("out_of_seq")
                     resends.append((uid, max(last, snap_idx) + 1))
-                    return
+                    return False
             ref = self._uid_ref(uid, records)
             records.append((K_SPARSE if kind == "s" else K_ENTRY, ref, idx, term, payload))
             n_entries += 1
-            info = get_info(uid)
             if kind == "s":
                 # sparse writes never imply truncation of higher indexes
                 self._last_idx[uid] = max(self._last_idx.get(uid, 0), idx)
-                if idx > info[0]:
-                    info[0] = idx
             else:
                 self._last_idx[uid] = idx
-                if idx <= info[0]:
-                    # overwrite rewinds this file's view across ALL
-                    # tables of the uid (superseded entries), including
-                    # indexes still pending in this batch
-                    flush_uid(uid, info)
-                    per_uid = self._file_seqs[uid]
-                    for t in list(per_uid):
-                        per_uid[t] = per_uid[t].limit(idx - 1)
-                info[0] = idx
-            note_pair(info[1], tid, idx, idx)
-            note_pair(written, (uid, term), idx, idx)
+            note_written((uid, term), idx, idx)
+            return index(uid, tid, idx, idx, kind == "s")
 
         for item in batch:
             kind = item[0]
             if kind == "r":
                 _, uid, first, terms, payloads, tid = item
+                n_runs += 1
                 m = len(payloads)
-                snap_idx = get_snap(uid)[0]
+                snap_idx = snapshot_index(uid)
                 if first <= snap_idx:
                     # run overlaps the snapshot floor (rare): per-entry
                     # path keeps the dead-index filtering exact
@@ -528,50 +533,44 @@ class Wal:
                         one("w", uid, first + k, terms[k], payloads[k], tid)
                     continue
                 last = self._last_idx.get(uid)
-                if last is not None and first > max(last, snap_idx) + 1:
+                if last is not None and first > last + 1 and first > snap_idx + 1:
                     self.counter.incr("out_of_seq")
                     resends.append((uid, max(last, snap_idx) + 1))
                     continue
                 last_e = first + m - 1
-                ref = self._uid_ref(uid, records)
+                ref = self._uid_refs.get(uid) or self._uid_ref(uid, records)
                 records.append((K_RUN, ref, first, terms, payloads))
                 n_entries += m
-                info = get_info(uid)
                 self._last_idx[uid] = last_e
-                if first <= info[0]:
-                    flush_uid(uid, info)
-                    per_uid = self._file_seqs[uid]
-                    for t in list(per_uid):
-                        per_uid[t] = per_uid[t].limit(first - 1)
-                info[0] = last_e
-                note_pair(info[1], tid, first, last_e)
+                if index(uid, tid, first, last_e):
+                    n_in_place += 1
                 # written events key on (uid, term): split multi-term runs
-                if terms[0] == terms[-1]:
-                    note_pair(written, (uid, terms[0]), first, last_e)
+                t0 = terms[0]
+                if t0 == terms[-1]:
+                    note_written((uid, t0), first, last_e)
                 else:
-                    lo, t0 = first, terms[0]
+                    lo = first
                     for k in range(1, m):
                         if terms[k] != t0:
-                            note_pair(written, (uid, t0), lo, first + k - 1)
+                            note_written((uid, t0), lo, first + k - 1)
                             lo, t0 = first + k, terms[k]
-                    note_pair(written, (uid, t0), lo, last_e)
+                    note_written((uid, t0), lo, last_e)
             elif kind == "t":
                 _, uid, idx, _term, _payload, _tid = item
-                info = acc.get(uid)
-                if info is not None:
-                    flush_uid(uid, info)
-                    info[0] = idx - 1
                 ref = self._uid_ref(uid, records)
                 records.append((K_TRUNC, ref, idx, 0, b""))
                 self._last_idx[uid] = idx - 1
-                for t, sq in self._file_seqs.get(uid, {}).items():
-                    self._file_seqs[uid][t] = sq.limit(idx - 1)
+                held = self._file_seqs.get(uid)
+                if held is not None:
+                    _clip(held, idx - 1)
             else:
-                one(kind, item[1], item[2], item[3], item[4], item[5])
-
-        for uid, info in acc.items():
-            if info[1]:
-                flush_uid(uid, info)
+                in_place = one(kind, item[1], item[2], item[3], item[4], item[5])
+                if kind == "w":
+                    n_runs += 1
+                    n_in_place += in_place
+        if n_runs:
+            self.counter.incr("runs", n_runs)
+            self.counter.incr("runs_in_place", n_in_place)
 
         tr = _obs.tracing()
         node = self._span_node
@@ -659,19 +658,51 @@ class Wal:
         if self.notify_many is not None and len(written) > 1:
             # one transport/lock round for the whole batch's written
             # events (a 10k-group batch otherwise pays 10k lock rounds)
-            self.notify_many(
-                [(uid, ("written", term, Seq(pairs)))
-                 for (uid, term), pairs in written.items()]
-            )
+            rows: List[WrittenRow] = []
+            for (uid, term), p in written.items():
+                if len(p) == 2:
+                    rows.append((uid, term, p[0], p[1]))
+                else:
+                    rows.extend((uid, term, lo, hi)
+                                for lo, hi in _written_seq(p).ranges())
+            self.notify_many(rows)
         else:
-            for (uid, term), pairs in written.items():
-                self.notify(uid, ("written", term, Seq(pairs)))
+            for (uid, term), p in written.items():
+                self.notify(uid, ("written", term, _written_seq(p)))
         for uid, from_idx in resends:
             self.notify(uid, ("resend_write", from_idx))
         if tr:
             _obs.end(sp)
         if self._bytes >= self.max_size_bytes:
             self._rollover()
+
+    def _index(self, uid: str, tid: int, lo: int, hi: int,
+               sparse: bool = False) -> bool:
+        """This file now holds ``lo..hi`` of ``uid`` from memtable table
+        ``tid``. True when the run lay above everything the file held
+        of the uid and extended its table's ranges in place; the rest
+        is an overwrite, which takes the superseded indexes out of the
+        file's view in ALL tables of the uid first, or a sparse write,
+        which never implies truncation of higher indexes."""
+        held = self._file_seqs.get(uid)
+        if held is None:
+            held = self._file_seqs[uid] = [lo - 1, {}]
+        in_place = lo > held[0]
+        if not in_place:
+            if sparse:
+                merged = Seq(held[1].get(tid, []) + [(lo, hi)])
+                held[1][tid] = [list(r) for r in merged.ranges()]
+                return False
+            _clip(held, lo - 1)
+        held[0] = hi
+        ranges = held[1].get(tid)
+        if not ranges:  # a table new to the file, or clipped empty
+            held[1][tid] = [[lo, hi]]
+        elif ranges[-1][1] + 1 == lo:
+            ranges[-1][1] = hi
+        else:
+            ranges.append([lo, hi])
+        return in_place
 
     def _sync(self) -> None:
         # fsync failure is POISON (fsyncgate): the page-cache state of
@@ -781,9 +812,14 @@ class Wal:
     def _rollover(self) -> None:
         self.counter.incr("rollovers")
         self._file.close()
-        full_path, seqs = self._file_path, self._file_seqs
+        full_path, held = self._file_path, self._file_seqs
         self._open_next()
         if self.segment_writer is not None:
+            seqs = {
+                uid: {t: Seq([(lo, hi) for lo, hi in ranges], _normalized=True)
+                      for t, ranges in per[1].items()}
+                for uid, per in held.items()
+            }
             self.segment_writer.flush_mem_tables(
                 self._flush_jobs(seqs), wal_file=full_path
             )

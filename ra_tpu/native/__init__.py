@@ -224,46 +224,48 @@ _K_ENTRY = 2
 
 def _pack_arrays(records: List[Record]):
     """Expand records (runs widened) into the parallel column arrays +
-    payload blob the native entry points consume."""
-    n = 0
-    for r in records:
-        n += len(r[4]) if r[0] == K_RUN else 1
-    kinds = np.empty(n, np.uint8)
-    refs = np.empty(n, np.uint16)
-    idxs = np.empty(n, np.uint64)
-    terms = np.empty(n, np.uint64)
-    lens = np.empty(n, np.uint32)
-    parts = []
-    i = 0
+    payload blob the native entry points consume. The columns collect
+    in lists and become arrays once: a 10k-group batch is thousands of
+    runs of ONE entry, and five array stores a run cost ten times what
+    five appends do."""
+    kinds: List[int] = []
+    refs: List[int] = []
+    idxs: List[int] = []
+    terms: List[int] = []
+    parts: List[bytes] = []
     for rec in records:
         kind = rec[0]
         if kind == K_RUN:
-            # vectorized fill for the whole run — one Python round per
-            # contiguous append run instead of one per entry
             _, ref, first, run_terms, payloads = rec
             m = len(payloads)
-            sl = slice(i, i + m)
-            kinds[sl] = _K_ENTRY
-            refs[sl] = ref
-            idxs[sl] = np.arange(first, first + m, dtype=np.uint64)
-            terms[sl] = run_terms
-            lens[sl] = [len(p) for p in payloads]
-            parts.extend(payloads)
-            i += m
+            if m == 1:
+                kinds.append(_K_ENTRY)
+                refs.append(ref)
+                idxs.append(first)
+                terms.append(run_terms[0])
+                parts.append(payloads[0])
+            else:
+                kinds.extend([_K_ENTRY] * m)
+                refs.extend([ref] * m)
+                idxs.extend(range(first, first + m))
+                terms.extend(run_terms)
+                parts.extend(payloads)
         else:
             _, ref, idx, term, payload = rec
-            kinds[i] = kind
-            refs[i] = ref
-            idxs[i] = idx
-            terms[i] = term
-            lens[i] = len(payload)
+            kinds.append(kind)
+            refs.append(ref)
+            idxs.append(idx)
+            terms.append(term)
             parts.append(payload)
-            i += 1
+    n = len(kinds)
+    lens = np.fromiter(map(len, parts), np.uint32, n)
     offs = np.empty(n, np.uint64)
     if n:
         offs[0] = 0
         np.cumsum(lens[:-1], dtype=np.uint64, out=offs[1:])
-    return n, kinds, refs, idxs, terms, offs, lens, b"".join(parts)
+    return (n, np.array(kinds, np.uint8), np.array(refs, np.uint16),
+            np.array(idxs, np.uint64), np.array(terms, np.uint64),
+            offs, lens, b"".join(parts))
 
 
 def frame_batch(records: List[Record], compute_crc: bool = True) -> Optional[bytes]:

@@ -775,35 +775,35 @@ class BatchCoordinator:
         queues the device written-scatter without waiting for a step-
         loop pass. Everything else rides normal ingress ordering."""
         if type(evt) is tuple and evt and evt[0] == "written":
-            self.wal_notify_many([(uid, evt)])
+            _, term, seq = evt
+            if seq is not None and not seq.is_empty():
+                self.wal_notify_many(
+                    [(uid, term, lo, hi) for lo, hi in seq.ranges()])
         else:
             self.deliver((uid, self.name), ("log_event", evt), None)
 
-    def wal_notify_many(self, items) -> None:
+    def wal_notify_many(self, rows) -> None:
         """Bulk durable-watermark delivery from one WAL flush (wire as
-        ``wal.notify_many``): one state-lock round for the whole
-        batch's written events. The durable-ack decoupling invariant
-        (docs/INTERNALS.md §15): everything this touches — the log's
-        written watermark, ``pending_ack``, ``last_ok_sent``, the
-        pending-scatter queue — is guarded by the state lock, and the
-        ack it emits is exactly the ack the step-loop path would have
-        emitted one wave later."""
+        ``wal.notify_many``): rows ``(uid, term, lo, hi)``, one
+        state-lock round for the whole batch's written events. The
+        durable-ack decoupling invariant (docs/INTERNALS.md §15):
+        everything this touches — the log's written watermark,
+        ``pending_ack``, ``last_ok_sent``, the pending-scatter queue —
+        is guarded by the state lock, and the ack it emits is exactly
+        the ack the step-loop path would have emitted one wave later."""
         route_out: Dict[str, List] = {}
         n_written = 0
         t_ask = time.perf_counter_ns()
         with self._wal_lock:
             by_get = self.by_name.get
             sw = self._staged_written
-            for uid, evt in items:
+            for uid, term, _lo, hi in rows:
                 g = by_get(uid)
                 if g is None:
                     continue
-                if not (type(evt) is tuple and evt and evt[0] == "written"):
-                    self.deliver((uid, self.name), ("log_event", evt), None)
-                    continue
                 n_written += 1
-                g.log.handle_event(evt)
-                wi, wt = g.log.last_written()
+                log = g.log
+                wi = log.note_written(term, hi)
                 # the device learns the durable watermark at the next
                 # dispatch (the staged written scatter drives the
                 # quorum scan)
@@ -813,14 +813,15 @@ class BatchCoordinator:
                     leader_sid, cover = g.pending_ack
                     g.pending_ack = None
                     ack = min(wi, cover)
-                    at = g.log.fetch_term(ack)
+                    at = log.fetch_term(ack)
+                    if at is None:
+                        at = log.last_written()[1]
                     out = route_out.get(leader_sid[1])
                     if out is None:
                         route_out[leader_sid[1]] = out = []
                     out.append(
                         (leader_sid,
-                         AppendEntriesReply(g.term, True, ack + 1, ack,
-                                            at if at is not None else wt),
+                         AppendEntriesReply(g.term, True, ack + 1, ack, at),
                          (g.name, self.name))
                     )
             # the round's accounts, written under the lock every writer

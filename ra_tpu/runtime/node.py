@@ -34,6 +34,7 @@ from ra_tpu.runtime.timers import TimerService
 from ra_tpu.runtime.transport import InProcTransport, NodeRegistry, registry as node_registry
 from ra_tpu.server import Server, ServerConfig
 from ra_tpu.system import SystemConfig
+from ra_tpu.utils.seq import Seq
 
 
 logger = logging.getLogger("ra_tpu")
@@ -793,21 +794,23 @@ class RaNode:
         if proc is not None:
             proc.enqueue(LogEvent(evt))
 
-    def _log_notify_many(self, items: List[Tuple[str, Any]]) -> None:
-        """Bulk WAL written-event fan-out: ONE call per fsync batch
-        (the Wal emits at most one written event per writer per batch),
-        enqueued to the server actors in a single pass on the WAL
-        writer thread — durable acks leave without re-entering any
-        shared queue (docs/INTERNALS.md §16)."""
+    def _log_notify_many(self, rows) -> None:
+        """Bulk WAL written-event fan-out: ONE call per fsync batch,
+        rows ``(uid, term, lo, hi)`` that become the public
+        ``("written", term, seq)`` event here, enqueued to the server
+        actors in a single pass on the WAL writer thread — durable acks
+        leave without re-entering any shared queue
+        (docs/INTERNALS.md §16)."""
         name_of = self.directory.name_of
         procs = self.procs
-        for uid, evt in items:
+        for uid, term, lo, hi in rows:
             name = name_of(uid)
             if name is None:
                 continue
             proc = procs.get(name)
             if proc is not None:
-                proc.enqueue(LogEvent(evt))
+                proc.enqueue(
+                    LogEvent(("written", term, Seq.from_range(lo, hi))))
 
     # ------------------------------------------------------------------
     # client plumbing

@@ -74,6 +74,12 @@ class Seq:
         return sum(hi - lo + 1 for lo, hi in self._ranges)
 
     def __contains__(self, idx: int) -> bool:
+        if not self._ranges:
+            return False
+        # the last range first: a log reads mostly near its tail
+        lo, hi = self._ranges[-1]
+        if idx >= lo:
+            return idx <= hi
         i = bisect.bisect_right(self._ranges, (idx, float("inf"))) - 1
         if i < 0:
             return False

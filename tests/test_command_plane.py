@@ -11,6 +11,7 @@ sequence, and the zero-spurious-wakeups invariant of the idle step loop.
 """
 
 import os
+import sys
 import threading
 import time
 
@@ -23,10 +24,13 @@ from ra_tpu.log.tables import TableRegistry
 from ra_tpu.log.wal import Wal
 from ra_tpu.machine import SimpleMachine
 from ra_tpu.ops import consensus as C
-from ra_tpu.protocol import Command, ElectionTimeout, HeartbeatReply, USR
+from ra_tpu.protocol import (
+    AppendEntriesReply, Command, ElectionTimeout, Entry, HeartbeatReply, USR,
+)
 from ra_tpu.rings import IngressRings, SpscRing, WaitGate
 from ra_tpu.runtime.coordinator import BatchCoordinator
 from ra_tpu.runtime.transport import NodeRegistry
+from ra_tpu.utils.seq import Seq
 
 
 @pytest.fixture(autouse=True)
@@ -736,3 +740,283 @@ def test_egress_sender_thread_ships_the_fanout():
     finally:
         for c in coords:
             c.stop()
+
+
+# ---------------------------------------------------------------------------
+# the WAL writer's hand-off: rows ``(uid, term, lo, hi)`` against the
+# per-uid ``("written", term, seq)`` events that the bulk hook carried
+# before them
+
+
+def _handle_events(c, items):
+    """``wal_notify_many`` as it stood when its items were ``(uid,
+    ("written", term, seq))``: ``Log.handle_event`` per uid, then the
+    same staging and deferred-ack release. The reference."""
+    route_out = {}
+    with c._wal_lock:
+        for uid, evt in items:
+            g = c.by_name.get(uid)
+            if g is None:
+                continue
+            g.log.handle_event(evt)
+            wi, wt = g.log.last_written()
+            if c._staged_written.get(g.gid, 0) < wi:
+                c._staged_written[g.gid] = wi
+            if g.pending_ack is not None and wi >= g.pending_ack[1]:
+                leader_sid, cover = g.pending_ack
+                g.pending_ack = None
+                ack = min(wi, cover)
+                at = g.log.fetch_term(ack)
+                route_out.setdefault(leader_sid[1], []).append(
+                    (leader_sid,
+                     AppendEntriesReply(g.term, True, ack + 1, ack,
+                                        at if at is not None else wt),
+                     (g.name, c.name)))
+    for node_name, msgs in route_out.items():
+        c._send_batch(node_name, msgs)
+
+
+class _HandoffNode:
+    """An unstarted coordinator over a flush()-driven Wal that keeps
+    what the writer hands over instead of delivering it."""
+
+    GROUPS = 6
+
+    def __init__(self, tmp_path, name, bulk):
+        self.c = c = BatchCoordinator(name, capacity=8, num_peers=3)
+        self.tables = TableRegistry()
+        self.events, self.rows, self.sent = [], [], []
+        self.wal = Wal(str(tmp_path / name / "wal"), self.tables,
+                       lambda uid, evt: self.events.append((uid, evt)),
+                       threaded=False)
+        if bulk:
+            self.wal.notify_many = self.rows.extend
+        c._send_batch = lambda node, msgs: self.sent.append((node, msgs))
+        self.logs = {}
+        for k in range(self.GROUPS):
+            gname = f"h{k}"
+            log = Log(gname, str(tmp_path / name / "data" / gname),
+                      self.tables, self.wal)
+            c.add_group(gname, f"hcl{k}",
+                        [(gname, name), (gname, "peer1"), (gname, "peer2")],
+                        SimpleMachine(lambda cm, s: s + cm, 0), log=log)
+            self.logs[gname] = log
+
+    def write(self, gname, first, terms):
+        self.logs[gname].write(
+            [Entry(first + k, t, Command(USR, 1)) for k, t in enumerate(terms)])
+
+    def owe_ack(self, gname, cover):
+        self.c.by_name[gname].pending_ack = ((gname, "peer1"), cover)
+
+    def state(self):
+        c = self.c
+        return {
+            "written": {n: log.last_written() for n, log in self.logs.items()},
+            "pending_ack": {n: c.by_name[n].pending_ack for n in self.logs},
+            "staged": dict(c._staged_written),
+            # (one by one: the single door sends each as it is released;
+            # the sender's own name differs between the two nodes)
+            "acks": [(node, to, msg, frm[0]) for node, msgs in self.sent
+                     for to, msg, frm in msgs],
+        }
+
+
+def _first_round(node):
+    node.write("h0", 1, [1, 1, 1])
+    node.owe_ack("h0", 3)          # released at what it covers
+    node.write("h1", 1, [1] * 5)
+    node.owe_ack("h1", 2)          # released below the watermark
+    node.write("h2", 1, [1, 1])
+    node.owe_ack("h2", 4)          # not covered yet: stays owed
+    node.write("h3", 1, [1, 1, 1])
+    node.owe_ack("h3", 3)
+    node.write("h4", 1, [1, 1, 2])  # two terms: two events, two rows
+    node.write("h5", 1, [3])
+    node.wal.flush()
+    # h3's suffix is rewritten before the hand-off arrives: its event
+    # names an entry that is gone (the rewrite itself is still queued)
+    node.write("h3", 3, [2])
+
+
+def _second_round(node):
+    node.write("h2", 3, [1, 1])
+    node.write("h5", 2, [3, 3])
+    node.wal.flush()
+
+
+@pytest.mark.parametrize("door", ["notify_many", "notify"])
+def test_rows_move_what_the_written_events_moved(tmp_path, door):
+    """The same writes on two nodes; one is handed rows through ``door``,
+    the other the events through the per-uid ``handle_event`` loop.
+    After each round: the same durable watermarks, deferred acks (sent
+    and still owed) and staged scatter; a stale row moves nothing."""
+    new = _HandoffNode(tmp_path, "hn_new", bulk=door == "notify_many")
+    old = _HandoffNode(tmp_path, "hn_old", bulk=False)
+    try:
+        for round_ in (_first_round, _second_round):
+            round_(new)
+            round_(old)
+            ghost = ("ghost", ("written", 1, Seq.from_range(1, 9)))
+            if door == "notify_many":
+                assert new.events == [] and len(new.rows) > 1
+                assert [r[:2] for r in new.rows] == \
+                    [(uid, evt[1]) for uid, evt in old.events]
+                new.c.wal_notify_many(new.rows + [("ghost", 1, 1, 9)])
+            else:
+                assert new.events == old.events
+                for uid, evt in new.events + [ghost]:
+                    new.c.wal_notify(uid, evt)
+            _handle_events(old.c, old.events + [ghost])
+            assert new.state() == old.state()
+            events = len(old.events)
+            assert new.c.counters.get("wal_notify_events") == events
+            assert new.c.counters.get("wal_notify_batches") == \
+                (1 if door == "notify_many" else events + 1)
+            for node in (new, old):
+                del node.events[:], node.rows[:]
+                node.c.counters.put("wal_notify_events", 0)
+                node.c.counters.put("wal_notify_batches", 0)
+            if round_ is _first_round:
+                got = new.state()
+                assert got["written"]["h0"] == (3, 1)
+                assert got["written"]["h3"] == (0, 0)  # the stale row
+                assert got["pending_ack"]["h3"] is not None
+                assert got["pending_ack"]["h2"] == (("h2", "peer1"), 4)
+                assert got["written"]["h4"] == (3, 2)
+                assert [(to[0], msg.last_index)
+                        for _n, to, msg, _f in got["acks"]] == [("h0", 3), ("h1", 2)]
+        got = new.state()
+        assert got["written"]["h3"] == (3, 2) and got["pending_ack"]["h3"] is None
+        assert got["written"]["h2"] == (4, 1) and got["pending_ack"]["h2"] is None
+        assert got["staged"] == {new.c.by_name[n].gid: w[0]
+                                 for n, w in got["written"].items()}
+    finally:
+        for node in (new, old):
+            node.c.stop()
+            node.wal.close()
+
+
+def test_wal_notify_takes_a_seq_of_several_ranges_range_by_range(tmp_path):
+    node = _HandoffNode(tmp_path, "hn_seq", bulk=False)
+    try:
+        node.write("h0", 1, [1] * 6)
+        node.wal.flush()
+        node.c.wal_notify("h0", ("written", 1, Seq([(1, 2), (4, 4)])))
+        assert node.logs["h0"].last_written() == (4, 1)
+        assert node.c.counters.get("wal_notify_events") == 2
+        node.c.wal_notify("h0", ("written", 1, Seq.empty()))
+        node.c.wal_notify("h0", ("written", 1, None))
+        assert node.c.counters.get("wal_notify_batches") == 1
+    finally:
+        node.c.stop()
+        node.wal.close()
+
+
+def test_no_ack_ahead_of_its_fsync_under_a_short_switch_interval(tmp_path):
+    """Three started nodes over threaded WAL writers, six groups, four
+    callers, the interpreter handing the core over every 10 us: no
+    follower's ack and no node's durable watermark ever names an index
+    that its WAL had not written and synced first (what the writer
+    hands over is noted on its way to the coordinator)."""
+    names = [f"sw{i}" for i in range(3)]
+    groups = [f"s{k}" for k in range(6)]
+    coords, storage, durable, doors, ahead = [], [], {}, {}, []
+
+    def tap(c, wal):
+        mine = durable[c.name] = {}
+        used = doors[c.name] = {"notify_many": 0, "notify": 0}
+
+        def note(uid, hi):
+            if hi > mine.get(uid, 0):
+                mine[uid] = hi
+
+        def many(rows):
+            used["notify_many"] += 1
+            for uid, _term, _lo, hi in rows:
+                note(uid, hi)
+            c.wal_notify_many(rows)
+
+        def one(uid, evt):
+            if evt[0] == "written":
+                used["notify"] += 1
+                note(uid, evt[2].last())
+            c.wal_notify(uid, evt)
+
+        def on_send(to, msg):
+            if type(msg) is AppendEntriesReply and msg.success \
+                    and msg.last_index > mine.get(to[0], 0):
+                ahead.append((c.name, to[0], msg.last_index, mine.get(to[0], 0)))
+            return False
+
+        wal.notify, wal.notify_many = one, many
+        c.transport.drop_fn = on_send
+
+    interval = sys.getswitchinterval()
+    stop = threading.Event()
+    done = [0] * 4
+    errors = []
+    callers = []
+    try:
+        for n in names:
+            c = BatchCoordinator(n, capacity=8, num_peers=3,
+                                 election_timeout_s=0.5, detector_poll_s=0.1,
+                                 tick_interval_s=0.2)
+            storage.append(_wal_backed(c, str(tmp_path / n)))
+            tap(c, storage[-1][1])
+            coords.append(c)
+        for c, (tables, wal, _sw, d) in zip(coords, storage):
+            for g in groups:
+                c.add_group(g, f"swcl_{g}", [(g, n) for n in names],
+                            SimpleMachine(lambda cm, s: s + cm, 0),
+                            log=Log(g, os.path.join(d, "data", g), tables, wal))
+            c.start()
+        coords[0].deliver_many(
+            [((g, names[0]), ElectionTimeout(), None) for g in groups])
+        await_(lambda: all(coords[0].by_name[g].role == C.R_LEADER
+                           for g in groups), what="six leaders")
+
+        def call(k):
+            i = k
+            while not stop.is_set():
+                try:
+                    api.process_command((groups[i % len(groups)], names[0]), 1,
+                                        timeout=30)
+                    done[k] += 1
+                except Exception as exc:  # noqa: BLE001
+                    errors.append(exc)
+                    return
+                i += 1
+
+        sys.setswitchinterval(1e-5)
+        callers = [threading.Thread(target=call, args=(k,), daemon=True)
+                   for k in range(4)]
+        for t in callers:
+            t.start()
+        deadline = time.monotonic() + 3.0
+        while time.monotonic() < deadline:
+            for c in coords:
+                for g in groups:
+                    wi = c.by_name[g].log.last_written()[0]
+                    # (read after the watermark: the note comes first)
+                    if wi > durable[c.name].get(g, 0):
+                        ahead.append((c.name, g, "watermark", wi))
+            time.sleep(0.005)
+        stop.set()
+        for t in callers:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in callers)
+    finally:
+        stop.set()
+        sys.setswitchinterval(interval)
+        for c in coords:
+            c.stop()
+        _close_storage(storage)
+    assert errors == [] and ahead == []
+    assert sum(done) >= 20, done
+    # both doors of the hand-off ran under it
+    assert all(d["notify_many"] > 0 for d in doors.values()), doors
+    assert sum(d["notify"] for d in doors.values()) > 0, doors
+    total = sum(done)
+    assert sum(c.by_name[g].machine_state for g in groups
+               for c in coords[:1]) == total

@@ -35,7 +35,8 @@ WINDOW_S = 2.0
 NODES = ("bench0", "bench1", "bench2")
 READERS = ("step_roofline", "full_width_steps_pct", "wal_entries_per_fsync",
            "wal_notify_wait_ms_per_kop", "ingest_append_ms_per_kop",
-           "egress_apply_ms_per_kop", "gc_pause_ms_per_kop")
+           "egress_apply_ms_per_kop", "gc_pause_ms_per_kop",
+           "wal_cpu_us_per_entry", "wal_runs_in_place_pct")
 
 
 def _bench():
@@ -76,12 +77,11 @@ def _watched_run(groups):
             if applying[self.name]:
                 seen["apply"][self.name] += 1
 
-    def wal_notify_many(self, items):
+    def wal_notify_many(self, rows):
         # (one WAL writer per coordinator: its rounds come one by one)
-        written = sum(1 for _uid, evt in items
-                      if type(evt) is tuple and evt and evt[0] == "written")
+        written = len(rows)
         before = self.counters.get("wal_notify_events")
-        notify_many(self, items)
+        notify_many(self, rows)
         after = self.counters.get("wal_notify_events")
         if after - before != written:
             seen["mismatch"].append((self.name, written, after - before))
@@ -234,11 +234,14 @@ def test_reader_reads_a_float_here_and_nothing_from_an_empty_run(
     # a program without the new accounts (the parent's) reads as nothing
     # where the metric needs them, never as an error
     if name in ("wal_notify_wait_ms_per_kop", "ingest_append_ms_per_kop",
-                "egress_apply_ms_per_kop", "gc_pause_ms_per_kop"):
+                "egress_apply_ms_per_kop", "gc_pause_ms_per_kop",
+                "wal_cpu_us_per_entry", "wal_runs_in_place_pct"):
         def without(snap):
             return {**snap,
                     "coordinator": {k: v for k, v in snap["coordinator"].items()
                                     if not k.startswith(("wal_notify_", "gc_"))},
+                    "wal": {k: v for k, v in snap["wal"].items()
+                            if k not in ("writer_cpu_ns", "runs", "runs_in_place")},
                     "wave": {k: v for k, v in snap["wave"].items()
                              if k not in ("ingest_append", "egress_apply")}}
 

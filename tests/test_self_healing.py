@@ -128,7 +128,9 @@ def test_wal_death_on_follower_heals_and_catches_up(cluster):
     # and its copy is durable again: the follower's server is out of
     # await_condition
     srv = fnode.procs[follower[0]].server
-    await_(lambda: srv.role in ("follower", "leader"), timeout=10,
+    # (where the restarts tripped the intensity throttle, healing comes
+    # once a 10 s window: the wait has to be longer than one)
+    await_(lambda: srv.role in ("follower", "leader"), timeout=30,
            what="role restored")
 
 
