@@ -267,6 +267,26 @@ COORDINATOR_FIELDS: List[FieldSpec] = [
     ("gc_pause_ns", "counter",
      "time inside those collections, every Python thread stopped, "
      "summed (perf_counter_ns, one pair a collection)"),
+    # -- machine effects on the batch backend (_realise_effects; booked
+    # once a step that realised any)
+    ("effects_send_msg", "counter",
+     "send_msg effects realised (leader only): deliveries handed to "
+     "send_msg_cb, a future or the transport"),
+    ("effects_other", "counter",
+     "other non-log effects taken up on the leader (monitor, demonitor, "
+     "timer, mod_call, log read, reply, aux, append)"),
+    ("release_cursors", "counter",
+     "release_cursor effects seen on this node (every replica realises "
+     "log effects)"),
+    ("release_cursor_snapshots", "counter",
+     "those after which the group's snapshot index moved (the log cut a "
+     "snapshot; the others fell under min_snapshot_interval)"),
+    ("monitors_armed", "counter",
+     "monitor effects realised into this node's monitor table (first "
+     "checkouts, and a new leader's state_enter re-arming)"),
+    ("monitor_downs", "counter",
+     "builtin down commands delivered by process_down to groups led "
+     "here that watched the target"),
     ("egress_thread_batches", "counter",
      "per-destination message batches shipped by the dedicated egress "
      "sender thread (off the step loop)"),

@@ -143,6 +143,14 @@ class FifoMachine(Machine):
             return st, ("ok", None), effects
         return state, ("error", "unknown_op")
 
+    def state_enter(self, role: str, state: FifoState):
+        """A fresh leader re-issues the monitor of every attached
+        consumer: monitors are leader-local runtime state, lost on
+        failover (reference: ra_fifo:state_enter(leader, _))."""
+        if role != "leader":
+            return []
+        return [Monitor("process", cid, "machine") for cid in state.consumers]
+
     def _service(self, st: FifoState, effects) -> None:
         """Deliver queued messages to ready consumers, up to each
         consumer's prefetch credit (reference: checkout credit)."""

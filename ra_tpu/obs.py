@@ -309,6 +309,11 @@ WAVE_SUBSET_PHASES = {
     "egress_apply": "subset of host_egress (machine apply + client "
                     "replies of every group the step committed; no "
                     "sample on a step that committed nothing)",
+    "effects_realise": "subset of host_egress, and of egress_apply where "
+                       "an apply returned them (machine effects realised: "
+                       "send_msg, monitors, release cursors, ...; one "
+                       "clock pair a _realise_effects call, added up; no "
+                       "sample on a step that realised none)",
 }
 WAVE_PHASES = WAVE_STEP_PHASES + tuple(WAVE_SUBSET_PHASES.items())
 

@@ -97,6 +97,7 @@ from ra_tpu.protocol import (
     USR,
 )
 from ra_tpu.runtime import heap as _heap
+from ra_tpu.runtime.monitors import Monitors
 from ra_tpu.runtime.transport import InProcTransport, NodeRegistry, registry as node_registry
 
 logger = logging.getLogger("ra_tpu")
@@ -502,6 +503,19 @@ class BatchCoordinator:
         # publish from the drainer thread would deadlock, so they ride
         # this state-lock-guarded queue into the next drain instead
         self._internal_q: deque = deque()
+        # machine monitors of the groups led here (Monitor / Demonitor
+        # effects, ``process_down``): watcher = the group's server id
+        self.monitors = Monitors()
+        # effects realised since the last flush, under the state lock:
+        # [clock ns inside _realise_effects, send_msg, other non-log
+        # effects, release cursors seen, of those that cut a snapshot,
+        # monitors armed, effects handed in]; _finish_ticket books them
+        # once a step
+        self._fx_acc = [0, 0, 0, 0, 0, 0, 0]
+        # under a profiler session (_fx_tr) the step's effect stretch is
+        # one span, opened at its first effect
+        self._fx_tr = False
+        self._fx_span = None
         # must-deliver overflow from FOREIGN threads (peer coordinator
         # step/egress/WAL threads, detector timers) whose publish hit a
         # full lane: never dropped, and never gate-waited either — a
@@ -766,6 +780,37 @@ class BatchCoordinator:
             self._internal_q.append((self._R_CMD, name, msg))
         else:
             self._internal_q.append((self._R_MSG, name, None, msg))
+
+    def process_down(self, target, info="noproc") -> int:
+        """A monitored process went away (the caller is whatever on
+        this node learns it: a connection closed, a consumer's owner
+        saying so): every group led here that watches ``target`` gets
+        the builtin ``("down", target, info)`` command, appended and
+        replicated like any other (an ``aux`` watch gets the aux cast).
+        A monitor fires once, as an Erlang monitor does: the machine
+        arms it again when the target checks in again. Any thread but
+        one inside ``send_msg_cb`` (that one holds the state lock).
+        Returns the number of groups told."""
+        told = 0
+        with self._state_lock:
+            for watcher, component in self.monitors.watchers("process", target):
+                self.monitors.remove(watcher, "process", target)
+                g = self.by_name.get(watcher[0])
+                if g is None or g.role != C.R_LEADER:
+                    continue
+                if component == "aux":
+                    self._deliver_internal(
+                        g.name, ("aux", "cast", ("down", target, info), None))
+                else:
+                    self._deliver_internal(
+                        g.name, Command(kind=USR, data=("down", target, info),
+                                        internal=True))
+                told += 1
+            if told:
+                self.counters.incr("monitor_downs", told)
+        if told and not self._wake.is_set():
+            self._wake.set()
+        return told
 
     def wal_notify(self, uid: str, evt) -> None:
         """Log-event entry point for WAL / segment-writer notify
@@ -1997,6 +2042,7 @@ class BatchCoordinator:
         _t_dev = time.perf_counter_ns()
         if cpu:
             _c_dev = time.thread_time_ns()
+        self._fx_tr = tr
         if eg_np is not None:
             if tr:
                 sp = _obs.begin("ra/egress/host_egress", node=node)
@@ -2013,7 +2059,10 @@ class BatchCoordinator:
                 sp = _obs.begin("ra/egress/rare", node=node)
             self._handle_rares(ticket.rare)
             if tr:
+                self._end_effects_span()
                 _obs.end(sp)
+        if self._fx_acc[0]:
+            self._book_effects()
         if cpu:
             _c_eg = time.thread_time_ns()
         _t_eg = time.perf_counter_ns()
@@ -3002,6 +3051,7 @@ class BatchCoordinator:
                     g.pending_queries = []
                     g.leader_slot = leader_l[p]  # hint before the sweep
                     self._fail_pending(g)
+                entered = (new_role == C.R_LEADER) != (g.role == C.R_LEADER)
                 g.role = new_role
                 g.term = gterm_l[p]
                 g.leader_slot = leader_l[p]
@@ -3017,6 +3067,8 @@ class BatchCoordinator:
                     self._broadcast_vote_req(g, queue_send, pre=False)
                 if bl_l[p]:
                     self._on_became_leader(g, aer_dirty)
+                if entered:
+                    self._state_enter(g)
                 ci2 = ca_l[p]
                 if ci2 > g.last_applied:
                     _t_app = clock_ns()
@@ -3036,6 +3088,8 @@ class BatchCoordinator:
             if apply_ns:
                 # sub-phase egress_apply: the step's applies, one record
                 self._wave_h["egress_apply"].record(apply_ns)
+            if self._fx_span is not None:
+                self._end_effects_span()
 
         for node_name, msgs in outbound.items():
             self._send_batch(node_name, msgs)
@@ -3378,19 +3432,41 @@ class BatchCoordinator:
     # src/ra_machine.erl:131-159, realised per src/ra_server_proc.erl
     # handle_effects) -----------------------------------------------------
 
+    def _state_enter(self, g: GroupHost) -> None:
+        """The group's role changed to leader or away from it (caller
+        holds the state lock, ``g.role`` is the new role): a replica that
+        left leadership forgets its watches (monitors are leader-local
+        runtime state), and the machine's ``state_enter`` says what the
+        new role arms (reference: ra_machine state_enter effects; a
+        quorum-queue machine re-issues a monitor per consumer)."""
+        is_leader = g.role == C.R_LEADER
+        if not is_leader:
+            self.monitors.forget((g.name, self.name))
+        mac = g.machine.which_module(g.effective_machine_version)
+        effs = mac.state_enter(self._ROLE_NAMES.get(g.role, g.role),
+                               g.machine_state)
+        if effs:
+            self._realise_effects(g, effs, is_leader)
+
     def _realise_effects(self, g: GroupHost, effs, is_leader: bool = True) -> None:
-        """Machine effects. Log effects (release_cursor / checkpoint)
-        are realised on EVERY replica — followers must truncate too;
-        the rest (send_msg, mod_call, timer, log read, reply, aux) are
-        leader-only on the apply path. Monitor/demonitor effects need
-        the actor runtime's monitor registry — groups using them should
-        run on the per_group_actor backend."""
+        """Machine effects, in apply order, on the thread that applied
+        (the egress thread; the step thread for ticks and aux), under
+        the state lock. Log effects (release_cursor / checkpoint) are
+        realised on EVERY replica (followers must truncate too); the
+        rest (send_msg, monitor, demonitor, mod_call, timer, log read,
+        reply, aux) on the leader only. One clock pair a call, none per
+        effect; the accounts are booked once a step (_finish_ticket)."""
+        if self._fx_tr and self._fx_span is None:
+            self._fx_span = _obs.begin("ra/egress/effects", node=self.name)
+        sent = other = cursors = snaps = armed = 0
+        t0 = time.perf_counter_ns()
         for eff in effs:
             if not is_leader and not isinstance(
                 eff, (fx.ReleaseCursor, fx.Checkpoint, fx.TryAppend)
             ):
                 continue
             if isinstance(eff, fx.ReleaseCursor):
+                cursors += 1
                 mac = g.machine.which_module(g.effective_machine_version)
                 g.log.update_release_cursor(
                     eff.index,
@@ -3399,7 +3475,8 @@ class BatchCoordinator:
                     eff.machine_state,
                     live_indexes=tuple(mac.live_indexes(eff.machine_state)),
                 )
-                self._sync_snapshot_floor(g)
+                if self._sync_snapshot_floor(g):
+                    snaps += 1
             elif isinstance(eff, fx.Checkpoint):
                 mac = g.machine.which_module(g.effective_machine_version)
                 g.log.checkpoint(
@@ -3410,6 +3487,7 @@ class BatchCoordinator:
                     live_indexes=tuple(mac.live_indexes(eff.machine_state)),
                 )
             elif isinstance(eff, fx.SendMsg):
+                sent += 1
                 cb = self.send_msg_cb
                 if cb is not None:
                     try:
@@ -3420,14 +3498,24 @@ class BatchCoordinator:
                     self._reply(eff.to, eff.msg)
                 elif isinstance(eff.to, tuple) and len(eff.to) == 2:
                     self.transport.send(eff.to, eff.msg, from_sid=(g.name, self.name))
+            elif isinstance(eff, fx.Monitor):
+                armed += 1
+                self.monitors.add((g.name, self.name), eff.kind, eff.target,
+                                  eff.component)
+            elif isinstance(eff, fx.Demonitor):
+                other += 1
+                self.monitors.remove((g.name, self.name), eff.kind, eff.target)
             elif isinstance(eff, fx.ModCall):
+                other += 1
                 try:
                     eff.fn(*eff.args)
                 except Exception:  # noqa: BLE001
                     pass
             elif isinstance(eff, fx.Timer):
+                other += 1
                 self._machine_timer(g, eff)
             elif isinstance(eff, fx.LogRead):
+                other += 1
                 entries = g.log.sparse_read(list(eff.indexes))
                 out = eff.fn(entries)
                 if out is not None:
@@ -3437,10 +3525,13 @@ class BatchCoordinator:
                     # a full lane must not block the drainer on itself)
                     self._deliver_internal(g.name, out)
             elif isinstance(eff, fx.Reply):
+                other += 1
                 self._reply(eff.from_ref, eff.reply)
             elif isinstance(eff, fx.Aux):
+                other += 1
                 self._deliver_internal(g.name, ("aux", "cast", eff.cmd, None))
             elif isinstance(eff, (fx.Append, fx.TryAppend)):
+                other += 1
                 # machine-originated command re-enters via the command
                 # queue: the next step's drain appends it on the leader;
                 # a TryAppend on a non-leader redirects per command
@@ -3455,8 +3546,46 @@ class BatchCoordinator:
                             from_ref=eff.from_ref if is_leader else None,
                             internal=True),
                 )
+        acc = self._fx_acc
+        # (never 0: a step that realised effects books them)
+        acc[0] += (time.perf_counter_ns() - t0) or 1
+        acc[1] += sent
+        acc[2] += other + armed
+        acc[3] += cursors
+        acc[4] += snaps
+        acc[5] += armed
+        acc[6] += len(effs)
 
-    def _sync_snapshot_floor(self, g: GroupHost) -> None:
+    def _end_effects_span(self) -> None:
+        """Close the step's ``ra/egress/effects`` span (first effect ->
+        here) inside the span that holds it, with the effects so far."""
+        sp = self._fx_span
+        if sp is not None:
+            self._fx_span = None
+            sp.set_metadata(effects=self._fx_acc[6])
+            _obs.end(sp)
+
+    def _book_effects(self) -> None:
+        """The step's effect accounts, once a step that realised any:
+        the sub-phase ``effects_realise`` and the effect counters."""
+        acc = self._fx_acc
+        ns, sent, other, cursors, snaps, armed, _n = acc
+        acc[:] = (0, 0, 0, 0, 0, 0, 0)
+        self._wave_h["effects_realise"].record(ns)
+        cnt = self.counters
+        if sent:
+            cnt.incr("effects_send_msg", sent)
+        if other:
+            cnt.incr("effects_other", other)
+        if cursors:
+            cnt.incr("release_cursors", cursors)
+        if snaps:
+            cnt.incr("release_cursor_snapshots", snaps)
+        if armed:
+            cnt.incr("monitors_armed", armed)
+
+    def _sync_snapshot_floor(self, g: GroupHost) -> bool:
+        """Tell the device a snapshot the log took; whether it took one."""
         snap = g.log.snapshot_index_term()
         if snap is not None and snap[0] > g.snap_floor:
             g.snap_floor = snap[0]
@@ -3466,6 +3595,8 @@ class BatchCoordinator:
                 jnp.asarray([snap[0]], jnp.int32),
                 jnp.asarray([snap[1]], jnp.int32),
             )
+            return True
+        return False
 
     def _machine_timer(self, g: GroupHost, eff: fx.Timer) -> None:
         old = g.machine_timers.pop(eff.name, None)
@@ -4372,6 +4503,7 @@ class BatchCoordinator:
             # deposed outside the device mailbox: same redirect contract
             # as the egress role-transition path
             self._fail_pending(g)
+            self._state_enter(g)
         if bumped and self.meta is not None:
             # entering a new term clears the durable vote (the device
             # mailbox path resets voted_for on term bumps identically)

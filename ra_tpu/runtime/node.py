@@ -28,6 +28,7 @@ from ra_tpu.log.tables import TableRegistry
 from ra_tpu.log.wal import Wal
 from ra_tpu.machine import Machine
 from ra_tpu.protocol import DownEvent, ElectionTimeout, FromPeer, LogEvent, ServerId
+from ra_tpu.runtime.monitors import Monitors
 from ra_tpu.runtime.proc import ServerProc
 from ra_tpu.runtime.scheduler import Scheduler
 from ra_tpu.runtime.timers import TimerService
@@ -38,28 +39,6 @@ from ra_tpu.utils.seq import Seq
 
 
 logger = logging.getLogger("ra_tpu")
-
-
-class Monitors:
-    """watcher server-id -> monitored targets (reference: ra_monitors)."""
-
-    def __init__(self) -> None:
-        self._lock = threading.Lock()
-        # (kind, target) -> {(watcher_sid, component)}
-        self._tab: Dict[Tuple[str, Any], set] = {}
-
-    def add(self, watcher: ServerId, kind: str, target: Any, component: str) -> None:
-        with self._lock:
-            self._tab.setdefault((kind, target), set()).add((watcher, component))
-
-    def remove(self, watcher: ServerId, kind: str, target: Any) -> None:
-        with self._lock:
-            s = self._tab.get((kind, target))
-            if s:
-                self._tab[(kind, target)] = {(w, c) for w, c in s if w != watcher}
-
-    def watchers(self, kind: str, target: Any) -> List[Tuple[ServerId, str]]:
-        return list(self._tab.get((kind, target), ()))
 
 
 class RaNode:

@@ -251,7 +251,8 @@ def test_system_overview_live_batch_cluster(three_coords):
     # wave phases non-zero under load: every slice of a step, and every
     # sub-phase a leader's step, realisation and apply of four client
     # commands must pass through (group 0 is on the sample mask). The
-    # native sub-phases have no sample while the native path is off;
+    # native sub-phases have no sample while the native path is off,
+    # effects_realise none while no machine returns an effect;
     # system_overview leaves an empty histogram out.
     wave = {k[2]: v for k, v in ov["histograms"].items()
             if isinstance(k, tuple) and k[0] == "wave" and k[1] == "ot0"}
@@ -259,8 +260,11 @@ def test_system_overview_live_batch_cluster(three_coords):
     for ph, _ in obs.WAVE_STEP_PHASES + (("apply", ""),):
         assert wave.get(ph, {}).get("count", 0) > 0, (ph, wave.keys())
         assert wave[ph]["sum_ms"] > 0, ph
-    for ph in set(obs.WAVE_SUBSET_PHASES) - {"classify_native", "pack_native"}:
+    for ph in set(obs.WAVE_SUBSET_PHASES) - {"classify_native", "pack_native",
+                                             "effects_realise"}:
         assert wave.get(ph, {}).get("count", 0) > 0, (ph, wave.keys())
+    # this machine returns no effect: nothing was realised
+    assert "effects_realise" not in wave
     # one record per pass that had commands / per step that committed:
     # never more than the phases they are subsets of
     assert wave["ingest_append"]["count"] <= wave["ingress_drain"]["count"]
