@@ -267,6 +267,19 @@ COORDINATOR_FIELDS: List[FieldSpec] = [
     ("gc_pause_ns", "counter",
      "time inside those collections, every Python thread stopped, "
      "summed (perf_counter_ns, one pair a collection)"),
+    # -- the detector thread (_detect_loop): how often its masks leave
+    # a row for its Python, and what the thread costs
+    ("detector_passes", "counter",
+     "passes of the detector thread (one a detector_poll_s; one in "
+     "tick_interval_s / detector_poll_s of them holds the tick's work)"),
+    ("detector_rows_walked", "counter",
+     "group rows its per-row Python ran for, the suspicion sweep, the "
+     "lane watchdog and the tick's probes added: the rows their masks "
+     "over the role, contact, ack and pending arrays left (0 on a poll "
+     "of a healthy elected fleet)"),
+    ("detector_cpu_ns", "counter",
+     "thread CPU inside those passes, summed (thread_time_ns, one pair "
+     "a pass; the clock ticks in 10 ms on some hosts, so read sums)"),
     # -- machine effects on the batch backend (_realise_effects; booked
     # once a step that realised any)
     ("effects_send_msg", "counter",

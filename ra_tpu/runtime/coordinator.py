@@ -39,6 +39,7 @@ and actor-backed members interoperate in either direction.
 from __future__ import annotations
 
 import logging
+import operator
 import pickle
 import random
 import threading
@@ -130,29 +131,68 @@ def parse_native(spec) -> frozenset:
     return parts
 
 
+@jax.jit
+def _health_copies(st):
+    """Fresh device copies of the state arrays ``_health_scan`` reads,
+    in one dispatch (the detector thread pays for each entry into JAX
+    with a wait for the interpreter lock)."""
+    return tuple(jnp.copy(a) for a in (
+        st.current_term, st.commit_index, st.last_index, st.role,
+        st.leader_slot, st.self_slot, st.match_index, st.active,
+    ))
+
+
+class GroupMirrors:
+    """Per-group facts as flat ``(capacity,)`` / ``(capacity, P)``
+    arrays, one row a group id: what the detector thread judges every
+    group by, read as masks so that its Python runs only for the rows a
+    mask leaves (docs/INTERNALS.md §14). Each has one writer:
+    ``contact`` and ``role`` the ``GroupHost`` properties, ``last_ack``
+    the host's own row view, ``peers`` ``_sync_peer_row``, ``pending``
+    ``_handle_commands`` (set) and the lane watchdog (cleared)."""
+
+    __slots__ = ("contact", "role", "last_ack", "pending", "peers")
+
+    FREE = -1  # the role of a row no group holds: no mask passes it
+
+    def __init__(self, capacity: int, num_peers: int):
+        self.contact = np.zeros(capacity, np.float64)  # last_contact
+        self.role = np.full(capacity, self.FREE, np.int8)
+        # last AER ack per slot, 0.0 for never (a leader's silent peers)
+        self.last_ack = np.zeros((capacity, num_peers), np.float64)
+        # 1 while pending_replies may hold client futures
+        self.pending = np.zeros(capacity, np.uint8)
+        # slots that hold a member other than this node's own
+        self.peers = np.zeros((capacity, num_peers), bool)
+
+
 class GroupHost:
     """Host-side companion of one device-resident group."""
 
     __slots__ = (
         "gid", "name", "cluster_name", "members", "self_slot", "log",
-        "machine", "machine_state", "last_applied", "role", "term",
+        "machine", "machine_state", "last_applied", "_role", "term",
         "leader_slot", "next_index", "commit_sent", "pending_replies",
         "inbox", "host_term_hint", "election_ref", "effective_machine_version",
         "pending_ack", "snap_accept", "snap_senders", "pre_vote_token",
         "voter_status", "cluster_change_permitted", "cluster_index",
         "pending_queries", "machine_timers", "has_tick", "snap_floor",
         "noop_index", "noop_committed", "query_seq", "cluster_history",
-        "last_ack", "aux_state", "aux_inited", "last_contact", "low_q",
+        "last_ack", "aux_state", "aux_inited", "low_q",
         "specials", "last_ok_sent", "fresh_tail", "match_hint", "lat",
-        "_clock", "fresh_anchor", "fresh_ts", "lease_contact",
+        "_clock", "fresh_anchor", "fresh_ts", "lease_contact", "_mirrors",
     )
 
     def __init__(self, gid, name, cluster_name, members, self_slot, log, machine,
-                 clock=None):
+                 mirrors, clock=None):
         from ra_tpu.runtime.clock import WALL
 
         self._clock = clock or WALL
         self.gid = gid
+        # this row of the coordinator's per-group arrays (the detector
+        # reads them as masks): a new occupant starts them afresh
+        self._mirrors = mirrors
+        mirrors.pending[gid] = 0
         self.name = name
         self.cluster_name = cluster_name
         self.members: List[ServerId] = list(members)
@@ -211,9 +251,12 @@ class GroupHost:
         # when a new leader truncates that suffix.
         # [(entry_index, members_copy, voter_status_copy), ...]
         self.cluster_history: List[Tuple[int, List, Dict[int, Any]]] = []
-        # per-slot monotonic time of the last AER ack (leader-side);
-        # drives the periodic resync of silent peers
-        self.last_ack: Dict[int, float] = {}
+        # per-slot monotonic time of the last AER ack (leader-side), 0.0
+        # for "never": this group's row of the coordinator's (capacity,
+        # P) table, the only copy. Drives the periodic resync of silent
+        # peers
+        self.last_ack = mirrors.last_ack[gid]
+        self.last_ack[:] = 0.0
         # aux machine state (initialized lazily on first aux message)
         self.aux_state: Any = None
         self.aux_inited = False
@@ -268,6 +311,24 @@ class GroupHost:
         self.fresh_anchor: Tuple[int, float] = (0, 0.0)
         self.fresh_ts = 0.0
         self.lease_contact = 0.0
+
+    # ``role`` keeps its scalar for the wave threads' many reads; the
+    # setter is the one writer of both it and the detector's mirror.
+    # ``last_contact`` lives in the mirror alone.
+    role = property(operator.attrgetter("_role"))
+
+    @role.setter
+    def role(self, role: int) -> None:
+        self._role = role
+        self._mirrors.role[self.gid] = role
+
+    @property
+    def last_contact(self) -> float:
+        return float(self._mirrors.contact[self.gid])
+
+    @last_contact.setter
+    def last_contact(self, t: float) -> None:
+        self._mirrors.contact[self.gid] = t
 
     def slot_of(self, sid: ServerId) -> int:
         try:
@@ -588,6 +649,17 @@ class BatchCoordinator:
         self._pending_roles: List[Tuple[int, int]] = []
         self._hot: set = set()  # gids with queued inbox msgs / term hints
         self._applied_np = np.zeros(capacity, np.int64)  # last_applied mirror
+        # what the detector reads as masks (GroupMirrors), under the
+        # names its passes use; node names any group's members live on
+        # (a dead leader's node need not be one the registry still has)
+        self._mirrors = GroupMirrors(capacity, num_peers)
+        self._contact_np = self._mirrors.contact
+        self._role_np = self._mirrors.role
+        self._last_ack_np = self._mirrors.last_ack
+        self._pending_np = self._mirrors.pending
+        self._peer_np = self._mirrors.peers
+        self._peer_nodes: set = set()
+        self._tick_gids: List[int] = []  # groups whose machine has a tick
         # mailbox pack buffers, double-buffered (docs/INTERNALS.md §15):
         # a build hands back the numpy buffer itself and the jitted step
         # takes it as its argument; the buffer returns to the pool only
@@ -1017,8 +1089,7 @@ class BatchCoordinator:
             if width >= cap:
                 break
             width <<= 1
-        for a in scratch:
-            jnp.copy(a)
+        _health_copies(scratch)
         np.asarray(eg)  # dispatch is async: wait for the last program
         return ran
 
@@ -1105,7 +1176,7 @@ class BatchCoordinator:
             g = GroupHost(
                 gid, name, cluster_name, members, members.index(sid),
                 log or MemoryLog(auto_written=True), machine,
-                clock=self.clock,
+                self._mirrors, clock=self.clock,
             )
             # restart safety: reload the durable term/vote so this
             # member cannot re-vote in a term it already voted in
@@ -1206,6 +1277,9 @@ class BatchCoordinator:
             self.groups[g.gid] = g
             self.by_name[name] = g
             self._hslots.append(self._health.ensure(name, g.cluster_name))
+            self._sync_peer_row(g)
+            if g.has_tick:
+                self._tick_gids.append(g.gid)
         self.n_groups += len(hosts)
         return sids
 
@@ -2456,6 +2530,11 @@ class BatchCoordinator:
                     elif cmd.reply_mode == "await_consensus":
                         pending[idx] = cmd.from_ref
                 idx += 1
+            if pending:
+                # the lane watchdog's mask: set here, after the futures
+                # are in, once a batch; the watchdog clears it when it
+                # finds the table empty
+                self._pending_np[gid] = 1
         if idx == first:
             return  # every command was rejected
         last = idx - 1
@@ -2542,7 +2621,7 @@ class BatchCoordinator:
     def _alloc_slot(self, g: GroupHost) -> Optional[int]:
         for i, m in enumerate(g.members):
             if m is None:
-                g.last_ack.pop(i, None)  # fresh occupant, fresh liveness
+                g.last_ack[i] = 0.0  # fresh occupant, fresh liveness
                 g.match_hint[i] = 0  # nothing confirmed for the newcomer
                 return i  # reuse a tombstoned slot
         if len(g.members) < self.P:
@@ -2566,10 +2645,21 @@ class BatchCoordinator:
             active=self.state.active.at[g.gid].set(jnp.asarray(active)),
             voting=self.state.voting.at[g.gid].set(jnp.asarray(voting)),
         )
+        self._sync_peer_row(g)
         if self.lease_cfg.enabled:
             if g.role == C.R_LEADER:
                 self._lease_revoke(g, "membership change")
             self._lease_sync(g)
+
+    def _sync_peer_row(self, g: GroupHost) -> None:
+        """The member table as the detector's masks see it: which slots
+        hold a peer, and the nodes any member lives on."""
+        row = self._peer_np[g.gid]
+        row[:] = False
+        for s, m in enumerate(g.members):
+            if m is not None:
+                row[s] = s != g.self_slot
+                self._peer_nodes.add(m[1])
 
     def _adopt_cluster_cmd(self, g: GroupHost, cmd: Command, entry_index: int = 0) -> None:
         """Follower-side adoption of a replicated cluster change (slot
@@ -3029,18 +3119,19 @@ class BatchCoordinator:
                 if g is None:
                     continue
                 new_role = role_l[p]
-                if new_role != g.role:
+                old_role = g.role
+                if new_role != old_role:
                     self._obs_rec.record(
                         "role_change", node=self.name, group=g.name,
                         term=gterm_l[p],
-                        detail=f"{self._ROLE_NAMES.get(g.role, g.role)}->"
+                        detail=f"{self._ROLE_NAMES.get(old_role, old_role)}->"
                                f"{self._ROLE_NAMES.get(new_role, new_role)}",
                     )
                     # role transitions restart the leaderless-suspicion
                     # window (a just-deposed leader must give the new
                     # one a chance to make contact before suspecting)
                     g.last_contact = now_roles
-                if g.role == C.R_LEADER and new_role != C.R_LEADER:
+                if old_role == C.R_LEADER and new_role != C.R_LEADER:
                     # deposed: in-flight linearizable reads must not be
                     # answered from this replica's state, and pending
                     # command futures must redirect rather than hang
@@ -3051,8 +3142,9 @@ class BatchCoordinator:
                     g.pending_queries = []
                     g.leader_slot = leader_l[p]  # hint before the sweep
                     self._fail_pending(g)
-                entered = (new_role == C.R_LEADER) != (g.role == C.R_LEADER)
-                g.role = new_role
+                entered = (new_role == C.R_LEADER) != (old_role == C.R_LEADER)
+                if new_role != old_role:
+                    g.role = new_role  # (a store into the mirror too)
                 g.term = gterm_l[p]
                 g.leader_slot = leader_l[p]
                 if tvc_l[p] and self.meta is not None:
@@ -3247,7 +3339,7 @@ class BatchCoordinator:
         g.next_index = [li + 1] * len(g.members)
         g.commit_sent = [0] * len(g.members)
         g.match_hint = [0] * len(g.members)
-        g.last_ack = {}
+        g.last_ack[:] = 0.0
         g.leader_slot = g.self_slot
         leaderboard.record(g.cluster_name, (g.name, self.name), tuple(g.members))
         # the new term's noop (commit gate + version carrier)
@@ -3924,8 +4016,8 @@ class BatchCoordinator:
                     # to every caught-up peer).
                     mh = g.match_hint[s] if s < len(g.match_hint) else 0
                     if nxt - mh > self.max_pipeline_count:
-                        la = g.last_ack.get(s)
-                        if la is not None and now - la <= self.tick_interval_s:
+                        la = g.last_ack[s]
+                        if la and now - la <= self.tick_interval_s:
                             continue  # window full but acks are flowing
                         g.last_ack[s] = now  # one probe per tick per peer
                         self.counters.incr("stale_peer_resends")
@@ -4183,7 +4275,8 @@ class BatchCoordinator:
                         and s < len(g.commit_sent)
                     ):
                         g.commit_sent[s] = -1
-                        g.last_ack.setdefault(s, now)
+                        if not g.last_ack[s]:
+                            g.last_ack[s] = now
                 self._send_aers({g.gid})
             return
         if isinstance(msg, tuple) and msg and msg[0] == "lane_fail":
@@ -4200,7 +4293,8 @@ class BatchCoordinator:
                         # -1 sentinel: the probe must fire even at
                         # commit 0 (a fresh leader's lost noop AER)
                         g.commit_sent[s] = -1
-                        g.last_ack.setdefault(s, now)
+                        if not g.last_ack[s]:
+                            g.last_ack[s] = now
                 self._send_aers({g.gid})
             return
         if isinstance(msg, tuple) and msg and msg[0] == "machine_tick":
@@ -4266,7 +4360,8 @@ class BatchCoordinator:
             g.commit_sent = [0]
             g.match_hint = [0]
             g.voter_status = {0: "voter"}
-            g.last_ack = {}
+            g.last_ack[:] = 0.0
+            self._sync_peer_row(g)
             g.cluster_change_permitted = True
             onehot = np.zeros(self.P, dtype=bool)
             onehot[0] = True
@@ -4670,7 +4765,7 @@ class BatchCoordinator:
                 g.next_index = [meta.index + 1] * len(new)
                 g.commit_sent = [0] * len(new)
                 g.match_hint = [0] * len(new)
-                g.last_ack = {}
+                g.last_ack[:] = 0.0
                 self.state = self.state._replace(
                     self_slot=self.state.self_slot.at[g.gid].set(g.self_slot)
                 )
@@ -4763,7 +4858,12 @@ class BatchCoordinator:
         # (applied_seen, oldest_pending_idx, since, strikes)
         lane_watch: Dict[int, Tuple[int, int, float, int]] = {}
         self._detect_last_tick = self.clock.monotonic()
+        cnt = self.counters
         while self.running:
+            # this thread's CPU inside its passes: one clock pair a pass
+            # (ten to thirty a second; the clock ticks in 10 ms on some
+            # hosts, so only the sum over many passes means anything)
+            cpu0 = time.thread_time_ns()
             try:
                 with _obs.span("ra/detect/scan", node=self.name):
                     self._detect_pass(cooldown, armed, lane_watch)
@@ -4773,6 +4873,8 @@ class BatchCoordinator:
                     logger.exception(
                         "coordinator %s: detector pass failed", self.name
                     )
+            cnt.incr("detector_passes")
+            cnt.incr("detector_cpu_ns", time.thread_time_ns() - cpu0)
             time.sleep(self._detector_poll_s)
 
     def _detect_pass(self, cooldown, armed, lane_watch) -> None:
@@ -4805,39 +4907,53 @@ class BatchCoordinator:
             )
             self._health_scan(now0)
             ms = int(self.clock.time() * 1000)
-            for i in range(self.n_groups):
+            me = self.name
+            out = [
+                ((self.groups[i].name, me), ("machine_tick", ms), None)
+                for i in self._tick_gids
+            ]
+            # peers silent for two ticks may have missed AERs
+            # (drops/partitions advance next_index optimistically):
+            # probe them so their reject hints rewind replication (zero
+            # cost while acks flow). The led rows with such a peer, as a
+            # mask over the ack and member tables; the row's own member
+            # table decides which slots
+            n = self.n_groups
+            old = now0 - self._last_ack_np[:n] > 2 * self.tick_interval_s
+            rows = np.flatnonzero(
+                (old & self._peer_np[:n]).any(axis=1)
+                & (self._role_np[:n] == C.R_LEADER)
+            )
+            self.counters.incr(
+                "detector_rows_walked", len(out) + len(rows))
+            for i, old_i in zip(rows.tolist(), old[rows].tolist()):
                 g = self.groups[i]
-                if g is None:
+                if g is None or g.role != C.R_LEADER:
                     continue
-                if g.has_tick:
-                    self.deliver((g.name, self.name), ("machine_tick", ms), None)
-                if g.role == C.R_LEADER:
-                    # peers silent for two ticks may have missed
-                    # AERs (drops/partitions advance next_index
-                    # optimistically): probe them so their reject
-                    # hints rewind replication (zero cost while
-                    # acks flow)
-                    stale = [
-                        s for s, m in enumerate(g.members)
-                        if m is not None and s != g.self_slot
-                        and now0 - g.last_ack.get(s, 0.0)
-                        > 2 * self.tick_interval_s
-                    ]
-                    if stale:
-                        self.deliver(
-                            (g.name, self.name), ("resync", stale), None
-                        )
+                own = g.self_slot
+                stale = [
+                    s for s, m in enumerate(g.members)
+                    if m is not None and s != own and old_i[s]
+                ]
+                if stale:
+                    out.append(((g.name, me), ("resync", stale), None))
+            if out:
+                self.deliver_many(out)  # one ring slot a tick
         # a stopped node unregisters: include previously-seen
         # names so disappearance reads as death
         known = set(self.registry.names()) | set(self._node_status)
+        all_alive = True  # every node a leader could be on, this pass
         for other in known:
             if other == self.name:
                 continue
             alive = self.transport.node_alive(other)
+            all_alive &= alive
             prev = self._node_status.get(other)
             self._node_status[other] = alive
             if prev is True and not alive:
                 self._on_node_down(other)
+        for other in self._peer_nodes - known:
+            all_alive &= self.transport.node_alive(other)
         # suspicion sweep. Three leaderless shapes need retry —
         # without it a partition heal can wedge a group forever
         # (nobody re-elects once every node is "alive" again):
@@ -4865,16 +4981,38 @@ class BatchCoordinator:
         contact_window = max(
             5 * self.tick_interval_s, 6 * self.election_timeout_s
         )
-        for i in range(self.n_groups):
+        # The rows worth the walk, as a mask over the role and contact
+        # mirrors: a row's contact older than its role's shortest
+        # threshold, which every branch below implies (branch 2's
+        # dead-leader clause only while some node is not alive, so
+        # liveness is read once a pass for the mask and once a row only
+        # for its rows). A healthy fleet leaves no row. Kept to four
+        # numpy calls: under a busy interpreter lock each one over
+        # 10,240 rows hands the lock over and waits to get it back
+        # (PERF.md section 6, PR 31)
+        n = self.n_groups
+        et = self.election_timeout_s
+        follower = contact_window if all_alive else min(et, contact_window)
+        campaigning = min(2 * et, follower)
+        # by role value: follower, pre-vote, candidate, leader (never),
+        # and last, where GroupMirrors.FREE indexes, a free row (never)
+        limits = np.array([follower, campaigning, campaigning, np.inf, np.inf])
+        hits = now - self._contact_np[:n] > limits[self._role_np[:n]]
+        for i in [k for k in armed if not hits[k]]:
+            del armed[i]  # no longer suspicious
+        rows = np.flatnonzero(hits).tolist()
+        self.counters.incr("detector_rows_walked", len(rows))
+        for i in rows:
             g = self.groups[i]
             if g is None or g.role == C.R_LEADER:
                 continue
             if g.voter_status.get(g.self_slot) != "voter":
                 continue
             leader = g.sid_of(g.leader_slot)
+            last_contact = g.last_contact
             if g.role in (C.R_PRE_VOTE, C.R_CANDIDATE):
                 suspicious = (
-                    now - g.last_contact > 2 * self.election_timeout_s
+                    now - last_contact > 2 * self.election_timeout_s
                 )
             elif leader is not None and leader[1] != self.name:
                 # a dead leader node is suspicious only once it
@@ -4885,12 +5023,12 @@ class BatchCoordinator:
                 # takeover duel)
                 suspicious = (
                     not self.transport.node_alive(leader[1])
-                    and now - g.last_contact > self.election_timeout_s
-                ) or now - g.last_contact > contact_window
+                    and now - last_contact > self.election_timeout_s
+                ) or now - last_contact > contact_window
             else:
                 suspicious = (
                     g.term > 0
-                    and now - g.last_contact > contact_window
+                    and now - last_contact > contact_window
                 )
             if not suspicious:
                 armed.pop(i, None)
@@ -4931,12 +5069,28 @@ class BatchCoordinator:
             max(self.command_deadline_s, self._WEDGE_WAVES * self._wave_s),
             self._WEDGE_STRETCH_MAX * self.command_deadline_s)
         stall_max = 0.0
-        for i in range(self.n_groups):
+        # the rows that may hold client futures (``_pending_np``: set by
+        # the append that put one in); the rest have nothing to watch.
+        # A copy: numpy's nonzero raises when the step thread marks a
+        # row between its counting and its filling
+        live = self._pending_np
+        marked = live[: self.n_groups].copy()
+        for i in [k for k in lane_watch if not marked[k]]:
+            del lane_watch[i]
+        rows = np.flatnonzero(marked).tolist()
+        self.counters.incr("detector_rows_walked", len(rows))
+        for i in rows:
             g = self.groups[i]
             if g is None:
                 continue
             pending = g.pending_replies
             if not pending:
+                # emptied since (applied, or failed): unmark first, look
+                # again after, so that an append racing this keeps its
+                # mark whichever side stores last
+                live[i] = 0
+                if g.pending_replies:
+                    live[i] = 1
                 lane_watch.pop(i, None)
                 continue
             try:
@@ -4996,10 +5150,7 @@ class BatchCoordinator:
             # behind the async dispatch queue. Enqueue device-side
             # COPIES under the lock (dispatch only, microseconds; the
             # copies' buffers are fresh, never donated) ...
-            snap = tuple(jnp.copy(a) for a in (
-                st.current_term, st.commit_index, st.last_index, st.role,
-                st.leader_slot, st.self_slot, st.match_index, st.active,
-            ))
+            snap = _health_copies(st)
         # ... and pay the transfer/queue wait OUTSIDE it: one
         # device_get per scan (the health_fetches == health_scans
         # counter invariant) with the step loop free to run

@@ -411,6 +411,7 @@ def test_watchdog_deadline_follows_the_wave_up_to_two_deadlines(
         c.add_group("g", "cl", [("g", "wd_wave")], DictKv())
         g = c.by_name["g"]
         g.pending_replies[7] = api.Future()
+        c._pending_np[g.gid] = 1  # as the append that puts one in marks it
         c._wave_s = wave_s
         watch = {}
         c._lane_watchdog(watch, 0.0)  # first seen still
