@@ -280,6 +280,29 @@ COORDINATOR_FIELDS: List[FieldSpec] = [
     ("detector_cpu_ns", "counter",
      "thread CPU inside those passes, summed (thread_time_ns, one pair "
      "a pass; the clock ticks in 10 ms on some hosts, so read sums)"),
+    # -- the wire (runtime/tcp.py; a coordinator built with tcp=True):
+    # booked by the transport once a frame, never once a message
+    ("wire_frames_out", "counter",
+     "batch frames handed to a peer's outbox (one a destination a "
+     "_send_batch, more only past MAX_FRAME)"),
+    ("wire_msgs_out", "counter", "protocol messages inside those frames"),
+    ("wire_bytes_out", "counter",
+     "bytes of those frames, length prefix and MAC included"),
+    ("wire_frames_in", "counter",
+     "batch frames authenticated, decoded and handed to ingest_batch"),
+    ("wire_msgs_in", "counter", "protocol messages inside those frames"),
+    ("wire_bytes_in", "counter",
+     "bytes of those frames, length prefix and MAC included"),
+    ("wire_encode_ns", "counter",
+     "wall ns building a batch's list, encoding and sealing it (one "
+     "clock pair a send_batch)"),
+    ("wire_decode_ns", "counter",
+     "wall ns from a batch frame's MAC check through its restricted "
+     "decode to ingest_batch's return (one clock pair a frame)"),
+    ("wire_dropped", "counter",
+     "messages the wire lost: a full outbox, a blocked pair, a drop_fn, "
+     "a message no frame holds, a failed connect or write, or shed by "
+     "the receiving ingress (Raft resends what it needs)"),
     # -- machine effects on the batch backend (_realise_effects; booked
     # once a step that realised any)
     ("effects_send_msg", "counter",
@@ -326,11 +349,6 @@ COORDINATOR_FIELDS: List[FieldSpec] = [
      "native GIL-released scatter (rt_pack_mbox)"),
     ("native_pack_msgs", "counter",
      "mailbox messages encoded by the native pack scatter"),
-    ("native_egress_batches", "counter",
-     "per-destination egress batches sealed+framed in one native call "
-     "(rt_seal_frames) on the sender path"),
-    ("native_egress_frames", "counter",
-     "wire frames produced by the native egress sealer"),
     ("native_fallbacks", "counter",
      "hot-loop iterations that took the byte-identical Python path "
      "while a native path was switched on (armed failpoints, "

@@ -6,7 +6,7 @@ bindings:
 
 - ``wal_native``: WAL batch framing + write + fsync (PR 5);
 - ``rt_native``: the hot-loop runtime (docs/INTERNALS.md §18) — ring
-  drain classification, mailbox pack scatter, and egress frame sealing.
+  drain classification and mailbox pack scatter.
 
 Everything here has a pure-Python fallback. ``available()`` reports the
 WAL library (the historical contract); ``entry_points()`` reports every
@@ -176,18 +176,6 @@ def _load_rt():
             ctypes.c_long,    # nrows
             ctypes.c_long,    # width
         ]
-        lib.rt_seal_frames.restype = ctypes.c_long
-        lib.rt_seal_frames.argtypes = [
-            ctypes.c_char_p,  # blob
-            ctypes.c_void_p,  # offs u64*
-            ctypes.c_void_p,  # lens u32*
-            ctypes.c_long,    # n
-            ctypes.c_char_p,  # key
-            ctypes.c_long,    # keylen
-            ctypes.c_long,    # mac_len
-            ctypes.c_void_p,  # out
-            ctypes.c_long,    # out_cap
-        ]
         _rt_lib = lib
         return _rt_lib
 
@@ -210,7 +198,6 @@ def entry_points() -> Dict[str, bool]:
         "wal": wal,
         "pack": rt,
         "classify": rt,
-        "egress": rt,
     }
 
 
@@ -406,40 +393,3 @@ def pack_mbox(packed: np.ndarray, cols, vals, rows: np.ndarray) -> bool:
         packed.shape[1],
     )
     return rc == 0
-
-
-def seal_frames(payloads: List[bytes], key: bytes,
-                mac_len: int = 16) -> Optional[bytes]:
-    """Batch-seal egress wire frames: for each payload, the u32-LE
-    length prefix + truncated HMAC-SHA256(key, payload) MAC + payload,
-    concatenated — byte-identical to the Python per-frame path of
-    ``TcpTransport`` (_LEN.pack + _seal). One GIL-released call for
-    the whole per-destination batch. None when the native lib is
-    absent (caller falls back)."""
-    lib = _load_rt()
-    if lib is None:
-        return None
-    n = len(payloads)
-    if n == 0:
-        return b""
-    lens = np.fromiter((len(p) for p in payloads), np.uint32, n)
-    offs = np.empty(n, np.uint64)
-    offs[0] = 0
-    np.cumsum(lens[:-1], dtype=np.uint64, out=offs[1:])
-    blob = b"".join(payloads)
-    bound = int(lens.sum()) + n * (4 + mac_len)
-    out = ctypes.create_string_buffer(bound)
-    w = lib.rt_seal_frames(
-        blob,
-        offs.ctypes.data,
-        lens.ctypes.data,
-        n,
-        key,
-        len(key),
-        mac_len,
-        ctypes.cast(out, ctypes.c_void_p),
-        bound,
-    )
-    if w < 0:
-        return None
-    return out.raw[:w]

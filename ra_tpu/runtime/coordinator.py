@@ -112,14 +112,14 @@ MSG_OF_TYPE = {
     PreVoteResult: C.MSG_PREVOTE_REPLY,
 }
 
-_NATIVE_PATHS = frozenset(("pack", "classify", "egress"))
+_NATIVE_PATHS = frozenset(("pack", "classify"))
 
 
 def parse_native(spec) -> frozenset:
     """Parse a ``--native`` spec into the set of enabled native
-    hot-loop paths: ``"auto"``/``"on"``/``True`` enable all three,
+    hot-loop paths: ``"auto"``/``"on"``/``True`` enable both,
     ``"off"``/``"none"``/``False`` none, anything else a comma list
-    over {pack, classify, egress} (docs/INTERNALS.md §18)."""
+    over {pack, classify} (docs/INTERNALS.md §18)."""
     if spec is True or spec in ("auto", "on", "all"):
         return _NATIVE_PATHS
     if not spec or spec in ("off", "none"):
@@ -423,6 +423,7 @@ class BatchCoordinator:
         lease: bool = False,
         lease_safety_factor: float = 0.8,
         lease_drift_epsilon_s: float = 0.002,
+        tcp: bool = False,
     ):
         from ra_tpu.runtime.clock import WALL
 
@@ -433,6 +434,12 @@ class BatchCoordinator:
         # simulation plane never drives this backend)
         self.clock = clock or WALL
         self.name = node_name
+        if tcp:
+            # first of all: a port that is taken raises here, before
+            # anything of this node is registered anywhere
+            from ra_tpu.runtime.tcp import TcpTransport
+
+            self.transport = TcpTransport(node_name, self.deliver)
         self.capacity = capacity
         self.P = num_peers
         self.aer_batch_size = aer_batch_size
@@ -597,7 +604,6 @@ class BatchCoordinator:
         self.native = native
         self._nat_pack = "pack" in paths and eps.get("pack", False)
         self._nat_classify = "classify" in paths and eps.get("classify", False)
-        self._nat_egress = "egress" in paths and eps.get("egress", False)
         self._drain_codes = bytearray()  # classify sidecar scratch
         self._low_dirty: set = set()  # gids with buffered low-priority cmds
         # staged device scatters, coalesced ACROSS passes (the host half
@@ -688,7 +694,26 @@ class BatchCoordinator:
         self._wave_s = 0.0
 
         self.registry = nodes or node_registry()
-        self.transport = InProcTransport(node_name, self.registry)
+        if tcp:
+            # real sockets, as RaNode's ``tcp``: the name is
+            # "host:port", peers are other processes (or coordinators
+            # with a registry of their own) and are NOT found in
+            # ``self.registry``; a batch frame's list comes in through
+            # ``ingest_batch``, one ring slot a frame
+            from ra_tpu.detector import PhiAccrualDetector
+
+            self.transport.deliver_batch = self.ingest_batch
+            self.transport.counters = self.counters
+            # (a pong that is a detector tick late is no evidence of
+            # death: the threads that carry it wait for the interpreter
+            # lock behind the waves'; a dead process shows at once, as a
+            # failed write)
+            self.transport.detector = PhiAccrualDetector(
+                owner=node_name, min_std=max(0.1, tick_interval_s / 2))
+            self.transport.on_proc_down_cb = self.process_down
+        else:
+            self.transport = InProcTransport(node_name, self.registry)
+        self._wired = tcp
         self.running = True
         self.registry.register(node_name, self)
         self.steps = 0
@@ -1133,6 +1158,9 @@ class BatchCoordinator:
                     t.cancel()
                 g.machine_timers.clear()
         self.registry.unregister(self.name)
+        if self._wired:
+            self.transport.close()  # sockets, and the threads on them
+            self.transport.detector.close()
 
     def add_group(
         self,
@@ -1481,7 +1509,18 @@ class BatchCoordinator:
             if tr:
                 sp = _obs.begin("ra/send/batch", node=self.name,
                                 msgs=sum(len(msgs) for _n, msgs in out))
-            for node_name, msgs in out:
+            batches = out
+            if self._wired and n > 1:
+                # across a wire a batch is a frame, and a frame costs
+                # the writer and the peer's reader a turn each at the
+                # interpreter lock: what queued for one destination
+                # while this thread waited its own turn leaves as one
+                # (the slower the turns, the larger the frames)
+                by_node: Dict[str, List] = {}
+                for node_name, msgs in out:
+                    by_node.setdefault(node_name, []).extend(msgs)
+                batches = by_node.items()
+            for node_name, msgs in batches:
                 try:
                     self._send_batch_inline(node_name, msgs)
                 except Exception:  # noqa: BLE001
@@ -2521,14 +2560,27 @@ class BatchCoordinator:
                 if cmd.kind in (RA_JOIN, RA_LEAVE, RA_CLUSTER_CHANGE):
                     if not self._prepare_cluster_cmd(g, cmd):
                         continue
-                log.append(Entry(idx, term, cmd))
+                ref = cmd.from_ref
+                if ref is not None or cmd.ts is not None:
+                    # the log keeps neither the reply handle (the
+                    # pending-reply table does, until the reply is out)
+                    # nor the submit stamp (read below, from ``cmds``):
+                    # an entry lives until its log is cut, and a handle
+                    # held that long is a caller's closure the collector
+                    # walks at every young collection (PERF.md section
+                    # 6, PR 33). What replication and the WAL would
+                    # strip anyway is stripped once, here
+                    log.append(Entry(
+                        idx, term, cmd._replace(from_ref=None, ts=None)))
+                else:
+                    log.append(Entry(idx, term, cmd))
                 if cmd.kind != USR:
                     g.specials.append(idx)
-                if cmd.from_ref is not None:
+                if ref is not None:
                     if cmd.reply_mode == "after_log_append":
-                        self._reply(cmd.from_ref, ("ok", (idx, term), me))
+                        self._reply(ref, ("ok", (idx, term), me))
                     elif cmd.reply_mode == "await_consensus":
-                        pending[idx] = cmd.from_ref
+                        pending[idx] = ref
                 idx += 1
             if pending:
                 # the lane watchdog's mask: set here, after the futures
@@ -3774,8 +3826,6 @@ class BatchCoordinator:
 
     def _send_batch_inline(self, node_name: str, msgs) -> None:
         node = self.registry.get(node_name)
-        if node is None:
-            return
         if isinstance(node, BatchCoordinator) and node is not self:
             # one hop for the whole batch; honor the same fault-injection
             # and liveness rules as InProcTransport.send
@@ -3798,20 +3848,14 @@ class BatchCoordinator:
                 # must-deliver remainder — never a batch-level drop
                 self.transport.dropped += node.ingest_batch(triples)
             return
-        if self._nat_egress and len(msgs) > 1:
-            # remote batch: seal + length-frame every AER/ack frame for
-            # this destination in ONE GIL-released native call on the
-            # sender path (rt_seal_frames). -1 = native unavailable or
-            # tcp failpoints armed: fall through to per-message send so
+        if node is None:
+            if not self._wired:
+                return
+            # a node across the wire: the batch as ONE frame. -1 = a tcp
+            # failpoint is armed: fall through to per-message send so
             # fire/mangle semantics apply frame by frame.
-            sb = getattr(self.transport, "send_batch", None)
-            if sb is not None:
-                sent = sb(node_name, msgs)
-                if sent >= 0:
-                    self.counters.incr("native_egress_batches")
-                    self.counters.incr("native_egress_frames", sent)
-                    return
-                self.counters.incr("native_fallbacks")
+            if self.transport.send_batch(node_name, msgs) >= 0:
+                return
         for to, msg, frm in msgs:
             self.transport.send(to, msg, from_sid=frm)
 
@@ -4940,8 +4984,10 @@ class BatchCoordinator:
             if out:
                 self.deliver_many(out)  # one ring slot a tick
         # a stopped node unregisters: include previously-seen
-        # names so disappearance reads as death
-        known = set(self.registry.names()) | set(self._node_status)
+        # names so disappearance reads as death; and the nodes members
+        # live on, which across a wire are in no registry of this process
+        known = (set(self.registry.names()) | set(self._node_status)
+                 | self._peer_nodes)
         all_alive = True  # every node a leader could be on, this pass
         for other in known:
             if other == self.name:
@@ -4952,8 +4998,6 @@ class BatchCoordinator:
             self._node_status[other] = alive
             if prev is True and not alive:
                 self._on_node_down(other)
-        for other in self._peer_nodes - known:
-            all_alive &= self.transport.node_alive(other)
         # suspicion sweep. Three leaderless shapes need retry —
         # without it a partition heal can wedge a group forever
         # (nobody re-elects once every node is "alive" again):

@@ -17,6 +17,12 @@ coordinator of the process is started:
   coordinator keeps alive, so a wave's allocations and releases cancel
   before a collection starts, and a full collection of the unfrozen
   rest is rare. The collector stays on: a cycle is still collected;
+- what survives a young collection on a serving node is mostly its
+  logs' entries, which live until their log is cut: the middle
+  generation is collected with every second young one, not with every
+  eleventh, so no stop is longer than two young generations' walk (at
+  ten it was one stop of half a second in a window of forty: PERF.md
+  section 6, PR 33). A full collection comes as rarely as it did;
 - one ``gc.callbacks`` hook books every pause on ONE started
   coordinator's counters (a pause is the process's; observers add the
   coordinators up) and, under a profiler session, as ``ra/gc/pause``.
@@ -35,7 +41,8 @@ from ra_tpu import obs as _obs
 # containers a group replica keeps alive across a full-width wave (its
 # entry, command, triple, AERs and replies in flight; PERF.md, PR 27)
 _YOUNG_PER_REPLICA = 8
-_FULL_EVERY = 100  # middle-generation collections to one full one
+_MIDDLE_AFTER = 0  # young collections between two of the middle generation
+_FULL_EVERY = 500  # middle-generation collections to one full one
 
 _lock = threading.RLock()  # a finaliser run by enter()'s collection may stop()
 _serving: list = []  # [(counters, node name, capacity * num_peers)]
@@ -69,7 +76,7 @@ def _on_gc(phase: str, info: dict) -> None:
 
 def _size_generations() -> None:
     young = _YOUNG_PER_REPLICA * max(size for _c, _n, size in _serving)
-    gc.set_threshold(max(_found[0], young), _found[1],
+    gc.set_threshold(max(_found[0], young), min(_found[1], _MIDDLE_AFTER),
                      max(_found[2], _FULL_EVERY))
 
 

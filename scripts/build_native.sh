@@ -4,7 +4,7 @@
 #
 #   ra_tpu/native/wal_native.<digest>.so  - WAL batch frame + write + fsync
 #   ra_tpu/native/rt_native.<digest>.so   - hot-loop runtime: drain-classify,
-#                                           mailbox pack scatter, egress seal
+#                                           mailbox pack scatter
 #
 # The Python loader builds these on first use, named by the digest of
 # their source, and this script builds through it; CI/tier-1 runs

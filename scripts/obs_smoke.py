@@ -288,11 +288,12 @@ def main() -> int:
             r"# TYPE ra_native_batches counter",
             # native hot-loop runtime (docs/INTERNALS.md §18): family
             # presence always; with rt_native loaded the live started
-            # cluster's traffic must have engaged classify and pack
-            # (egress stays 0 in-proc — the TCP seam is not wired here)
+            # cluster's traffic must have engaged classify and pack;
+            # the wire's counters are a family of every coordinator (0
+            # in-proc)
             r"# TYPE ra_native_classify_batches counter",
             r"# TYPE ra_native_pack_batches counter",
-            r"# TYPE ra_native_egress_batches counter",
+            r"# TYPE ra_wire_frames_out counter",
             r"# TYPE ra_native_fallbacks counter",
         ] + ([
             r"ra_native_classify_batches\{[^}]*obs0[^}]*\} (\d+)",

@@ -42,6 +42,9 @@ ROLES = (
     (re.compile(r"^ra-batch-eg-"), "egress"),
     (re.compile(r"^ra-batch-snd-"), "sender"),
     (re.compile(r"^ra-batch-"), "step"),
+    (re.compile(r"^ra-tcp-out-"), "wire writer"),
+    (re.compile(r"^ra-tcp-in-"), "wire reader"),
+    (re.compile(r"^ra-tcp-(ping|accept)-"), "wire liveness"),
     (re.compile(r"^ra-wal"), "wal writer"),
     (re.compile(r"^ra-segment-writer"), "segment writer"),
     (re.compile(r"^(fifo-gen|bench-gen|ycsb)-"), "generator"),

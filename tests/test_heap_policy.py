@@ -21,6 +21,9 @@ from ra_tpu.runtime import heap
 from ra_tpu.runtime.coordinator import BatchCoordinator
 
 FOUND = (901, 11, 12)  # what the "embedding application" had set
+# while a node serves: the middle generation goes with every second
+# young collection, a full one comes once in heap._FULL_EVERY of those
+MIDDLE, FULL = min(FOUND[1], heap._MIDDLE_AFTER), heap._FULL_EVERY
 GC_COUNTERS = ("gc_collections", "gc_full_collections", "gc_pause_ns")
 
 
@@ -67,7 +70,7 @@ def test_installed_at_the_first_start_not_at_construction(collector_as_found):
     c.start()
     threshold, frozen, hooks = _policy()
     # generation 0 by the one rule: 8 containers a group replica
-    assert threshold == (8 * 64 * 3, FOUND[1], 100)
+    assert threshold == (8 * 64 * 3, MIDDLE, FULL)
     assert frozen > 0 and hooks == 1
     c.stop()
     assert _policy() == (FOUND, 0, 0)
@@ -82,10 +85,10 @@ def test_one_install_for_three_sized_to_the_largest(collector_as_found):
         frozen.append(gc.get_freeze_count())
         assert gc.callbacks.count(heap._on_gc) == 1
     assert min(frozen) > 0
-    assert gc.get_threshold() == (8 * 256 * 3, FOUND[1], 100)
+    assert gc.get_threshold() == (8 * 256 * 3, MIDDLE, FULL)
     # the largest leaves: the rule is read again from those that serve
     coords[1].stop()
-    assert gc.get_threshold() == (8 * 128 * 3, FOUND[1], 100)
+    assert gc.get_threshold() == (8 * 128 * 3, MIDDLE, FULL)
     assert gc.get_freeze_count() > 0
     coords[0].stop()
     assert gc.callbacks.count(heap._on_gc) == 1
@@ -95,11 +98,11 @@ def test_one_install_for_three_sized_to_the_largest(collector_as_found):
 
 def test_a_small_coordinator_never_lowers_what_was_found(collector_as_found):
     c = _coord(collector_as_found, "hp_c0", capacity=8)  # 8 * 8 * 3 < 901
-    gc.set_threshold(FOUND[0], FOUND[1], 500)
+    gc.set_threshold(FOUND[0], FOUND[1], 2 * FULL)
     c.start()
-    assert gc.get_threshold() == (FOUND[0], FOUND[1], 500)
+    assert gc.get_threshold() == (FOUND[0], MIDDLE, 2 * FULL)
     c.stop()
-    assert gc.get_threshold() == (FOUND[0], FOUND[1], 500)
+    assert gc.get_threshold() == (FOUND[0], FOUND[1], 2 * FULL)
     gc.set_threshold(*FOUND)
 
 
@@ -108,7 +111,7 @@ def test_built_and_never_started_changes_nothing(collector_as_found):
     serving = _coord(collector_as_found, "hp_d1")
     serving.start()
     threshold, frozen, hooks = _policy()
-    assert threshold == (8 * 64 * 3, FOUND[1], 100)  # not idle's 512
+    assert threshold == (8 * 64 * 3, MIDDLE, FULL)  # not idle's 512
     assert frozen > 0 and hooks == 1
     idle.stop()  # stop() without start(): the policy stays in
     # (frozen, not the same count: the permanent generation holds what
@@ -224,6 +227,79 @@ def test_groups_serve_under_the_policy_and_after_it(collector_as_found):
     assert total == 20
     assert _gc_counters(coords[0])[0] >= 20
     assert _gc_counters(coords[1])[0] == 0
+
+
+def test_the_log_keeps_no_reply_handle_once_it_is_in(collector_as_found):
+    """A caller's handle is held until its reply is out, by the
+    pending-reply table; the entry, which lives until its log is cut,
+    holds neither the handle nor the submit stamp, so a handle that is a
+    closure dies by reference count with its reply (PR 33)."""
+    from ra_tpu.protocol import USR, Command
+
+    names = ["hp_k0", "hp_k1", "hp_k2"]
+    coords = [BatchCoordinator(n, capacity=8, num_peers=3,
+                               election_timeout_s=0.15, detector_poll_s=0.05,
+                               tick_interval_s=0.2) for n in names]
+    collector_as_found.extend(coords)
+    ids = [("hk", n) for n in names]
+    for c in coords:
+        c.add_group("hk", "hp_cluster_k", ids,
+                    SimpleMachine(lambda cmd, s: s + cmd, 0))
+        c.start()
+    coords[0].deliver(ids[0], ElectionTimeout(), None)
+    deadline = time.monotonic() + 30
+    while coords[0].by_name["hk"].role != C.R_LEADER:
+        assert time.monotonic() < deadline
+        time.sleep(0.02)
+
+    class Handle:
+        def __init__(self):
+            self.got = threading.Event()
+
+        def __call__(self, reply):
+            self.reply = reply
+            self.got.set()
+
+    handles = [Handle() for _ in range(5)]
+    gone = [weakref.ref(h) for h in handles]
+    coords[0].deliver_many([
+        (ids[0], Command(kind=USR, data=1, reply_mode="await_consensus",
+                         from_ref=h, ts=time.monotonic_ns()), None)
+        for h in handles])
+    for h in handles:
+        assert h.got.wait(10)
+    assert sorted(h.reply[1] for h in handles) == [1, 2, 3, 4, 5]
+    g = coords[0].by_name["hk"]
+    assert not g.pending_replies
+    last = g.log.last_index_term()[0]
+    held = [g.log.fetch(i) for i in range(last - 4, last + 1)]
+    assert [e.cmd.data for e in held] == [1] * 5
+    assert all(e.cmd.from_ref is None and e.cmd.ts is None for e in held)
+    del handles, h
+    assert all(ref() is None for ref in gone)
+
+
+def test_no_stop_walks_more_than_two_young_generations(collector_as_found):
+    """What outlives a young collection on a serving node is mostly log
+    entries: the middle generation goes with every second young
+    collection (generation 0, 1, 0, 1, ...), never ten young
+    generations' worth at once."""
+    c = _coord(collector_as_found, "hp_l0")
+    c.start()
+    seen = []
+
+    def watch(phase, info):
+        if phase == "start":
+            seen.append(info["generation"])
+
+    gc.callbacks.append(watch)
+    try:
+        keep = [[] for _ in range(6 * gc.get_threshold()[0])]
+    finally:
+        gc.callbacks.remove(watch)
+    del keep
+    assert len(seen) >= 5 and 2 not in seen
+    assert all(a != b for a, b in zip(seen, seen[1:])), seen
 
 
 def test_a_pause_is_a_span_under_a_profiler_session(collector_as_found,

@@ -1,5 +1,5 @@
 """Byte-parity fuzz tests for the native hot-loop runtime (docs/
-INTERNALS.md §18): rt_classify / rt_pack_mbox / rt_seal_frames against
+INTERNALS.md §18): rt_classify / rt_pack_mbox against
 their Python reference paths, plus the fallback seams — .so missing,
 armed failpoints, and the loader's negative build cache.
 
@@ -10,14 +10,10 @@ reference, and the coordinator's native/off variants checked against
 each other on identical seeded corpora).
 """
 
-import hashlib
-import hmac
 import os
 import random
 import shutil
-import struct
 import subprocess
-import time
 from collections import Counter
 
 import numpy as np
@@ -45,16 +41,6 @@ needs_rt = pytest.mark.skipif(
 )
 
 
-def free_port():
-    import socket
-
-    s = socket.socket()
-    s.bind(("127.0.0.1", 0))
-    p = s.getsockname()[1]
-    s.close()
-    return p
-
-
 # -- build guard (satellite: scripts/build_native.sh contract) -------------
 
 
@@ -66,14 +52,13 @@ def test_native_builds_when_compiler_present():
     if shutil.which("g++") is None:
         pytest.skip("no g++ on PATH")
     eps = native.entry_points()
-    assert eps == {"wal": True, "pack": True, "classify": True,
-                   "egress": True}
+    assert eps == {"wal": True, "pack": True, "classify": True}
     # available() stays the WAL-only historical contract
     assert native.available() == eps["wal"]
 
 
 def test_parse_native_specs():
-    allp = frozenset(("pack", "classify", "egress"))
+    allp = frozenset(("pack", "classify"))
     assert parse_native("auto") == allp
     assert parse_native(True) == allp
     assert parse_native("on") == allp
@@ -82,10 +67,12 @@ def test_parse_native_specs():
     assert parse_native("none") == frozenset()
     assert parse_native(False) == frozenset()
     assert parse_native("") == frozenset()
-    assert parse_native("pack,egress") == frozenset(("pack", "egress"))
+    assert parse_native("pack,classify") == allp
     assert parse_native(" classify ") == frozenset(("classify",))
     with pytest.raises(ValueError):
         parse_native("pack,warp")
+    with pytest.raises(ValueError):  # the per-message sealer went (PR 33)
+        parse_native("pack,egress")
 
 
 # -- rt_classify vs Python reference ---------------------------------------
@@ -401,87 +388,6 @@ def test_pack_hot_armed_failpoint_falls_back():
         c.stop()
 
 
-# -- egress frame sealing parity -------------------------------------------
-
-
-def _seal_ref(payloads, key, mac_len):
-    out = []
-    for p in payloads:
-        mac = hmac.new(key, p, hashlib.sha256).digest()[:mac_len]
-        out.append(struct.pack("<I", len(mac) + len(p)) + mac + p)
-    return b"".join(out)
-
-
-@needs_rt
-def test_seal_frames_parity_fuzz():
-    """Native egress sealing must be byte-identical to the per-frame
-    Python path (_LEN.pack + truncated HMAC-SHA256) — including empty
-    payloads, long keys (> SHA-256 block size), and odd MAC lengths."""
-    rng = random.Random(0x5EA1)
-    for trial in range(40):
-        n = rng.randint(1, 32)
-        payloads = [
-            bytes(rng.randrange(256) for _ in range(rng.randint(0, 512)))
-            for _ in range(n)
-        ]
-        key = bytes(rng.randrange(256)
-                    for _ in range(rng.choice([0, 7, 16, 64, 65, 200])))
-        mac_len = rng.choice([4, 16, 32])
-        blob = native.seal_frames(payloads, key, mac_len)
-        assert blob == _seal_ref(payloads, key, mac_len), f"trial {trial}"
-    assert native.seal_frames([], b"k") == b""
-
-
-@needs_rt
-def test_send_batch_wire_parity():
-    """A send_batch blob decodes on a live receiver exactly like the
-    equivalent per-message sends: same messages, same order."""
-    from ra_tpu.runtime.tcp import TcpTransport
-
-    got = []
-    a_port, b_port = free_port(), free_port()
-    a = TcpTransport(f"127.0.0.1:{a_port}", lambda t, m, f: True)
-    b = TcpTransport(
-        f"127.0.0.1:{b_port}", lambda t, m, f: got.append((t, m, f)) or True
-    )
-    try:
-        b_name = f"127.0.0.1:{b_port}"
-        msgs = [
-            (("p0", b_name), ("hb", 1), ("q0", a.node_name)),
-            (("p1", b_name), Command(USR, ("put", "k", 2)), None),
-            (("p2", b_name), ("hb", 3), ("q2", a.node_name)),
-        ]
-        sent = a.send_batch(b_name, msgs)
-        assert sent == 3
-        deadline = time.monotonic() + 10
-        while time.monotonic() < deadline and len(got) < 3:
-            time.sleep(0.02)
-        assert [(t[0], m) for t, m, _ in got] == [
-            ("p0", ("hb", 1)),
-            ("p1", Command(USR, ("put", "k", 2))),
-            ("p2", ("hb", 3)),
-        ]
-        assert got[0][2] == ("q0", a.node_name) and got[1][2] is None
-    finally:
-        a.close()
-        b.close()
-
-
-def test_send_batch_armed_failpoint_declines():
-    """With a tcp failpoint armed send_batch must decline (-1) so the
-    caller's per-message sends keep fire/mangle semantics per frame.
-    Holds with or without the native lib (without, it always declines)."""
-    from ra_tpu.runtime.tcp import TcpTransport
-
-    a = TcpTransport(f"127.0.0.1:{free_port()}", lambda t, m, f: True)
-    try:
-        faults.arm("tcp.frame", ("torn", 0.5), ("always",))
-        assert a.send_batch("127.0.0.1:1", [(("p", "n"), ("m",), None)]) == -1
-    finally:
-        faults.disarm_all()
-        a.close()
-
-
 # -- .so-missing fallbacks -------------------------------------------------
 
 
@@ -496,12 +402,11 @@ def test_rt_lib_missing_helpers_and_coordinator(monkeypatch):
         np.zeros((2, 2), np.int32), [0], [1, 2],
         np.asarray([0, 1], np.int32),
     ) is False
-    assert native.seal_frames([b"x"], b"k") is None
     eps = native.entry_points()
-    assert not eps["pack"] and not eps["classify"] and not eps["egress"]
+    assert not eps["pack"] and not eps["classify"]
     c = _mk_coord("nmh0", "auto")
     try:
-        assert not (c._nat_pack or c._nat_classify or c._nat_egress)
+        assert not (c._nat_pack or c._nat_classify)
         _add_groups(c, "nmh0")
         _apply_ops(c, [("cmd", "g0", i, "normal") for i in range(5)])
         pre = c._drain_classify()

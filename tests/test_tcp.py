@@ -422,3 +422,290 @@ class _WirePayload:
 
     def __init__(self, v):
         self.v = v
+
+
+# -- the batch frame (docs/INTERNALS.md section 18; ISSUE 33) ----------------
+
+
+class _Sink:
+    """What a transport's owner gives it for its ``wire_*`` counters."""
+
+    def __init__(self):
+        self.v = {}
+
+    def incr(self, field, n=1):
+        self.v[field] = self.v.get(field, 0) + n
+
+
+def _pair(batch_cb=True, **kw):
+    """Two transports; ``got`` is what ``b`` delivered, as (name, msg,
+    from_sid), and ``calls`` how many deliveries that took."""
+    from ra_tpu.runtime.tcp import TcpTransport
+
+    got, calls = [], []
+
+    def deliver(to, msg, frm):
+        got.append((to[0], msg, frm))
+        calls.append(1)
+        return True
+
+    def deliver_batch(triples):
+        got.extend((n, m, f) for n, f, m in triples)
+        calls.append(len(triples))
+        return 0
+
+    def bound(deliver, **kw):
+        for _ in range(8):  # a port taken between look and bind: again
+            try:
+                return TcpTransport(f"127.0.0.1:{free_port()}", deliver, **kw)
+            except OSError:
+                continue
+        raise AssertionError("no free port")
+
+    a = bound(lambda t, m, f: True, **kw)
+    b = bound(deliver)
+    if batch_cb:
+        b.deliver_batch = deliver_batch
+    a.counters, b.counters = _Sink(), _Sink()
+    return a, b, got, calls
+
+
+def _await(cond, timeout=10.0):
+    deadline = time.monotonic() + timeout
+    while time.monotonic() < deadline and not cond():
+        time.sleep(0.01)
+    return cond()
+
+
+def _protocol_batch(a, b):
+    from ra_tpu.protocol import (AppendEntriesReply, AppendEntriesRpc, Command,
+                                 Entry, HeartbeatRpc, USR)
+
+    body = bytes(range(256)) * 4  # a 1 KB body, as the fifo cell's
+    aer = AppendEntriesRpc(
+        term=3, leader_id=("q0", a.node_name), prev_log_index=7,
+        prev_log_term=3, leader_commit=7,
+        entries=(Entry(8, 3, Command(USR, ("enqueue", body),
+                                     from_ref=lambda r: None, ts=123)),
+                 Entry(9, 3, Command(USR, ("settle", ("consumer", 4), [8])))))
+    ack = AppendEntriesReply(term=3, success=True, next_index=10,
+                             last_index=9, last_term=3)
+    hb = HeartbeatRpc(term=3, leader_id=("q2", a.node_name), query_index=5)
+    return [
+        (("q0", b.node_name), aer, ("q0", a.node_name)),
+        (("q1", b.node_name), ack, ("q1", a.node_name)),
+        (("q2", b.node_name), hb, None),  # from_sid of None
+        (("q3", b.node_name), ("resync", [1, 2]), ("q3", a.node_name)),
+    ]
+
+
+@pytest.mark.parametrize("batch_cb", [True, False],
+                         ids=["ingest_batch", "per_message_deliver"])
+def test_batch_frame_round_trips_in_order(batch_cb):
+    """One ``send_batch`` is one frame, one MAC, one outbox element; the
+    receiver hands the decoded list to the batch callback in one call
+    (a coordinator's ``ingest_batch``) or, for an owner without one (a
+    ``RaNode``), feeds ``deliver`` from the same list, in order."""
+    a, b, got, calls = _pair(batch_cb)
+    try:
+        msgs = _protocol_batch(a, b)
+        assert a.send_batch(b.node_name, msgs) == 4
+        assert _await(lambda: len(got) == 4)
+        assert [n for n, _m, _f in got] == ["q0", "q1", "q2", "q3"]
+        assert [f for _n, _m, f in got] == [
+            ("q0", a.node_name), ("q1", a.node_name), None,
+            ("q3", a.node_name)]
+        aer = got[0][1]
+        # reply handles and submit stamps do not cross (sanitize_for_wire)
+        assert aer.entries[0].cmd.from_ref is None
+        assert aer.entries[0].cmd.ts is None
+        assert aer.entries[0].cmd.data == ("enqueue", bytes(range(256)) * 4)
+        assert aer.entries[1].cmd.data == ("settle", ("consumer", 4), [8])
+        assert got[1][1] == msgs[1][1] and got[2][1] == msgs[2][1]
+        assert got[3][1] == ("resync", [1, 2])
+        assert calls == ([4] if batch_cb else [1, 1, 1, 1])
+        assert _await(lambda: b.counters.v.get("wire_frames_in") == 1)
+        out, inn = a.counters.v, b.counters.v
+        assert (out["wire_frames_out"], out["wire_msgs_out"]) == (1, 4)
+        assert (inn["wire_frames_in"], inn["wire_msgs_in"]) == (1, 4)
+        assert out["wire_bytes_out"] == inn["wire_bytes_in"] > 1024
+        assert out["wire_encode_ns"] > 0 and inn["wire_decode_ns"] > 0
+        assert inn.get("wire_dropped", 0) == 0 and a.dropped == b.dropped == 0
+        # any authenticated frame is evidence of life
+        assert a.node_name in b._last_heard
+    finally:
+        a.close()
+        b.close()
+    assert a.threads() == [] and b.threads() == []
+
+
+def test_what_the_batch_callback_sheds_is_counted_as_dropped():
+    a, b, got, _calls = _pair()
+    b.deliver_batch = lambda triples: 3  # a full ingress lane sheds three
+    try:
+        assert a.send_batch(b.node_name, _protocol_batch(a, b)) == 4
+        assert _await(lambda: b.dropped == 3)
+        assert b.counters.v["wire_dropped"] == 3
+        assert b.counters.v["wire_msgs_in"] == 4
+    finally:
+        a.close()
+        b.close()
+
+
+def _raw_batch_frame(a, b):
+    from ra_tpu.protocol import sanitize_for_wire
+
+    (wire, n), = a._batch_frames(
+        [(to[0], frm, sanitize_for_wire(msg))
+         for to, msg, frm in _protocol_batch(a, b)])
+    assert n == 4
+    return wire
+
+
+@pytest.mark.parametrize("where", ["mac", "head", "body", "last_byte"])
+def test_flipped_bit_in_a_batch_frame_delivers_none_of_it(where):
+    """The MAC is checked before anything is decoded: a frame with one
+    bit wrong closes the connection and not one of its messages is
+    delivered."""
+    from ra_tpu.runtime.tcp import _LEN, _MAC_LEN
+
+    a, b, got, _calls = _pair()
+    try:
+        wire = bytearray(_raw_batch_frame(a, b))
+        at = {"mac": _LEN.size + 3, "head": _LEN.size + _MAC_LEN + 5,
+              "body": len(wire) // 2, "last_byte": len(wire) - 1}[where]
+        wire[at] ^= 0x10
+        host, port = b.node_name.rsplit(":", 1)
+        s = socket.create_connection((host, int(port)), timeout=5)
+        s.sendall(bytes(wire))
+        s.settimeout(5)
+        assert s.recv(1) == b""  # closed by the receiver
+        s.close()
+        assert got == [] and b.counters.v.get("wire_frames_in", 0) == 0
+        # the untouched frame on a new connection is delivered whole
+        s = socket.create_connection((host, int(port)), timeout=5)
+        s.sendall(_raw_batch_frame(a, b))
+        assert _await(lambda: len(got) == 4)
+        s.close()
+    finally:
+        a.close()
+        b.close()
+
+
+def test_unregistered_type_in_a_batch_closes_the_connection_and_is_logged(
+        caplog):
+    a, b, got, _calls = _pair()
+    try:
+        msgs = _protocol_batch(a, b)
+        msgs[2] = (msgs[2][0], _WirePayload(7), None)  # not allowlisted
+        with caplog.at_level("ERROR", logger="ra_tpu"):
+            assert a.send_batch(b.node_name, msgs) == 4
+            assert _await(lambda: any(
+                "register_wire_type" in r.getMessage() for r in caplog.records))
+        assert got == []  # none of the frame's messages
+        # the sender reconnects lazily; a clean batch then arrives
+        assert _await(lambda: a.send_batch(b.node_name, _protocol_batch(a, b))
+                      == 4 and _await(lambda: len(got) >= 4, 1.0))
+    finally:
+        a.close()
+        b.close()
+
+
+def test_a_batch_over_max_frame_is_split_and_arrives_whole(monkeypatch):
+    from ra_tpu.runtime import tcp as tcpmod
+
+    monkeypatch.setattr(tcpmod, "MAX_FRAME", 8192)
+    a, b, got, calls = _pair()
+    try:
+        msgs = [((f"q{i}", b.node_name), ("blob", i, bytes(500)), None)
+                for i in range(64)]
+        msgs[10] = (("q10", b.node_name), ("blob", 10, bytes(9000)), None)
+        assert a.send_batch(b.node_name, msgs) == 63  # one fits no frame
+        assert _await(lambda: len(got) == 63)
+        assert [m[1] for _n, m, _f in got] == [i for i in range(64) if i != 10]
+        assert len(calls) == a.counters.v["wire_frames_out"] > 4
+        assert a.counters.v["wire_msgs_out"] == 63
+        assert a.dropped == 1 and a.counters.v["wire_dropped"] == 1
+    finally:
+        a.close()
+        b.close()
+
+
+def test_send_batch_declines_while_a_tcp_failpoint_is_armed():
+    """With ``tcp.send`` / ``tcp.frame`` armed the per-message path runs,
+    so fire and mangle keep their meaning frame by frame: the transport
+    declines (-1) and a wired coordinator falls back to ``send``."""
+    from ra_tpu import faults
+    from ra_tpu.runtime.coordinator import BatchCoordinator
+    from ra_tpu.runtime.transport import NodeRegistry
+
+    a, b, got, calls = _pair()
+    c = BatchCoordinator(f"127.0.0.1:{free_port()}", capacity=8,
+                         nodes=NodeRegistry(), tcp=True)
+    assert c.transport.threads()  # bound first of all, before any registry
+    try:
+        msgs = _protocol_batch(a, b)
+        faults.arm("tcp.frame", ("torn", 0.5), ("always",))
+        assert a.send_batch(b.node_name, msgs) == -1
+        faults.disarm_all()
+        faults.arm("tcp.send", ("raise", "eio"), ("always",))
+        assert a.send_batch(b.node_name, msgs) == -1
+        assert a.counters.v.get("wire_frames_out", 0) == 0
+        # the coordinator's fan-out: every message through send(), where
+        # the armed failpoint drops it and counts it
+        c._send_batch_inline(b.node_name, msgs)
+        assert c.transport.dropped == 4
+        assert c.counters.get("wire_dropped") == 4
+        assert c.counters.get("wire_frames_out") == 0
+        faults.disarm_all()
+        c._send_batch_inline(b.node_name, msgs)
+        assert _await(lambda: len(got) == 4) and calls == [4]
+        assert c.counters.get("wire_frames_out") == 1
+    finally:
+        faults.disarm_all()
+        c.stop()
+        a.close()
+        b.close()
+
+
+def test_an_outbox_at_its_cap_drops_the_batch_and_counts_its_messages():
+    a, b, got, _calls = _pair(outbox_cap=0)  # never room for an element
+    try:
+        assert a.send_batch(b.node_name, _protocol_batch(a, b)) == 0
+        assert a.dropped == 4 and a.counters.v["wire_dropped"] == 4
+        assert a.counters.v.get("wire_frames_out", 0) == 0
+        a.block(a.node_name, b.node_name)  # a blocked pair: per message too
+        assert a.send_batch(b.node_name, _protocol_batch(a, b)) == 0
+        assert a.dropped == 8 and a.counters.v["wire_dropped"] == 8
+        time.sleep(0.1)
+        assert got == []
+    finally:
+        a.close()
+        b.close()
+
+
+def test_a_large_frame_in_small_pieces_is_read_once(monkeypatch):
+    """The reader appends what arrives and cuts its buffer once a recv,
+    never once a frame: a 2 MB frame that trickles in, with small frames
+    behind it, arrives whole and in order."""
+    a, b, got, calls = _pair()
+    try:
+        big = [(("q0", b.node_name), ("blob", 0, bytes(2 << 20)), None)]
+        small = [((f"q{i}", b.node_name), ("blob", i, b"x"), None)
+                 for i in range(1, 4)]
+        wire = b"".join(w for w, _n in a._batch_frames(
+            [(to[0], frm, msg) for to, msg, frm in big]))
+        for m in small:
+            wire += b"".join(w for w, _n in a._batch_frames(
+                [(m[0][0], m[2], m[1])]))
+        host, port = b.node_name.rsplit(":", 1)
+        s = socket.create_connection((host, int(port)), timeout=5)
+        for i in range(0, len(wire), 100_000):
+            s.sendall(wire[i:i + 100_000])
+        assert _await(lambda: len(got) == 4)
+        assert [m[1] for _n, m, _f in got] == [0, 1, 2, 3]
+        assert len(got[0][1][2]) == 2 << 20 and calls == [1, 1, 1, 1]
+        s.close()
+    finally:
+        a.close()
+        b.close()

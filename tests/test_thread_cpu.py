@@ -31,6 +31,12 @@ pytestmark = pytest.mark.skipif(
     ("ra-batch-snd-bench2", "sender"),
     ("ra-batch-bench2", "step"),
     ("ra-wal", "wal writer"),
+    ("ra-batch-det-127.0.0.1:43659", "detector"),
+    ("ra-batch-127.0.0.1:43659", "step"),
+    ("ra-tcp-out-127.0.0.1:43659", "wire writer"),
+    ("ra-tcp-in-127.0.0.1:43659", "wire reader"),
+    ("ra-tcp-ping-127.0.0.1:43659", "wire liveness"),
+    ("ra-tcp-accept-127.0.0.1:43659", "wire liveness"),
     ("fifo-gen-3", "generator"),
     ("ycsb-17", "generator"),
     ("tf_XLATfrtCpuClient/-123", "tf_XLATfrtCpuClient"),
