@@ -384,10 +384,17 @@ COORDINATOR_FIELDS: List[FieldSpec] = [
     ("read_quorum_ns", "counter",
      "heartbeats queued -> the quorum's reply issued, summed"),
     ("state_queries", "counter",
-     "state_query messages answered (kv_get's log fetch, members, "
-     "overview)"),
+     "log reads made for a consistent query's answer (a LogRead: "
+     "kv_get's value, read by the replica that answers), plus "
+     "state_query messages answered (members, overview, sparse_read)"),
     ("state_query_ns", "counter",
-     "future born -> state_query reply issued, summed"),
+     "around the log fetch of a LogRead answer, on the answering "
+     "thread; for a state_query message: future born -> reply issued; "
+     "summed"),
+    ("read_log_misses", "counter",
+     "LogRead answers whose named index this replica's log no longer "
+     "held (the entry comes back None and kv_get re-asks; 0 in a "
+     "healthy window)"),
 ]
 
 # Per-node health-plane vector (name ("health", node_name); written

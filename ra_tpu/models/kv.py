@@ -8,7 +8,7 @@ current ones are advertised via ``live_indexes`` so compaction retains
 exactly the live set.
 
 Commands: ("put", key, value) | ("delete", key). Reads go through
-``get``/aux (log fetch), not apply.
+``kv_get`` (one consistent query that names the log entry), not apply.
 """
 
 from __future__ import annotations
@@ -20,6 +20,7 @@ from typing import Any, Dict, Optional, Tuple
 from ra_tpu import obs
 from ra_tpu.effects import ReleaseCursor
 from ra_tpu.machine import Machine
+from ra_tpu.protocol import LogRead
 
 
 def _digest(value: Any) -> bytes:
@@ -68,11 +69,13 @@ class KvMachine(Machine):
 
 
 def kv_get(api_mod, member, key, timeout: float = 5.0) -> Optional[Any]:
-    """Read a value: consistent-query the index map, then fetch the
-    value from the log (the reference reads via aux/read plans; here the
-    state query returns the index and the log read follows). Retries the
-    state query when the fetch misses — a concurrent overwrite + snapshot
-    may compact the index read in the first round trip."""
+    """Read a value in one round: ONE consistent query whose function
+    resolves the key in the index map and names the value's log entry
+    (``LogRead``); the leader that answers reads that entry from its own
+    log there and then (the reference gets index and read plan in one
+    call; docs/INTERNALS.md §13). The digest is checked here. Re-asks,
+    at most three times, when the answering replica's log no longer
+    held the index it named."""
     if obs.tracing():
         with obs.span("ra/kv/get", node=member[1]):
             return _kv_get(api_mod, member, key, timeout)
@@ -80,24 +83,19 @@ def kv_get(api_mod, member, key, timeout: float = 5.0) -> Optional[Any]:
 
 
 def _kv_get(api_mod, member, key, timeout):
+    def resolve(st):
+        at = st.get(key)
+        return None if at is None else LogRead(at[0], at[1])
+
     for _attempt in range(3):
-        out = api_mod.consistent_query(member, lambda st: st.get(key), timeout=timeout)
+        out = api_mod.consistent_query(member, resolve, timeout=timeout)
         if out[0] != "ok" or out[1] is None:
             return None
-        idx, digest = out[1]
-        entry = _fetch_log_entry(api_mod, member, idx, timeout)
+        idx, digest, entry = out[1]
         if entry is None:
-            continue  # compacted under us: re-resolve the current index
+            continue  # cut under the answer: re-resolve the current index
         value = entry.cmd.data[2]
         if _digest(value) != digest:
             raise IOError(f"kv digest mismatch for {key!r} at idx {idx}")
         return value
     return None
-
-
-def _fetch_log_entry(api_mod, member, idx, timeout):
-    fut = api_mod.Future()
-    if not api_mod._try_send(member, ("state_query", lambda s: s.log.fetch(idx), fut)):
-        return None
-    out = fut.result(timeout)
-    return out[1]

@@ -65,6 +65,26 @@ class Command(NamedTuple):
     ts: Any = None
 
 
+# -- a query's answer that names a log entry -------------------------------
+
+
+class LogRead(NamedTuple):
+    """What a consistent query's function may return in place of a plain
+    value: "read ``index`` from this group's log" (reference:
+    ``ra_kv``'s read plan, values read from the log on demand). The
+    replica that issues the answer fetches the entry there and then and
+    replies with this record, ``entry`` filled in (``None``: its log no
+    longer holds that index). ``note`` rides along untouched (``kv_get``
+    carries the value's digest there). docs/INTERNALS.md §13."""
+
+    index: int
+    note: Any = None
+    entry: Any = None  # Optional[Entry], set by the answering replica
+
+    def read_from(self, log) -> "LogRead":
+        return self._replace(entry=log.fetch(self.index))
+
+
 # -- snapshot metadata -----------------------------------------------------
 
 

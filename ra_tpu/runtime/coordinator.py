@@ -80,6 +80,7 @@ from ra_tpu.protocol import (
     InstallSnapshotResult,
     InstallSnapshotRpc,
     LOSSY_PROTOCOL_TYPES,
+    LogRead,
     NOOP,
     RC_BATCH,
     RC_CMD,
@@ -2344,6 +2345,10 @@ class BatchCoordinator:
                         hint = max(1, min(msg.next_index, msg.last_index + 1))
                         g.next_index[slot] = min(g.next_index[slot], hint)
                     aer_dirty.add(g.gid)
+                    if msg.success and g.inbox and self._supersede_ack(
+                        g.inbox, from_sid, msg
+                    ):
+                        return
             elif (
                 self.lease_cfg.enabled
                 and (t is PreVoteRpc or t is RequestVoteRpc)
@@ -2397,6 +2402,30 @@ class BatchCoordinator:
                 )
             return
         rare.append((g, msg, from_sid))
+
+    @staticmethod
+    def _supersede_ack(inbox, from_sid, msg: AppendEntriesReply) -> bool:
+        """Acks are cumulative: a success reply says of its sender all
+        that an earlier success reply of the same term said. The device
+        takes ONE message a group a step, so under a hot key a leader's
+        lane fills with its followers' acks, two a write, and the
+        newest, the one that commits, waits behind the stale ones. Where
+        ``inbox`` still holds the sender's previous success of this
+        term, with nothing else of the sender's after it, the new one
+        takes its place (as if the old had been lost and the new had
+        come sooner, which the protocol allows) and True comes back."""
+        for k in range(len(inbox) - 1, -1, -1):
+            fs, m = inbox[k]
+            if fs == from_sid:
+                if (
+                    type(m) is AppendEntriesReply and m.success
+                    and m.term == msg.term
+                    and m.last_index <= msg.last_index
+                ):
+                    inbox[k] = (from_sid, msg)
+                    return True
+                return False
+        return False
 
     def _handle_command(self, g: GroupHost, cmd: Command, appended, written, aer_dirty):
         self._handle_commands(g, (cmd,), appended, written, aer_dirty)
@@ -4514,6 +4543,25 @@ class BatchCoordinator:
             if m is not None and g.voter_status.get(i) == "voter"
         )
 
+    def _answer_query(self, g: GroupHost, fn):
+        """A consistent query's answer, at every site that issues one
+        (single voter, lease, quorum round; call sites hold the state
+        lock): ``fn`` of the applied state and, where that names a log
+        entry (``LogRead``), the entry read from this replica's log
+        there and then, so a ``kv_get`` is one request and one reply
+        (docs/INTERNALS.md §13). The log read is booked where the
+        second message's turn used to be."""
+        res = fn(g.machine_state)
+        if type(res) is LogRead:
+            t0 = time.monotonic_ns()
+            res = res.read_from(g.log)
+            cnt = self.counters
+            cnt.incr("state_queries")
+            cnt.incr("state_query_ns", time.monotonic_ns() - t0)
+            if res.entry is None:
+                cnt.incr("read_log_misses")
+        return res
+
     def _handle_consistent_query(self, g: GroupHost, fn, fut) -> None:
         """Linearizable read: confirm leadership with a voter heartbeat
         quorum round before answering, gated on the leader's own noop
@@ -4533,7 +4581,7 @@ class BatchCoordinator:
         born = getattr(fut, "t_born", None)
         cnt = self.counters
         if self._voter_count(g) <= 1:
-            self._reply(fut, ("ok", fn(g.machine_state), me))
+            self._reply(fut, ("ok", self._answer_query(g, fn), me))
             if born is not None:
                 cnt.incr("read_registers")
                 cnt.incr("read_register_ns", time.monotonic_ns() - born)
@@ -4552,7 +4600,7 @@ class BatchCoordinator:
             exp = self._lease_expiry[gid]
             if exp > now:
                 self.counters.incr("read_lease_served")
-                self._reply(fut, ("ok", fn(g.machine_state), me))
+                self._reply(fut, ("ok", self._answer_query(g, fn), me))
                 if born is not None:
                     cnt.incr("read_registers")
                     cnt.incr("read_register_ns", time.monotonic_ns() - born)
@@ -4680,7 +4728,8 @@ class BatchCoordinator:
             if msg.query_index >= q["qid"]:
                 q["acks"].add(from_sid)
                 if len(q["acks"]) + 1 >= quorum and g.last_applied >= q["qi"]:
-                    self._reply(q["fut"], ("ok", q["fn"](g.machine_state), me))
+                    self._reply(
+                        q["fut"], ("ok", self._answer_query(g, q["fn"]), me))
                     done.append(q)
                     t_reg = q.get("t_reg")
                     if t_reg is not None:

@@ -77,6 +77,7 @@ from ra_tpu.protocol import (
     InstallSnapshotResult,
     InstallSnapshotRpc,
     LogEvent,
+    LogRead,
     NOOP,
     REJECT_NOSPACE,
     REJECT_OVERLOADED,
@@ -1056,6 +1057,15 @@ class Server:
                     self._commit_h["durable_commit"].record(lat[4] - lat[3])
                 self._apply_to(agreed, effects=effects)
 
+    def _answer_query(self, fn):
+        """A consistent query's answer, at every site that issues one
+        (quorum round, lease, lease read parked for its apply): ``fn``
+        of the applied state and, where that names a log entry
+        (``LogRead``), the entry read from this replica's log there
+        and then (docs/INTERNALS.md §13)."""
+        res = fn(self.machine_state)
+        return res.read_from(self.log) if type(res) is LogRead else res
+
     def _evaluate_queries(self, effects: EffectList) -> None:
         if not self.pending_queries:
             return
@@ -1069,7 +1079,7 @@ class Server:
         for qi, from_ref, fn in self.pending_queries:
             if qi <= agreed_qi:
                 self._c("consistent_queries")
-                effects.append(Reply(from_ref, ("ok", fn(self.machine_state), self.id)))
+                effects.append(Reply(from_ref, ("ok", self._answer_query(fn), self.id)))
             else:
                 still.append((qi, from_ref, fn))
         self.pending_queries = still
@@ -1182,7 +1192,7 @@ class Server:
                         self._c("read_lease_served")
                         self._c("consistent_queries")
                         effects.append(
-                            Reply(from_ref, ("ok", fn(self.machine_state), self.id))
+                            Reply(from_ref, ("ok", self._answer_query(fn), self.id))
                         )
                     else:
                         self.pending_lease_reads.append((read_idx, from_ref, fn))
@@ -1515,7 +1525,7 @@ class Server:
                 if ridx <= hi:
                     self._c("read_lease_served")
                     self._c("consistent_queries")
-                    sink.append(Reply(ref, ("ok", fn(self.machine_state), self.id)))
+                    sink.append(Reply(ref, ("ok", self._answer_query(fn), self.id)))
                 else:
                     still_reads.append((ridx, ref, fn))
             self.pending_lease_reads = still_reads
