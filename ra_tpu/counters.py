@@ -395,6 +395,23 @@ COORDINATOR_FIELDS: List[FieldSpec] = [
      "LogRead answers whose named index this replica's log no longer "
      "held (the entry comes back None and kv_get re-asks; 0 in a "
      "healthy window)"),
+    # -- what turns a wave sub-phase's total into microseconds a message
+    # (docs/INTERNALS.md §13): booked once a pass or step from a local
+    # sum, never once a message
+    ("routed_msgs", "counter",
+     "protocol messages the step thread routed to their handlers "
+     "(sub-phase ingress_route's work: AppendEntries, replies, votes, "
+     "heartbeats, rare messages)"),
+    ("follower_aers", "counter",
+     "AppendEntries that carried entries and that this node, as a "
+     "follower, wrote to its log and acknowledged at realisation "
+     "(sub-phase egress_follow's per-message work)"),
+    ("follower_entries", "counter",
+     "log entries those AppendEntries carried"),
+    ("rares_handled", "counter",
+     "rare messages handled at realisation (sub-phase egress_rare's "
+     "work: consistent queries, heartbeats and their replies, election "
+     "timeouts, snapshot chunks, membership)"),
 ]
 
 # Per-node health-plane vector (name ("health", node_name); written

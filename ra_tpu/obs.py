@@ -263,22 +263,28 @@ def histogram(name, help: str = "", unit: str = "ns",
 # WAVE_STEP_PHASES are DISJOINT slices of one coordinator step — they
 # sum to the step-loop wall time and are the share denominator in
 # attribution tools; WAVE_SUBSET_PHASES are finer-grained views RECORDED
-# WITHIN a step phase (never added to the denominator).
+# WITHIN a step phase (never added to the denominator), and two waits
+# that stand outside every phase and are in no share's denominator
+# either: send_queue lies BETWEEN the phases' threads and the sender,
+# gil_wait BENEATH all of them (whatever a thread of the process does,
+# it pays that wait whenever it comes back for the interpreter lock).
 WAVE_STEP_PHASES = (
     ("ingress_drain", "drain ingress queues + route messages + append "
-                      "client commands (includes WAL handoff)"),
+                      "client commands; its leaves: ingress_classify, "
+                      "step_lock_wait, ingress_route, ingest_append, "
+                      "ingest_fanout"),
     ("host_pack", "apply queued device scatters + pack the mailbox"),
     ("device_step", "step dispatched -> egress synced and the state lock "
                     "held: ticket_queue + egress_sync + egress_lock_wait"),
-    ("host_egress", "realise egress: acks, role changes, apply, replies"),
+    ("host_egress", "realise egress: acks, role changes, apply, replies, "
+                    "rare messages (also those of a ticket that stepped "
+                    "nothing); its leaves: egress_follow, egress_mirror, "
+                    "egress_apply, egress_rare"),
     ("aer_fanout", "build + send outbound AER batches (a dispatching "
                    "pass's, ahead of its host_pack, and the commit-driven "
                    "one at realisation)"),
 )
 WAVE_SUBSET_PHASES = {
-    "apply": "subset of host_egress (machine apply, sampled groups)",
-    "wal_handoff": "subset of ingress_drain (log.append hand-off, "
-                   "sampled groups)",
     "classify_native": "subset of ingress_drain (GIL-released native "
                        "class partition of the drained burst; zero "
                        "samples when the native path is off)",
@@ -302,7 +308,7 @@ WAVE_SUBSET_PHASES = {
     "egress_lock_wait": "subset of device_step (egress synced -> the "
                         "state lock held)",
     # what only a busy fleet works: one record per pass over ALL its
-    # groups (the sampled wal_handoff and apply above time one group)
+    # groups
     "ingest_append": "subset of ingress_drain (log appends + WAL "
                      "hand-off of the pass's client commands, all "
                      "groups; no sample on a pass without commands)",
@@ -314,6 +320,41 @@ WAVE_SUBSET_PHASES = {
                        "send_msg, monitors, release cursors, ...; one "
                        "clock pair a _realise_effects call, added up; no "
                        "sample on a step that realised none)",
+    # the rest of ingress_drain and host_egress, to the leaf (step
+    # thread the first three, the realising thread the next three)
+    "ingress_classify": "subset of ingress_drain (the burst popped off "
+                        "the ingress lanes and classified, outside the "
+                        "state lock; recorded where ingress_drain is)",
+    "ingress_route": "subset of ingress_drain (every drained protocol "
+                     "message to its handler, and the replies that "
+                     "routing produced handed to the sender; no sample "
+                     "on a pass without messages)",
+    "ingest_fanout": "subset of ingress_drain (an ingest-only pass's "
+                     "AppendEntries fan-out for the groups it appended "
+                     "to; a dispatching pass's is aer_fanout's)",
+    "egress_follow": "subset of host_egress (the loop over the step's "
+                     "consumed messages: vote and AppendEntries replies, "
+                     "a follower's log writes and acks; no sample on a "
+                     "step that consumed none)",
+    "egress_mirror": "subset of host_egress (the sweep over the touched "
+                     "groups: roles, terms, meta store, became-leader, "
+                     "term hints, and the step's replies handed to the "
+                     "sender; less the applies inside it)",
+    "egress_rare": "subset of host_egress (the step's rare messages: "
+                   "consistent queries registered, heartbeats answered, "
+                   "elections, snapshots, membership; no sample on a "
+                   "step without any)",
+    "send_queue": "no phase's: a batch published to the sender's ring "
+                  "-> the sender thread drains it (one sample a batch; "
+                  "an inline send waits for nobody and records none)",
+    "gil_wait": "no phase's: how much later than asked a thread of this "
+                "process wakes from a 20 ms sleep, which is the wait of "
+                "any thread that comes back from a call that let go of "
+                "the interpreter lock (a jitted call, an fsync, a "
+                "socket, a numpy call); kernel timer slack included "
+                "(0.06-0.25 ms when nothing contends); sampled 50 times "
+                "a second by one thread a process, on the first started "
+                "coordinator only; the process's, no one thread's",
 }
 WAVE_PHASES = WAVE_STEP_PHASES + tuple(WAVE_SUBSET_PHASES.items())
 

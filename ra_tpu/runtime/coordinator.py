@@ -1137,7 +1137,7 @@ class BatchCoordinator:
                 # ship the residue inline so queued acks still leave
                 out: List = []
                 if self._egress_rings.drain(out):
-                    for node_name, msgs in out:
+                    for node_name, msgs, _t_pub in out:
                         try:
                             self._send_batch_inline(node_name, msgs)
                         except Exception:  # noqa: BLE001 — best effort
@@ -1486,6 +1486,7 @@ class BatchCoordinator:
         Drains outstanding batches on stop so queued acks still leave."""
         wake = self._egress_wake
         rings = self._egress_rings
+        send_queue = self._wave_h["send_queue"].record
         out: List = []
         while True:
             n = rings.drain(out)
@@ -1506,10 +1507,14 @@ class BatchCoordinator:
                 wake.wait()
                 continue
             msgs_n = 0
+            # sub-phase send_queue: published -> drained, a sample a batch
+            now = time.perf_counter_ns()
+            for _n, _msgs, t_pub in out:
+                send_queue(now - t_pub)
             tr = _obs.tracing()
             if tr:
                 sp = _obs.begin("ra/send/batch", node=self.name,
-                                msgs=sum(len(msgs) for _n, msgs in out))
+                                msgs=sum(len(item[1]) for item in out))
             batches = out
             if self._wired and n > 1:
                 # across a wire a batch is a frame, and a frame costs
@@ -1518,10 +1523,11 @@ class BatchCoordinator:
                 # while this thread waited its own turn leaves as one
                 # (the slower the turns, the larger the frames)
                 by_node: Dict[str, List] = {}
-                for node_name, msgs in out:
+                for node_name, msgs, _t_pub in out:
                     by_node.setdefault(node_name, []).extend(msgs)
                 batches = by_node.items()
-            for node_name, msgs in batches:
+            for item in batches:
+                node_name, msgs = item[0], item[1]
                 try:
                     self._send_batch_inline(node_name, msgs)
                 except Exception:  # noqa: BLE001
@@ -1800,12 +1806,14 @@ class BatchCoordinator:
                 elif name in by:
                     radd(trip)
 
-    def _ingest(self, n_items, cmd_q, routes, lows, tr=False):
+    def _ingest(self, n_items, cmd_q, routes, lows, tr=False, t_held=0):
         """Route one classified burst under the state lock: messages to
         their handlers, commands into the logs and the WAL queue, the
         appended runs and durable watermarks into the staged scatter
         dicts. Returns ``(n_items, rare, aer_dirty)``. ``tr``: a
-        profiler session takes the spans."""
+        profiler session takes the spans; ``t_held``: when the caller
+        got the state lock (``perf_counter_ns``), where the sub-phase
+        ``ingress_route`` starts."""
         # fold the step/egress threads' own must-deliver self-publishes
         # (machine Append/Aux effects realized under the state lock —
         # including by the prev-ticket finish that just ran): they are
@@ -1872,15 +1880,27 @@ class BatchCoordinator:
                     g.low_q.append(cmd)
                     low_dirty.add(g.gid)
         if routes:
+            # every drained protocol message to its handler, and the
+            # replies that produced to the sender: sub-phase
+            # ingress_route, one record a pass that had messages, from
+            # the lock's own stamp (so that the way here is inside it)
+            if tr:
+                sp = _obs.begin("ra/step/ingress_drain/route",
+                                node=self.name, msgs=len(routes))
+            _t_route = t_held or time.perf_counter_ns()
             now_mono = self.clock.monotonic()
             for name, from_sid, msg in routes:
                 g = by_get(name)
                 if g is not None:
                     route(g, from_sid, msg, rare, appended, written,
                           aer_dirty, route_out, now_mono)
-        if route_out:
             for node_name, msgs in route_out.items():
                 self._send_batch(node_name, msgs)
+            self._wave_h["ingress_route"].record(
+                time.perf_counter_ns() - _t_route)
+            self.counters.incr("routed_msgs", len(routes))
+            if tr:
+                _obs.end(sp)
         if cmd_q or self._low_dirty:
             # the pass's client commands into the logs and the WAL
             # queue: sub-phase ingest_append, one record for all groups
@@ -1918,8 +1938,9 @@ class BatchCoordinator:
         shift = self._CPU_SAMPLE_SHIFT
         if tr:
             sp = _obs.begin("ra/step/ingress_drain", node=node)
+        t_held = self._step_lock.t_held
         n_items, rare, aer_dirty = self._ingest(n_items, cmd_q, routes, lows,
-                                                tr)
+                                                tr, t_held)
         if tr:
             _obs.end(sp)
         appended = self._staged_app
@@ -1932,12 +1953,18 @@ class BatchCoordinator:
             # coalescing the pipeline is for happens here.
             if rare:
                 self._pending_rare = rare
+            fan_ns = 0
             if aer_dirty:
                 # replication fan-out never waits for the next dispatch:
-                # fresh appends ship while the in-flight step realises
+                # fresh appends ship while the in-flight step realises.
+                # Inside this pass's ingress_drain: sub-phase
+                # ingest_fanout (aer_fanout keeps its one writer, the
+                # realising thread)
                 if tr:
-                    sp = _obs.begin("ra/step/aer_fanout", node=node)
+                    sp = _obs.begin("ra/step/ingress_drain/fanout", node=node)
+                _t_fan = time.perf_counter_ns()
                 self._send_aers(aer_dirty)
+                fan_ns = (time.perf_counter_ns() - _t_fan) or 1
                 if tr:
                     _obs.end(sp)
             if n_items:
@@ -1946,7 +1973,10 @@ class BatchCoordinator:
                     cnt.incr("cpu_ns_ingress_drain",
                              (time.thread_time_ns() - _c_in) << shift)
                 wh["ingress_drain"].record(time.perf_counter_ns() - _t_in)
-                wh["step_lock_wait"].record(self._step_lock.t_held - _t_cls)
+                wh["ingress_classify"].record(_t_cls - _t_in)
+                wh["step_lock_wait"].record(t_held - _t_cls)
+                if fan_ns:
+                    wh["ingest_fanout"].record(fan_ns)
             return None
         if not (
             n_items or self._hot or rare or appended or written
@@ -2028,8 +2058,15 @@ class BatchCoordinator:
                     self.shard_moves += 1
                     self.state = jax.device_put(self.state, self._shard_state)
                 packed = jax.device_put(packed, self._shard_mbox)
-            self.state, eg_packed = step(self.state, packed)
+            state, eg_packed = step(self.state, packed)
+            # the old state, donated to the call, goes here: its arrays
+            # are released as the new ones take their place
             if tr:
+                sp_rel = _obs.begin(
+                    "ra/step/host_pack/step_dispatch/release", node=node)
+            self.state = state
+            if tr:
+                _obs.end(sp_rel)
                 _obs.end(sp)
             stepped = True
             self.steps += 1
@@ -2049,7 +2086,8 @@ class BatchCoordinator:
         # ticket's realisation half syncs it (np.asarray) and processes
         # the egress. The sequential step_once realises inline.
         wh["ingress_drain"].record(_t_ing - _t_in)
-        wh["step_lock_wait"].record(self._step_lock.t_held - _t_cls)
+        wh["ingress_classify"].record(_t_cls - _t_in)
+        wh["step_lock_wait"].record(t_held - _t_cls)
         if stepped:
             wh["host_pack"].record(_t_pack - _t_drain)
             wh["scatter_dispatch"].record(_t_scat - _t_drain)
@@ -2164,14 +2202,23 @@ class BatchCoordinator:
             # mailbox view, so the pack buffer may be reused
             self._mbox_release(ticket.mbox_buf)
             eg = {name: eg_np[i] for i, name in enumerate(C.EGRESS_FIELDS)}
-            self._process_egress(eg, ticket.consumed, aer_dirty,
-                                 act=ticket.act)
+            _t_rare = self._process_egress(eg, ticket.consumed, aer_dirty,
+                                           act=ticket.act, tr=tr, t0=_t_dev)
             if tr:
                 _obs.end(sp)
+        else:
+            _t_rare = _t_dev
+        rare_ns = 0
         if ticket.rare:
+            # where a consistent query is registered and a heartbeat
+            # answered: sub-phase egress_rare, from where egress_mirror
+            # ended (booked below, beside the host_egress it is a part
+            # of)
             if tr:
                 sp = _obs.begin("ra/egress/rare", node=node)
             self._handle_rares(ticket.rare)
+            rare_ns = (time.perf_counter_ns() - _t_rare) or 1
+            self.counters.incr("rares_handled", len(ticket.rare))
             if tr:
                 self._end_effects_span()
                 _obs.end(sp)
@@ -2196,8 +2243,8 @@ class BatchCoordinator:
         # (recorded at dispatch time); device_step runs from the
         # dispatch to here, and its three sub-phases add up to it;
         # host_egress includes the rare paths, apply and client replies
-        # (the step's applies also get egress_apply, one sampled group's
-        # apply its own histogram). The dispatching pass's
+        # (its leaves: egress_follow, egress_mirror and egress_apply
+        # from _process_egress, egress_rare). The dispatching pass's
         # own AER fan-out is booked here too, so that aer_fanout and
         # its CPU account have one writer.
         wh = self._wave_h
@@ -2208,7 +2255,14 @@ class BatchCoordinator:
             wh["egress_sync"].record(t_sync - t_pop)
             wh["egress_lock_wait"].record(_t_dev - t_sync)
             wh["device_step"].record(_t_dev - ticket.t_pack)
+        # (a pass that found nothing to step still hands its rare
+        # messages over on a ticket: a consistent query on a quiet
+        # leader. Their time is host_egress too, or no phase holds it)
+        egressed = eg_np is not None or rare_ns
+        if egressed:
             wh["host_egress"].record(_t_eg - _t_dev)
+            if rare_ns:
+                wh["egress_rare"].record(rare_ns)
         if ticket.aer0_ns is not None:
             wh["aer_fanout"].record(ticket.aer0_ns)
         wh["aer_fanout"].record(_t_aer - _t_eg)
@@ -2218,7 +2272,7 @@ class BatchCoordinator:
             if ticket.aer0_cpu_ns is not None:
                 cpu_aer += ticket.aer0_cpu_ns
             cnt.incr("cpu_ns_aer_fanout", cpu_aer << shift)
-            if eg_np is not None:
+            if egressed:
                 cnt.incr("cpu_ns_host_egress", (_c_eg - _c_dev) << shift)
 
     def _handle_rares(self, rares) -> None:
@@ -2566,7 +2620,6 @@ class BatchCoordinator:
         # commit-stage sampling: bounded to groups on the sample mask,
         # and only for commands stamped with a submit ts
         sampled = (gid & self._lat_mask) == 0
-        t_h0 = time.monotonic_ns() if sampled else 0
         # fast path: plain user commands owing no replies (the pipeline
         # shape) — build the run in one pass and bulk-append it
         simple = True
@@ -2621,7 +2674,6 @@ class BatchCoordinator:
         last = idx - 1
         if sampled:
             now_ns = time.monotonic_ns()
-            self._wave_h["wal_handoff"].record(now_ns - t_h0)
             ts0 = cmds[0].ts
             lat = g.lat
             if ts0 is not None and (
@@ -3087,11 +3139,18 @@ class BatchCoordinator:
 
     # -- egress ------------------------------------------------------------
 
-    def _process_egress(self, eg, consumed, aer_dirty, act=None) -> None:
+    def _process_egress(self, eg, consumed, aer_dirty, act=None,
+                        tr=False, t0=0) -> int:
         """Realise one step's egress. ``act`` is None for the full-width
         step (egress row == group id) or the i64 position->gid map of an
         active-set step (egress row == position in ``act``); ``consumed``
-        is keyed in the same space as the egress rows."""
+        is keyed in the same space as the egress rows. ``tr``: a
+        profiler session takes the spans; ``t0``: where the wave phase
+        ``host_egress`` started (``perf_counter_ns``). Two clock reads
+        split the time since ``t0`` into the sub-phases
+        ``egress_follow`` (up to the end of the loop over the consumed
+        messages) and ``egress_mirror`` (all after it, less the applies,
+        which are ``egress_apply``). Returns the second."""
         outbound: Dict[str, List[Tuple[ServerId, Any, ServerId]]] = {}
 
         def queue_send(to: ServerId, msg: Any, frm: ServerId):
@@ -3105,7 +3164,13 @@ class BatchCoordinator:
         # numpy scalar indexing (plus int()/bool() coercion) in a
         # per-message loop is slow; gather each needed field for exactly
         # the consumed rows in one vector op, then read python ints
+        clock_ns = time.perf_counter_ns
+        t_mark = t0 or clock_ns()
         if consumed:
+            if tr:
+                sp = _obs.begin("ra/egress/host_egress/follow",
+                                node=self.name, msgs=len(consumed))
+            n_faer = n_fent = 0
             items = list(consumed.items())
             ci = np.fromiter((i for i, _ in items), np.int64, len(items))
             nh_l = needs_host[ci].tolist()
@@ -3128,6 +3193,9 @@ class BatchCoordinator:
                         # the host performs the write and owns the
                         # durable watermark, so it builds the success
                         # ack (possibly deferred until WAL fsync)
+                        if msg.entries:
+                            n_faer += 1
+                            n_fent += len(msg.entries)
                         self._host_write_entries(g, msg)
                         self._ack_aer(g, from_sid, msg, term_l[p], outbound)
                     elif sr_l[p] and from_sid is not None:
@@ -3160,6 +3228,18 @@ class BatchCoordinator:
                             PreVoteResult(term_l[p], msg.token, bool(succ_l[p])),
                             (g.name, self.name),
                         )
+            t_follow = clock_ns()
+            self._wave_h["egress_follow"].record(t_follow - t_mark)
+            t_mark = t_follow
+            if n_faer:
+                cnt = self.counters
+                cnt.incr("follower_aers", n_faer)
+                cnt.incr("follower_entries", n_fent)
+            if tr:
+                _obs.end(sp)
+        if tr:
+            sp = _obs.begin("ra/egress/host_egress/mirror", node=self.name)
+        apply_ns = 0
 
         # vectorized change detection: only touched groups pay Python cost
         n = self.n_groups if act is None else len(act)
@@ -3192,8 +3272,6 @@ class BatchCoordinator:
             now_roles = self.clock.monotonic()
             # machine apply and client replies of every group this step
             # committed: each in its place, their clock pairs added up
-            apply_ns = 0
-            clock_ns = time.perf_counter_ns
             for p, pos in enumerate(touched):
                 i = pos if act is None else int(act[pos])
                 g = groups[i]
@@ -3266,6 +3344,13 @@ class BatchCoordinator:
 
         for node_name, msgs in outbound.items():
             self._send_batch(node_name, msgs)
+        # sub-phase egress_mirror: all since the consumed loop, the
+        # applies taken out (they are egress_apply's)
+        t_end = clock_ns()
+        self._wave_h["egress_mirror"].record(t_end - t_mark - apply_ns)
+        if tr:
+            _obs.end(sp)
+        return t_end
 
     def _host_resolve_aer(self, g: GroupHost, from_sid, msg: AppendEntriesRpc, queue_send):
         """Deep backfill: resolve the prev term from the host log and
@@ -3441,12 +3526,6 @@ class BatchCoordinator:
         hi = min(commit_index, li)
         if hi <= g.last_applied:
             return
-        # apply-duration histogram is SAMPLED (same mask as the commit
-        # stages): at 10k groups per wave an unconditional record per
-        # group is a measurable tax on the loop it measures
-        _t_apply0 = (
-            time.perf_counter_ns() if (g.gid & self._lat_mask) == 0 else 0
-        )
         # commit-stage sample: the tracked entry commits (and applies)
         # in THIS call iff it is durable and within hi; ``lat`` stays a
         # local None otherwise so the hot loop pays one check per entry
@@ -3508,10 +3587,6 @@ class BatchCoordinator:
                     self._lat_gids.discard(g.gid)
                 else:
                     self._commit_gates(g, hi, is_leader)
-                if _t_apply0:
-                    self._wave_h["apply"].record(
-                        time.perf_counter_ns() - _t_apply0
-                    )
                 return
         mac = machine.which_module(mver)
         apply_fn = mac.apply
@@ -3589,8 +3664,6 @@ class BatchCoordinator:
             self._commit_h["apply_reply"].record(time.monotonic_ns() - now2)
             g.lat = None
             self._lat_gids.discard(g.gid)
-        if _t_apply0:
-            self._wave_h["apply"].record(time.perf_counter_ns() - _t_apply0)
 
     def _commit_gates(self, g: GroupHost, hi: int, is_leader: bool) -> None:
         """Noop-commit gate for apply paths that skip the per-entry loop
@@ -3848,7 +3921,10 @@ class BatchCoordinator:
         cost; a full handoff ring falls back to an inline send (bounded
         handoff never drops)."""
         if self._egress_on:
-            if self._egress_rings.publish((node_name, msgs)):
+            # (the stamp rides the item: sub-phase send_queue, which the
+            # sender thread records when it drains the batch)
+            if self._egress_rings.publish(
+                    (node_name, msgs, time.perf_counter_ns())):
                 return
             self.counters.incr("egress_thread_ring_full")
         self._send_batch_inline(node_name, msgs)

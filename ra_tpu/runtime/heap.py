@@ -30,6 +30,11 @@ coordinator of the process is started:
 The last ``stop()`` gives it all back: ``gc.unfreeze()`` and the
 thresholds that were found. Called from ``BatchCoordinator.start()``
 and ``stop()`` only.
+
+The process's other account rides the same registry and the same two
+calls: ``runtime/gil_probe.py``'s thread, which samples the wait for the
+interpreter lock, lives from the first ``enter`` to the last ``leave``
+and books on the first started coordinator too.
 """
 
 import gc
@@ -37,6 +42,7 @@ import threading
 import time
 
 from ra_tpu import obs as _obs
+from ra_tpu.runtime import gil_probe as _gil_probe
 
 # containers a group replica keeps alive across a full-width wave (its
 # entry, command, triple, AERs and replies in flight; PERF.md, PR 27)
@@ -87,12 +93,15 @@ def enter(coord) -> None:
     with _lock:
         gc.collect()
         gc.freeze()
-        if not _serving:
+        first = not _serving
+        if first:
             _found = gc.get_threshold()
             gc.callbacks.append(_on_gc)
         _serving.append((coord.counters, coord.name,
                          coord.capacity * coord.P))
         _size_generations()
+        if first:
+            _gil_probe.start(_serving)
 
 
 def leave(coord) -> None:
@@ -107,6 +116,7 @@ def leave(coord) -> None:
         if _serving:
             _size_generations()
             return
+        _gil_probe.stop()
         gc.callbacks.remove(_on_gc)
         gc.set_threshold(*_found)
         _found = None

@@ -20,6 +20,14 @@ one that says whether the wave loop's time is accounted for.
 ``benchmark/trace_reduce.reduce_planes`` is, so that the benchmark's
 ``breakdown.idle_gaps`` can call it.
 
+``dispatch_edges()``, on the same tuples, lays every step dispatch
+(span ``ra/step/host_pack/step_dispatch``) against the run of the step
+program it started (``XLA Modules`` on the same device plane): how long
+from the call's start to the program's start, and from the program's
+end to the call's return. The second, where it is positive, is the step
+thread standing at the interpreter lock with the device already done
+(``scripts/traced_cell.py`` prints both tables for one cell).
+
 Usage:
     python scripts/idle_gaps.py <trace.xplane.pb[.gz]> [--top 30] [--json]
 """
@@ -33,7 +41,13 @@ import sys
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 HOST_PLANE = "/host:CPU"
 OPS_LINE = "XLA Ops"
+MODULES_LINE = "XLA Modules"
+STEP_PROGRAM = "consensus_step_packed"  # every step variant's name holds it
 SPAN_PREFIX = "ra/"
+DISPATCH_SPAN = "ra/step/host_pack/step_dispatch"
+# a span that was opened at its end and says how far back it reaches
+# (ra_tpu/runtime/gil_probe.py: an annotation cannot be opened in the past)
+BACKDATED_BY = "wait_ns"
 
 
 def merge(intervals):
@@ -116,15 +130,58 @@ def idle_gaps(device_ops, host_spans, window=None) -> dict:
     }
 
 
+def median(values):
+    v = sorted(values)
+    return None if not v else (v[(len(v) - 1) // 2] + v[len(v) // 2]) / 2
+
+
+def dispatch_edges(step_runs, host_spans) -> dict:
+    """``step_runs``: [(start_ns, end_ns)] of the step programs' runs on
+    one device; ``host_spans`` as for :func:`idle_gaps`. Every
+    ``DISPATCH_SPAN``, in order of its start, takes the first run not
+    yet taken that begins inside or after it (a run belongs to one
+    dispatch; the nodes of a process share the device). Per node: the
+    dispatches matched, the medians of call start -> program start
+    (``to_start_ms``) and of program end -> call return
+    (``after_end_ms``: negative where the call returned while the
+    program still ran), and ``ended_before_return``: the share of steps
+    whose program had ended before the call returned."""
+    runs = sorted(step_runs)
+    spans = sorted((lo, hi, node) for name, node, lo, hi in host_spans
+                   if name == DISPATCH_SPAN)
+    by_node, k = {}, 0
+    for lo, hi, node in spans:
+        while k < len(runs) and runs[k][0] < lo:
+            k += 1
+        if k == len(runs):
+            break
+        run_lo, run_hi = runs[k]
+        k += 1
+        by_node.setdefault(node, []).append((run_lo - lo, hi - run_hi))
+    return {
+        "dispatches": len(spans), "step_runs": len(runs),
+        "rows": [
+            {"node": node, "steps": len(edges),
+             "to_start_ms": median(e[0] for e in edges) / 1e6,
+             "after_end_ms": median(e[1] for e in edges) / 1e6,
+             "ended_before_return":
+                 sum(e[1] > 0 for e in edges) / len(edges)}
+            for node, edges in sorted(by_node.items())
+        ],
+    }
+
+
 def read_trace(path: str):
-    """``(device_ops, host_spans)`` of the first TPU plane and the host
-    plane of an ``xplane.pb`` (or one gzipped)."""
+    """``(device_ops, host_spans, step_runs)`` of the first TPU plane and
+    the host plane of an ``xplane.pb`` (or one gzipped); ``step_runs``:
+    the runs of the step programs on that plane, for
+    :func:`dispatch_edges`."""
     from jax.profiler import ProfileData
 
     opener = gzip.open if path.endswith(".gz") else open
     with opener(path, "rb") as f:
         data = ProfileData.from_serialized_xspace(f.read())
-    device_ops, host_spans = [], []
+    device_ops, host_spans, step_runs = [], [], []
     device = min((p.name for p in data.planes if DEVICE_PLANE.match(p.name)),
                  default=None)
     for plane in data.planes:
@@ -133,14 +190,19 @@ def read_trace(path: str):
                 if line.name == OPS_LINE:
                     device_ops += [(e.start_ns, e.start_ns + e.duration_ns)
                                    for e in line.events]
+                elif line.name == MODULES_LINE:
+                    step_runs += [(e.start_ns, e.start_ns + e.duration_ns)
+                                  for e in line.events
+                                  if STEP_PROGRAM in e.name]
         elif plane.name == HOST_PLANE:
             for line in plane.lines:
                 for e in line.events:
                     if e.name.startswith(SPAN_PREFIX):
-                        node = dict(e.stats).get("node", "?")
-                        host_spans.append((e.name, str(node), e.start_ns,
-                                           e.start_ns + e.duration_ns))
-    return device_ops, host_spans
+                        stats = dict(e.stats)
+                        lo = e.start_ns - int(stats.get(BACKDATED_BY, 0))
+                        host_spans.append((e.name, str(stats.get("node", "?")),
+                                           lo, e.start_ns + e.duration_ns))
+    return device_ops, host_spans, step_runs
 
 
 def render(got: dict, top: int) -> str:
@@ -170,7 +232,7 @@ def main(argv=None) -> int:
     ap.add_argument("--top", type=int, default=30)
     ap.add_argument("--json", action="store_true")
     args = ap.parse_args(argv)
-    device_ops, host_spans = read_trace(args.trace)
+    device_ops, host_spans, _step_runs = read_trace(args.trace)
     if not device_ops:
         print("idle_gaps: no operation on a TPU plane in this trace",
               file=sys.stderr)

@@ -99,8 +99,12 @@ def main() -> int:
                            for n in names), "leaders")
     t0 = time.perf_counter()
     waves = 0
+    # (an ingest-only pass that appended sends its AppendEntries at once:
+    # sub-phase ingest_fanout, which only the overlap works)
+    fanout = obs.histograms().fetch(("wave", lead.name, "ingest_fanout"))
     while waves < args.cmds or (
-        lead.counters.get("pipeline_overlap_ns") <= 0 and waves < 200
+        (lead.counters.get("pipeline_overlap_ns") <= 0 or fanout.n == 0)
+        and waves < 200
     ):
         waves += 1
         lead.deliver_many([
@@ -133,7 +137,12 @@ def main() -> int:
                 errors.append(f"pipe0 served with rt_native loaded but "
                               f"{k}=0 (native path never engaged)")
     # effects_realise must exist; this burst's machine returns no
-    # effect, so its count is 0 (tests/test_fifo_deployment.py works it)
+    # effect, so its count is 0 (tests/test_fifo_deployment.py works it).
+    # Every other sub-phase must be NONZERO here, send_queue and gil_wait
+    # among them: those two may be empty only on a cluster that was never
+    # started (no sender thread, no probe), and this one is started, with
+    # pipe0 the process's first started coordinator, which takes the
+    # probe's samples.
     required_pipe = (
         [rf"ra_wave_pipe0_{ph}_seconds_count (\d+)"
          for ph, _ in obs.WAVE_PHASES

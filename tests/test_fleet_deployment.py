@@ -60,10 +60,10 @@ def _watched_run(groups):
     apply_group = BatchCoordinator._apply_group
     notify_many = BatchCoordinator.wal_notify_many
 
-    def _ingest(self, n_items, cmd_q, routes, lows, tr=False):
+    def _ingest(self, n_items, cmd_q, *args, **kw):
         if cmd_q:
             seen["ingest"][self.name] += 1
-        return ingest(self, n_items, cmd_q, routes, lows, tr)
+        return ingest(self, n_items, cmd_q, *args, **kw)
 
     def _apply_group(self, g, commit_index):
         applying[self.name] = True

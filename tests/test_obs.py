@@ -250,19 +250,24 @@ def test_system_overview_live_batch_cluster(three_coords):
     assert ov["overview"]["backend"] == "tpu_batch"
     # wave phases non-zero under load: every slice of a step, and every
     # sub-phase a leader's step, realisation and apply of four client
-    # commands must pass through (group 0 is on the sample mask). The
-    # native sub-phases have no sample while the native path is off,
-    # effects_realise none while no machine returns an effect;
+    # commands must pass through. The native sub-phases have no sample
+    # while the native path is off, effects_realise none while no
+    # machine returns an effect, ingest_fanout none unless a command
+    # arrived while a step was in flight, gil_wait none on a node that
+    # is not the process's first started one
+    # (tests/test_wave_account.py holds those two);
     # system_overview leaves an empty histogram out.
     wave = {k[2]: v for k, v in ov["histograms"].items()
             if isinstance(k, tuple) and k[0] == "wave" and k[1] == "ot0"}
     assert set(wave) <= {ph for ph, _ in obs.WAVE_PHASES}
-    for ph, _ in obs.WAVE_STEP_PHASES + (("apply", ""),):
+    for ph, _ in obs.WAVE_STEP_PHASES + (("egress_apply", ""),):
         assert wave.get(ph, {}).get("count", 0) > 0, (ph, wave.keys())
         assert wave[ph]["sum_ms"] > 0, ph
     for ph in set(obs.WAVE_SUBSET_PHASES) - {"classify_native", "pack_native",
-                                             "effects_realise"}:
+                                             "effects_realise",
+                                             "ingest_fanout", "gil_wait"}:
         assert wave.get(ph, {}).get("count", 0) > 0, (ph, wave.keys())
+    assert not {"apply", "wal_handoff"} & set(wave)
     # this machine returns no effect: nothing was realised
     assert "effects_realise" not in wave
     # one record per pass that had commands / per step that committed:

@@ -624,9 +624,11 @@ def test_idle_leaders_aer_leaves_before_the_device_hand_off(monkeypatch):
         (ticket, wall1), = tickets
         assert ticket.stepped and ticket.aer0_ns > 0
         assert leader.counters.get("aer_groups_before_pack") == before + 1
-        assert sorted(node for node, _ in on_ring_at_dispatch) == [
+        # (a ring item: destination, messages, when it was published)
+        assert sorted(node for node, _, _t in on_ring_at_dispatch) == [
             "hf1", "hf2"]
-        for _node, msgs in on_ring_at_dispatch:
+        for _node, msgs, t_pub in on_ring_at_dispatch:
+            assert ticket.t_in < t_pub < ticket.t_pack
             (_to, rpc, frm), = msgs
             assert type(rpc) is AppendEntriesRpc and frm == ids[0]
             assert [e.cmd.data for e in rpc.entries] == [7]
@@ -634,7 +636,7 @@ def test_idle_leaders_aer_leaves_before_the_device_hand_off(monkeypatch):
         # fan-out (booked when the ticket realises), then host_pack
         assert (wall1 - wall0 + ticket.aer0_ns
                 == ticket.t_pack - ticket.t_in)
-        for node, msgs in on_ring_at_dispatch:
+        for node, msgs, _t in on_ring_at_dispatch:
             leader._send_batch_inline(node, msgs)
         monkeypatch.undo()
         _drive(coords, step,

@@ -5,8 +5,10 @@ split the wave phases and the reads (ISSUE 24; docs/INTERNALS.md §13):
   every span name of every thread role on ``/host:CPU`` with its
   ``node`` stat;
 - the sub-phases of ``device_step`` and of ``host_pack`` add up to
-  them, the thread-CPU accounts stay inside their wall phases, the read
-  accounts count every read and stay inside what the callers measured;
+  them, the leaves of ``ingress_drain`` and of ``host_egress`` stay
+  inside them (ISSUE 35), the thread-CPU accounts stay inside their
+  wall phases, the read accounts count every read and stay inside what
+  the callers measured;
 - ``scripts/idle_gaps.py`` on synthetic planes; ``api.profile``.
 """
 
@@ -26,6 +28,7 @@ from ra_tpu.log.wal import Wal
 from ra_tpu.models.kv import KvMachine, kv_get
 from ra_tpu.ops import consensus as C
 from ra_tpu.protocol import USR, Command, ElectionTimeout
+from ra_tpu.runtime import heap
 from ra_tpu.runtime.coordinator import BatchCoordinator
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
@@ -36,17 +39,23 @@ NODES = ("sp0", "sp1", "sp2")
 GROUPS = 4
 READS = 60
 WRITES = 150
+BURST = (4, 10)  # callers at once, puts each: a put lands on a busy loop
 
 SPANS = {
     "step": {"ra/step/classify", "ra/step/lock_wait", "ra/step/ingress_drain",
-             "ra/step/ingress_drain/ingest_append", "ra/step/host_pack",
+             "ra/step/ingress_drain/ingest_append",
+             "ra/step/ingress_drain/route", "ra/step/ingress_drain/fanout",
+             "ra/step/host_pack",
              "ra/step/host_pack/scatter_dispatch",
              "ra/step/host_pack/mailbox_build",
-             "ra/step/host_pack/step_dispatch", "ra/step/aer_fanout",
+             "ra/step/host_pack/step_dispatch",
+             "ra/step/host_pack/step_dispatch/release", "ra/step/aer_fanout",
              "ra/step/idle"},
     "egress": {"ra/egress/wait", "ra/egress/sync", "ra/egress/lock_wait",
-               "ra/egress/host_egress", "ra/egress/rare",
+               "ra/egress/host_egress", "ra/egress/host_egress/follow",
+               "ra/egress/host_egress/mirror", "ra/egress/rare",
                "ra/egress/aer_fanout"},
+    "probe": {"ra/probe/gil_wait"},
     "send": {"ra/send/batch"},
     "detect": {"ra/detect/scan"},
     "wal": {"ra/wal/batch", "ra/wal/batch/write", "ra/wal/batch/notify",
@@ -153,6 +162,26 @@ def traced(tmp_path_factory):
                 assert kv_get(api, (names[g], lead.name), f"key{g}") == \
                     b"w" * 64
             read_ns = time.monotonic_ns() - t0
+
+            # puts from several callers at once: one lands while a step
+            # is in flight, and its pass is an ingest-only one
+            def burst(g):
+                for _ in range(BURST[1]):
+                    api.process_command((names[g], lead.name),
+                                        ("put", f"key{g}", b"w" * 64))
+
+            callers = [threading.Thread(target=burst, args=(k % GROUPS,))
+                       for k in range(BURST[0])]
+            for t in callers:
+                t.start()
+            for t in callers:
+                t.join()
+            # a thread that keeps the interpreter lock for its whole turn,
+            # for a few of the probe's periods
+            spin_until = time.monotonic() + 0.25
+            while time.monotonic() < spin_until:
+                sum(range(1000))
+            probe_node = heap._serving[0][1]
             storage[0][1].force_rollover()  # a job for the segment writer
             await_(lambda: storage[0][2].wait_idle(0.1), what="segments")
             time.sleep(0.3)  # a detector pass or two
@@ -161,10 +190,12 @@ def traced(tmp_path_factory):
         after_w, after_c = wave_totals(coords), counter_totals(coords)
         yield {
             "spans": host_spans(obs.xplane_path(trace_dir)),
+            "xplane": obs.xplane_path(trace_dir),
             "wave": {k: (after_w[k][0] - before_w[k][0],
                          after_w[k][1] - before_w[k][1]) for k in after_w},
             "counters": {k: after_c[k] - before_c[k] for k in after_c},
             "read_ns": read_ns,
+            "probe_node": probe_node,
         }
     finally:
         BatchCoordinator._CPU_SAMPLE_SHIFT = shift
@@ -181,9 +212,12 @@ def test_every_span_of_a_thread_role_is_in_the_trace(traced, role):
     got = traced["spans"]
     missing = SPANS[role] - set(got)
     assert not missing, (missing, sorted(got))
+    # (the probe books on the process's first started coordinator, which
+    # is one that an earlier test of this worker leaked, if any did)
+    ours = {traced["probe_node"]} if role == "probe" else set(NODES)
     for name in SPANS[role]:
         nodes = {stats.get("node") for stats in got[name]}
-        assert nodes and nodes <= set(NODES), (name, nodes)
+        assert nodes and nodes <= ours, (name, nodes)
 
 
 def test_step_dispatch_and_batches_carry_their_stats(traced):
@@ -227,13 +261,57 @@ def test_host_pack_and_ingress_sub_phases_stay_inside(traced):
                         ("egress_apply", "host_egress")):
         assert 0 < wave[part][0] <= wave[whole][0]
         assert 0 < wave[part][1] <= wave[whole][1]
-    assert wave["ingest_append"][0] <= WRITES  # every put on its own pass
+    # every put on its own pass (the burst's may share one)
+    assert wave["ingest_append"][0] <= WRITES + BURST[0] * BURST[1]
     # ingest_append is one stretch of a pass and has its span (the
     # histograms were read just outside the profiler session);
     # egress_apply adds up applies that lie apart, and has none
     n = len(traced["spans"]["ra/step/ingress_drain/ingest_append"])
     assert 0.9 * wave["ingest_append"][0] <= n <= wave["ingest_append"][0]
     assert not any("egress_apply" in name for name in traced["spans"])
+
+
+def test_ingress_and_egress_leaves_stay_inside_and_have_their_spans(traced):
+    """ISSUE 35: the leaves of ``ingress_drain`` and of ``host_egress``,
+    one record and one span a pass or step that had the work."""
+    wave, got = traced["wave"], traced["spans"]
+    n_in, ns_in = wave["ingress_drain"]
+    assert abs(wave["ingress_classify"][0] - n_in) <= 3  # (read live)
+    ingress = ("ingress_classify", "step_lock_wait", "ingress_route",
+               "ingest_append", "ingest_fanout")
+    egress = ("egress_follow", "egress_mirror", "egress_apply", "egress_rare")
+    for leaves, whole in ((ingress, "ingress_drain"), (egress, "host_egress")):
+        for leaf in leaves:
+            assert 0 < wave[leaf][0] <= wave[whole][0], (leaf, wave[leaf])
+            assert 0 < wave[leaf][1] <= wave[whole][1], (leaf, wave[leaf])
+        assert sum(wave[leaf][1] for leaf in leaves) <= wave[whole][1]
+    # (the histograms were read just outside the profiler session)
+    for span, leaf in (("ra/step/ingress_drain/route", "ingress_route"),
+                       ("ra/step/ingress_drain/fanout", "ingest_fanout"),
+                       ("ra/egress/host_egress/follow", "egress_follow"),
+                       ("ra/egress/host_egress/mirror", "egress_mirror"),
+                       ("ra/egress/rare", "egress_rare")):
+        assert 0.9 * wave[leaf][0] - 1 <= len(got[span]) <= wave[leaf][0], \
+            (span, len(got[span]), wave[leaf][0])
+    assert all(s["msgs"] >= 1 for s in got["ra/step/ingress_drain/route"])
+    assert all(s["msgs"] >= 1 for s in got["ra/egress/host_egress/follow"])
+    # an ingest-only pass's fan-out is no longer called aer_fanout: that
+    # name is the dispatching pass's, one a step at most
+    assert len(got["ra/step/aer_fanout"]) <= wave["host_pack"][0]
+    # the sender's queue: a sample a batch, in no phase
+    assert wave["send_queue"][0] >= len(got["ra/send/batch"]) > 0
+    # the probe: a span only where the wait passed a millisecond, and it
+    # says how far back it reaches
+    waits = got["ra/probe/gil_wait"]
+    assert all(s["wait_ns"] > 1_000_000 for s in waits)
+    assert len(waits) <= wave["gil_wait"][0] + 1 or \
+        traced["probe_node"] not in NODES
+    # ... and idle_gaps lays it from its due time to the wake
+    _ops, laid, _runs = idle_gaps.read_trace(traced["xplane"])
+    probe = [(hi - lo) for name, _node, lo, hi in laid
+             if name == "ra/probe/gil_wait"]
+    assert sorted(probe) == pytest.approx(
+        sorted(s["wait_ns"] for s in waits), abs=100_000)
 
 
 def test_wal_notify_accounts_one_round_per_batch(traced):
