@@ -810,8 +810,7 @@ def phase_cluster(groups: int, per_group: int, seed: int, workdir: str,
         close_storage(storage)
         leaderboard.clear()
     alive = [t.name for c in coords
-             for t in (c._step_thread, c._egress_thread, c._sender_thread,
-                       c._detector)
+             for t in (c._step_thread, c._sender_thread, c._detector)
              if t is not None and t.is_alive()]
     if alive:
         raise RuntimeError(f"coordinator threads outlived stop(): {alive}")

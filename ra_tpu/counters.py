@@ -207,13 +207,14 @@ COORDINATOR_FIELDS: List[FieldSpec] = [
     ("read_local_bounded", "counter",
      "local queries served under an explicit max_staleness_s bound"),
     ("pipeline_steps", "counter",
-     "device steps dispatched by the started two-stage wave loop; "
-     "pair with pipeline_overlap_ns for how much host work each hid"),
-    ("pipeline_overlap_ns", "counter",
-     "host staging time (ingress drain + pack + dispatch) spent while "
-     "a previous step's device compute / egress realisation was still "
-     "in flight — the overlap the started wave loop creates; 0 "
-     "under step_once (docs/INTERNALS.md §15)"),
+     "tickets dispatched and realised by the started wave loop's turns "
+     "(a ticket that carries only rare messages counts too); 0 under "
+     "step_once (docs/INTERNALS.md §15)"),
+    ("sync_found_ready", "counter",
+     "started turns whose step program had already finished when the "
+     "egress sync began (jax.Array.is_ready, read once before "
+     "np.asarray): beside pipeline_steps, whether what egress_sync "
+     "waits for is the device or the interpreter lock"),
     # -- async command plane (docs/INTERNALS.md §16) --------------------
     ("ingress_ring_msgs", "counter",
      "items drained from the lock-free ingress rings (a bulk fan-out "
@@ -232,17 +233,14 @@ COORDINATOR_FIELDS: List[FieldSpec] = [
      "lane publish (snapshot traffic, TimeoutNow, internal commands: "
      "never shed, never gate-waited — a foreign drainer thread parked "
      "on our gate while we park on its gate would deadlock)"),
-    ("staging_passes", "counter",
-     "ingest-only passes that folded drained work into the staged "
-     "scatter buffers while a device step was still in flight"),
     ("staging_prezeroed", "counter",
-     "mailbox pack buffers pre-zeroed inside the pipeline overlap "
-     "window (the dispatch pass then packs into the spare buffer with "
-     "no take/zero cost on the critical path)"),
+     "full-width mailbox pack buffers pre-zeroed between a dispatch "
+     "and its egress sync, while the device computes (the next "
+     "dispatch then packs into the spare buffer with no take/zero "
+     "cost on the critical path)"),
     ("aer_groups_before_pack", "counter",
      "groups whose AppendEntries left from a dispatching pass ahead of "
-     "its mailbox pack and device hand-off (the fan-out of an "
-     "ingest-only pass is not counted; docs/INTERNALS.md §15)"),
+     "its mailbox pack and device hand-off (docs/INTERNALS.md §15)"),
     # -- the WAL writer's durable hand-off (wal_notify_many: one state-
     # lock round per fsync batch; perf_counter_ns pairs per batch)
     ("wal_notify_batches", "counter",

@@ -290,8 +290,8 @@ WAVE_SUBSET_PHASES = {
                        "samples when the native path is off)",
     "pack_native": "subset of host_pack (GIL-released native mailbox "
                    "scatter; zero samples when the native path is off)",
-    # the three blind phases, split (step thread writes the first four,
-    # the thread that realises tickets the last three)
+    # the three blind phases, split (the step thread writes them all:
+    # the first four as it dispatches, the last three as it realises)
     "step_lock_wait": "subset of ingress_drain (classified -> the state "
                       "lock held)",
     "scatter_dispatch": "subset of host_pack (queued set_roles / "
@@ -300,13 +300,15 @@ WAVE_SUBSET_PHASES = {
     "mailbox_build": "subset of host_pack (pack the step's mailbox)",
     "step_dispatch": "subset of host_pack (the jitted step call: "
                      "argument transfer + dispatch)",
-    "ticket_queue": "subset of device_step (step dispatched -> the "
-                    "ticket popped for realisation: the wait in the "
-                    "pipe queue)",
+    "ticket_queue": "subset of device_step (step dispatched -> its "
+                    "realisation begins: the state lock let go and the "
+                    "spare mailbox zeroed; one sample a stepped ticket)",
     "egress_sync": "subset of device_step (np.asarray of the egress: "
                    "the host's true wait for the device)",
     "egress_lock_wait": "subset of device_step (egress synced -> the "
-                        "state lock held)",
+                        "state lock held again: the started loop syncs "
+                        "outside it, so what it waits for is mostly a "
+                        "WAL writer's wal_notify_many)",
     # what only a busy fleet works: one record per pass over ALL its
     # groups
     "ingest_append": "subset of ingress_drain (log appends + WAL "
@@ -320,8 +322,8 @@ WAVE_SUBSET_PHASES = {
                        "send_msg, monitors, release cursors, ...; one "
                        "clock pair a _realise_effects call, added up; no "
                        "sample on a step that realised none)",
-    # the rest of ingress_drain and host_egress, to the leaf (step
-    # thread the first three, the realising thread the next three)
+    # the rest of ingress_drain and host_egress, to the leaf (the step
+    # thread writes both halves: the drain's, then the realisation's)
     "ingress_classify": "subset of ingress_drain (the burst popped off "
                         "the ingress lanes and classified, outside the "
                         "state lock; recorded where ingress_drain is)",
@@ -329,9 +331,9 @@ WAVE_SUBSET_PHASES = {
                      "message to its handler, and the replies that "
                      "routing produced handed to the sender; no sample "
                      "on a pass without messages)",
-    "ingest_fanout": "subset of ingress_drain (an ingest-only pass's "
-                     "AppendEntries fan-out for the groups it appended "
-                     "to; a dispatching pass's is aer_fanout's)",
+    "ingest_fanout": "subset of ingress_drain (kept for the accounts "
+                     "that add it; reads zero: every pass dispatches, "
+                     "and a dispatching pass's fan-out is aer_fanout's)",
     "egress_follow": "subset of host_egress (the loop over the step's "
                      "consumed messages: vote and AppendEntries replies, "
                      "a follower's log writes and acks; no sample on a "

@@ -1,7 +1,7 @@
 """Lock-free command-plane rings (docs/INTERNALS.md §16).
 
 The ingress side of the async command plane: every producer thread
-(client api calls, peer coordinators' step/egress threads, the WAL
+(client api calls, peer coordinators' step and sender threads, the WAL
 writer, detector timers) publishes into its OWN bounded single-producer/
 single-consumer ring, and the coordinator's step thread drains all
 lanes in one batched pass. No producer ever contends with the step loop
@@ -98,8 +98,8 @@ class SpscRing:
         return n
 
     def __len__(self) -> int:
-        # head BEFORE tail: any thread may ask (the egress and WAL
-        # threads poll ``pending()``), and both indexes only grow with
+        # head BEFORE tail: any thread may ask (the WAL writers and the
+        # sender poll ``pending()``), and both indexes only grow with
         # tail >= head at every instant — so a tail read after the head
         # can only over-count. The other order goes negative when the
         # reader is descheduled between the two loads, and a negative

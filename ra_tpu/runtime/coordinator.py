@@ -561,15 +561,15 @@ class BatchCoordinator:
         # window room; admission-rejected clients park a waiter on it
         # (api.process_command) instead of a fixed 10 ms sleep poll
         self._adm_gate = WaitGate()
-        # idents of the threads that DRAIN the rings (step + egress loop
-        # threads, plus whichever thread is inside a ``step_once``
-        # call): a full-ring publish from one of these must divert to
+        # idents of the threads that DRAIN the rings (the step thread,
+        # or whichever thread is inside a ``step_once`` call): a
+        # full-ring publish from one of these must divert to
         # _internal_q — gate-waiting would deadlock on itself
         self._drainer_idents: set = set()
         # reusable drain scratch (step-thread only)
         self._drain_buf: List = []
-        # must-deliver self-publishes from the coordinator's own step/
-        # egress threads (machine Append/Aux effects): a blocking ring
+        # must-deliver self-publishes from the coordinator's own step
+        # thread (machine Append/Aux effects): a blocking ring
         # publish from the drainer thread would deadlock, so they ride
         # this state-lock-guarded queue into the next drain instead
         self._internal_q: deque = deque()
@@ -608,15 +608,16 @@ class BatchCoordinator:
         self._nat_classify = "classify" in paths and eps.get("classify", False)
         self._drain_codes = bytearray()  # classify sidecar scratch
         self._low_dirty: set = set()  # gids with buffered low-priority cmds
-        # staged device scatters, coalesced ACROSS passes (the host half
-        # of the double-buffered staging): appended runs per gid as
-        # [[lo, hi, term], ...] chronological, durable watermarks as
-        # gid -> max idx. Ingest-only passes fold straight into these;
-        # the next dispatching pass consumes them with zero re-merging.
+        # staged device scatters, coalesced until the next dispatch:
+        # appended runs per gid as [[lo, hi, term], ...] chronological,
+        # durable watermarks as gid -> max idx. The drain and the WAL
+        # writers fold straight into these; the dispatch consumes them
+        # with zero re-merging.
         self._staged_app: Dict[int, List[List[int]]] = {}
         self._staged_written: Dict[int, int] = {}
-        # pre-zeroed full-width mailbox buffer staged in the pipeline
-        # overlap window (dispatch packs into it with no take/zero cost)
+        # pre-zeroed full-width mailbox buffer, zeroed while the device
+        # computes a step (the next dispatch packs into it with no
+        # take/zero cost)
         self._spare_mbox: Optional[np.ndarray] = None
         # prezero only while full-width steps are the live shape (the
         # active-set sub path zeroes tiny buffers — not worth staging)
@@ -668,14 +669,13 @@ class BatchCoordinator:
         self._peer_np = self._mirrors.peers
         self._peer_nodes: set = set()
         self._tick_gids: List[int] = []  # groups whose machine has a tick
-        # mailbox pack buffers, double-buffered (docs/INTERNALS.md §15):
-        # a build hands back the numpy buffer itself and the jitted step
-        # takes it as its argument; the buffer returns to the pool only
-        # after that step's egress sync (np.asarray) proves the program
-        # ran, which also holds where the backend aliases it.
-        # ``step_once`` cycles one buffer; the started loop keeps
-        # one in flight while the next step packs the other — the pool
-        # is bounded by the single-outstanding-ticket cap.
+        # mailbox pack buffers, pooled (docs/INTERNALS.md §15): a build
+        # hands back the numpy buffer itself and the jitted step takes
+        # it as its argument; the buffer returns to the pool only after
+        # that step's egress sync (np.asarray) proves the program ran,
+        # which also holds where the backend aliases it. ``step_once``
+        # cycles one buffer; the started loop at full width two, the
+        # step's and the spare it zeroes while the device computes.
         self._mbox_pool: List[np.ndarray] = []
         # guards self.state (donated buffers!) between the step thread and
         # add_group callers
@@ -691,8 +691,8 @@ class BatchCoordinator:
         self._cpu_turn = 0
         self._cpu_mask = (1 << self._CPU_SAMPLE_SHIFT) - 1
         # a wave's duration on ``self.clock`` (the watchdog's clock),
-        # smoothed over eight: one float, written by the realising
-        # thread and read by the detector's
+        # smoothed over eight: one float, written by the step thread
+        # and read by the detector's
         self._wave_s = 0.0
 
         self.registry = nodes or node_registry()
@@ -725,24 +725,13 @@ class BatchCoordinator:
         self.shard_moves = 0
         self.msgs_processed = 0
 
-        # pipelined wave loop (docs/INTERNALS.md §15): the started run
-        # loop splits each step into host staging (ingress drain + pack
-        # + device dispatch, step thread) and realisation (egress sync
-        # + process + AER fan-out, egress thread), overlapping step
-        # N+1's staging with step N's device compute / egress sync.
-        # ``step_once`` (the tests' driver) is the sequential
-        # two-halves-inline form; callers must not mix it with a
-        # STARTED loop (ticket order would invert).
-        self._pipe_cv = threading.Condition()
-        self._pipe_q: deque = deque()
-        self._pipe_inflight = 0  # tickets dispatched but not finished
+        # the wave loop (docs/INTERNALS.md §15): one turn, ``_turn``,
+        # dispatches a step and realises it on the same thread. The
+        # started loop (``_run``) and ``step_once`` (the tests' driver)
+        # both call it; callers must not mix the two on one coordinator.
+        # No thread realises steps apart from the step thread: the
+        # attribute stays, None, for callers that join what is there
         self._egress_thread: Optional[threading.Thread] = None
-        # work drained by ingest-only passes (a ticket still in
-        # flight): rares park here until the next dispatching pass
-        # picks them up (appended/written runs go straight to the
-        # staged scatter dicts, their canonical form; AER fan-out
-        # never parks — ingest passes ship it immediately)
-        self._pending_rare: List[Tuple] = []
         self._step_thread = threading.Thread(
             target=self._run, name=f"ra-batch-{node_name}", daemon=True
         )
@@ -872,7 +861,7 @@ class BatchCoordinator:
         return True
 
     def _deliver_internal(self, name: str, msg) -> None:
-        """Self-delivery from the step/egress threads (machine effects
+        """Self-delivery from the step thread (machine effects
         re-entering the command queue). Caller holds the state lock;
         the queue is drained by the next _drain_and_dispatch."""
         if type(msg) is Command:
@@ -978,14 +967,10 @@ class BatchCoordinator:
             cnt.incr("wal_notify_hold_ns", time.perf_counter_ns() - t_held)
         for node_name, msgs in route_out.items():
             self._send_batch(node_name, msgs)
-        # wake the step thread only when the staged watermark is
-        # actionable NOW: with a ticket in flight the idle predicate
-        # ignores staged work (an ingest-only pass cannot scatter it),
-        # so an unconditional set here woke the loop for nothing (the
-        # ``step_spurious_wakeups`` counter). When the in-flight
-        # ticket realises, the egress thread's own _have_work check
-        # sees the staged state and wakes the loop (its inflight
-        # decrement precedes that check, so no release is ever missed).
+        # wake the step thread only when the staged watermark is still
+        # there to step: a turn that took it up while this batch sent
+        # its acks leaves nothing, and a set then would wake the loop
+        # for nothing (the ``step_spurious_wakeups`` counter)
         if n_written and self._have_work() and not self._wake.is_set():
             self._wake.set()
 
@@ -1126,11 +1111,7 @@ class BatchCoordinator:
         if self._started:
             self._wake.set()
             self._egress_wake.set()
-            with self._pipe_cv:
-                self._pipe_cv.notify_all()
             self._step_thread.join(timeout=5)
-            if self._egress_thread is not None:
-                self._egress_thread.join(timeout=5)
             if self._sender_thread is not None:
                 self._sender_thread.join(timeout=5)
                 # a publisher that read _egress_on before stop() flipped
@@ -1308,24 +1289,15 @@ class BatchCoordinator:
     # -- the step loop -----------------------------------------------------
 
     def _have_work(self) -> bool:
-        """Is there anything for a step pass to do right now? Fresh
-        ingress (ring items, internal self-deliveries, buffered lows)
-        always counts. Deferred device work — hot gids, staged
-        scatters, queued roles, parked rares — counts only with no
-        ticket in flight: an ingest-only pass cannot act on it, so
-        waiting on it mid-flight would busy-spin until realisation
-        wakes us (its inflight decrement precedes the wake set, so the
-        post-wake re-check sees the dispatchable state)."""
-        if (
+        """Is there anything for a turn to do right now? Fresh ingress
+        (ring items, internal self-deliveries, buffered lows) and
+        deferred device work (hot gids, staged scatters, queued
+        roles)."""
+        return bool(
             self._rings.pending() or self._internal_q
             or self._overflow_q or self._low_dirty
-        ):
-            return True
-        if self._pipe_inflight > 0:
-            return False
-        return bool(
-            self._hot or self._staged_app or self._staged_written
-            or self._pending_roles or self._pending_rare
+            or self._hot or self._staged_app or self._staged_written
+            or self._pending_roles
         )
 
     def _idle_wait(self) -> None:
@@ -1334,7 +1306,7 @@ class BatchCoordinator:
         and the clear must not be lost — publish stores the item BEFORE
         setting the event, so either the re-check sees the item or the
         wait sees the set), then block until a ring publish, WAL
-        notify, egress realisation, timer delivery, or stop wakes us.
+        notify, timer delivery, or stop wakes us.
         No timed polls: an idle coordinator consumes zero CPU, and the
         ``step_spurious_wakeups`` counter proves every wake found
         work."""
@@ -1353,124 +1325,62 @@ class BatchCoordinator:
             self.counters.incr("step_spurious_wakeups")
 
     def _run(self) -> None:
-        """Two-stage pipelined wave loop (docs/INTERNALS.md §15). This
-        thread owns host STAGING: ingress drain, command append + WAL
-        handoff, queued scatters, mailbox pack, async device dispatch.
-        The egress thread owns step REALISATION: egress host sync,
-        egress processing (applies, acks, role changes), rare messages,
-        AER fan-out. Every touch of host group state happens under
-        ``_state_lock`` on either thread; the overlap window is the
-        device compute + egress sync wait, which runs with no lock
-        held — step N+1 stages and dispatches inside it. At most ONE
-        ticket is in flight past the one being realised (the double
-        buffer bound); tickets are realised strictly in dispatch order
-        (egress fields are absolute per-step snapshots — out-of-order
-        realisation would regress role/term mirrors)."""
+        """The started wave loop (docs/INTERNALS.md §15): turn after
+        turn, each dispatching a step and realising it on this thread;
+        an idle wait where a turn finds nothing to step. The sender
+        thread ships what the turns hand it."""
         self._drainer_idents.add(threading.get_ident())
-        self._egress_thread = threading.Thread(
-            target=self._egress_loop, name=f"ra-batch-eg-{self.name}",
-            daemon=True,
-        )
-        self._egress_thread.start()
         self._sender_thread = threading.Thread(
             target=self._sender_loop, name=f"ra-batch-snd-{self.name}",
             daemon=True,
         )
         self._sender_thread.start()
         self._egress_on = True
-        cv = self._pipe_cv
         while self.running:
-            t0 = time.perf_counter_ns()
-            # dispatch only with NO ticket in flight (the double-buffer
-            # bound): while one is being realised, passes are INGEST-
-            # ONLY — ingress keeps draining and commands keep reaching
-            # the logs/WAL (coalescing the next step) without splitting
-            # the wave into many small device steps. _pipe_inflight is
-            # only incremented by this thread, so a lock-free read of 0
-            # is exact (a stale >0 just delays dispatch by one pass).
-            inflight = self._pipe_inflight > 0
-            # classify OUTSIDE the state lock (docs/INTERNALS.md §16):
-            # the WAL writer's wal_notify_many must never wait behind
-            # the O(items) classification of a deep burst
-            pre = self._drain_classify()
-            with self._step_lock:
-                ticket = self._drain_and_dispatch(pre, dispatch=not inflight)
-            if inflight:
-                # host staging done while the previous step's device
-                # compute / egress realisation / WAL handoff were in
-                # flight — the overlap the pipeline exists for
-                dt = time.perf_counter_ns() - t0
-                if dt > 20_000:  # ignore empty probe passes
-                    self.counters.incr("pipeline_overlap_ns", dt)
-                # double-buffered staging: pre-zero the NEXT dispatch's
-                # full-width mailbox inside the overlap window, so the
-                # dispatching pass packs into a ready spare with zero
-                # take/zero cost on its critical path
-                if self._prezero_useful and self._spare_mbox is None:
-                    full = (self._NROWS, self.capacity)
-                    with self._state_lock:
-                        buf = None
-                        pool = self._mbox_pool
-                        for k, b in enumerate(pool):
-                            # (an active-set buffer of the same width
-                            # has the index row more)
-                            if b.shape == full:
-                                buf = b
-                                del pool[k]
-                                break
-                    if buf is None:
-                        buf = np.zeros(full, np.int32)
-                    else:
-                        buf.fill(0)
-                    self._spare_mbox = buf
-                    self.counters.incr("staging_prezeroed")
-            if ticket is not None:
-                self.counters.incr("pipeline_steps")
-                with cv:
-                    self._pipe_inflight += 1
-                    self._pipe_q.append(ticket)
-                    cv.notify_all()
-                continue
-            self._idle_wait()
-        with cv:
-            cv.notify_all()
+            if not self._turn(served=True):
+                self._idle_wait()
         self._egress_wake.set()
 
-    def _egress_loop(self) -> None:
-        self._drainer_idents.add(threading.get_ident())
-        cv = self._pipe_cv
-        while True:
-            with cv:
-                if not self._pipe_q and self.running:
-                    tr = _obs.tracing()
-                    if tr:
-                        sp = _obs.begin("ra/egress/wait", node=self.name)
-                    while not self._pipe_q and self.running:
-                        cv.wait()
-                    if tr:
-                        _obs.end(sp)
-                if not self._pipe_q:
-                    return  # stopped and drained
-                ticket = self._pipe_q.popleft()
-            # device sync OUTSIDE every lock: the step thread stages
-            # and dispatches the next step during this wait
-            self._realise(ticket, self._egress_lock)
-            with cv:
-                self._pipe_inflight -= 1
-                cv.notify_all()
-            # realisation may have produced device work (hot retries,
-            # staged scatters) or unblocked deferred work the idle
-            # predicate ignores while a ticket is in flight: wake the
-            # step thread ONLY when such work exists — an unconditional
-            # wake after a work-free realisation is exactly the
-            # spurious wakeup the idle invariant forbids (caught by
-            # test_command_plane's zero-spurious assertion). The
-            # inflight decrement above precedes the check, so the
-            # deferred state is dispatchable by the time we look; any
-            # work arriving after a negative check sets the wake
-            # itself (publish/stage/notify all do).
-            if self._have_work() and not self._wake.is_set():
-                self._wake.set()
+    def _turn(self, served: bool) -> bool:
+        """One turn of the wave loop, for both drivers: classify the
+        ingress off the state lock; drain, ingest, fan out and dispatch
+        under it; realise the step. ``served`` (the started loop): the
+        lock is let go across the egress sync, so that the WAL writers'
+        ``wal_notify_many`` can take it while the device computes, and
+        realisation takes it again as ``_egress_lock``; ``step_once``
+        holds it throughout. Returns False when there was nothing to
+        step and no rare message to hand over."""
+        pre = self._drain_classify()  # heavy half, off the state lock
+        with self._step_lock:
+            ticket = self._drain_and_dispatch(pre)
+            if ticket is None:
+                return False
+            if not served:
+                self._realise(ticket)
+                return True
+        cnt = self.counters
+        cnt.incr("pipeline_steps")
+        if self._prezero_useful and self._spare_mbox is None:
+            # the next full-width dispatch packs into a buffer zeroed
+            # here, while the device computes (the pool is this
+            # thread's alone: only the turns take and release buffers)
+            full = (self._NROWS, self.capacity)
+            pool = self._mbox_pool
+            buf = None
+            for k, b in enumerate(pool):
+                # (an active-set buffer of the same width has the index
+                # row more)
+                if b.shape == full:
+                    buf = pool.pop(k)
+                    buf.fill(0)
+                    break
+            self._spare_mbox = np.zeros(full, np.int32) if buf is None else buf
+            cnt.incr("staging_prezeroed")
+        eg = ticket.eg_packed
+        if eg is not None and eg.is_ready():
+            cnt.incr("sync_found_ready")
+        self._realise(ticket, self._egress_lock)
+        return True
 
     def _sender_loop(self) -> None:
         """Dedicated egress fan-out thread: per-destination message
@@ -1551,29 +1461,24 @@ class BatchCoordinator:
             self._drainer_idents.discard(token)
 
     def step_once(self) -> bool:
-        """One SEQUENTIAL coordinator iteration: drain ingress, scatter
-        host log updates, run the fused device step, realise egress.
-        Returns False when there was nothing to do. The deterministic
-        driver of the tests — never call it on a started coordinator
-        (realisation order would invert)."""
+        """One turn of the wave loop from the caller's thread, the
+        state lock held throughout: drain ingress, scatter host log
+        updates, run the fused device step, realise egress. Returns
+        False when there was nothing to do. The deterministic driver of
+        the tests — never call it on a started coordinator (two threads
+        would turn one loop)."""
         token = self._coop_drainer()
         try:
-            pre = self._drain_classify()  # heavy half, off the state lock
-            with self._step_lock:
-                ticket = self._drain_and_dispatch(pre)
-                if ticket is None:
-                    return False
-                self._realise(ticket)
-                return True
+            return self._turn(served=False)
         finally:
             self._coop_done(token)
 
     def _realise(self, ticket, lock=None) -> None:
         """Realise one dispatched step: sync its egress off the device
         (``np.asarray``: the host's one true wait for the device), then
-        finish it under the state lock. The egress thread passes its
-        lock and syncs outside it; ``step_once`` holds the lock
-        already."""
+        finish it under the state lock. The started loop passes its
+        ``_egress_lock`` and syncs outside it; ``step_once`` holds the
+        lock already."""
         tr = _obs.tracing()
         t_pop = time.perf_counter_ns()
         eg_np = None
@@ -1594,8 +1499,9 @@ class BatchCoordinator:
         """One dispatched-but-unrealised step: the device egress handle
         plus everything realisation needs (who was consumed, the
         position->gid map, rares, the staging timestamps, and the wall
-        and thread-CPU ns of the pass's own AER fan-out, which the
-        realising thread books so that each account keeps one writer).
+        and thread-CPU ns of the pass's own AER fan-out, which
+        realisation books beside its own, so that each account is
+        written in one place).
         ``cpu``: this turn is one whose thread-CPU time is read."""
 
         __slots__ = ("eg_packed", "consumed", "act", "aer_dirty", "rare",
@@ -1807,7 +1713,7 @@ class BatchCoordinator:
         profiler session takes the spans; ``t_held``: when the caller
         got the state lock (``perf_counter_ns``), where the sub-phase
         ``ingress_route`` starts."""
-        # fold the step/egress threads' own must-deliver self-publishes
+        # fold the step thread's own must-deliver self-publishes
         # (machine Append/Aux effects realized under the state lock —
         # including by the prev-ticket finish that just ran): they are
         # few, and folding here keeps their same-pass ordering
@@ -1840,23 +1746,14 @@ class BatchCoordinator:
             # counted in n_items (pass-has-work accounting) but NOT in
             # ingress_ring_msgs — these never touched a ring
             n_items += n_internal
-        # seed rares / AER-dirty gids parked by earlier ingest-only
-        # passes (started loop).
-        # ALWAYS detach (aliasing trap): _route_one appends into it,
-        # so keeping an alias of the live (empty) container would
-        # re-seed — and re-process — this pass's rares on the next pass
-        rare: List[Tuple[GroupHost, Any, Optional[ServerId]]] = (
-            self._pending_rare
-        )
-        self._pending_rare = []
+        rare: List[Tuple[GroupHost, Any, Optional[ServerId]]] = []
         aer_dirty: set = set()
         # appended runs: gid -> [[lo, hi, term], ...] (contiguous,
         # same-term); written: gid -> max durable idx. Run-based so the
         # device scatter is one row per touched GROUP, not per entry.
-        # These ARE the staged double-buffer halves: ingest-only passes
-        # leave their folds in place and the next dispatching pass
-        # consumes them with zero re-merging (the WAL writer thread
-        # stages durable watermarks into _staged_written directly).
+        # These ARE the staged scatter dicts: the dispatch consumes them
+        # with zero re-merging (the WAL writer thread stages durable
+        # watermarks into _staged_written directly, between turns).
         appended = self._staged_app
         written = self._staged_written
         # replies produced during routing (deferred durable acks): one
@@ -1916,7 +1813,7 @@ class BatchCoordinator:
         return n_items, rare, aer_dirty
 
     def _drain_and_dispatch(
-        self, pre, dispatch: bool = True
+        self, pre
     ) -> Optional["BatchCoordinator._StepTicket"]:
         # caller holds the state lock (taken through ``_step_lock``);
         # ``pre`` is _drain_classify()'s output taken BEFORE the lock
@@ -1938,39 +1835,6 @@ class BatchCoordinator:
             _obs.end(sp)
         appended = self._staged_app
         written = self._staged_written
-        if not dispatch:
-            # ingest-only pass (a ticket is still being realised): the
-            # drained work is already folded into the staged scatter
-            # dicts the next dispatching pass consumes, and commands
-            # have already reached the logs and the WAL queue — the
-            # coalescing the pipeline is for happens here.
-            if rare:
-                self._pending_rare = rare
-            fan_ns = 0
-            if aer_dirty:
-                # replication fan-out never waits for the next dispatch:
-                # fresh appends ship while the in-flight step realises.
-                # Inside this pass's ingress_drain: sub-phase
-                # ingest_fanout (aer_fanout keeps its one writer, the
-                # realising thread)
-                if tr:
-                    sp = _obs.begin("ra/step/ingress_drain/fanout", node=node)
-                _t_fan = time.perf_counter_ns()
-                self._send_aers(aer_dirty)
-                fan_ns = (time.perf_counter_ns() - _t_fan) or 1
-                if tr:
-                    _obs.end(sp)
-            if n_items:
-                cnt.incr("staging_passes")
-                if cpu:
-                    cnt.incr("cpu_ns_ingress_drain",
-                             (time.thread_time_ns() - _c_in) << shift)
-                wh["ingress_drain"].record(time.perf_counter_ns() - _t_in)
-                wh["ingress_classify"].record(_t_cls - _t_in)
-                wh["step_lock_wait"].record(t_held - _t_cls)
-                if fan_ns:
-                    wh["ingest_fanout"].record(fan_ns)
-            return None
         if not (
             n_items or self._hot or rare or appended or written
             or self._pending_roles
@@ -1981,13 +1845,13 @@ class BatchCoordinator:
         _t_ing = _t_drain = time.perf_counter_ns()
         # Host work first: drain-produced AERs (fresh appends, ack-driven
         # next_index moves) leave BEFORE the pack and the device
-        # hand-off, as an ingest-only pass sends them. _send_aers reads
-        # logs and host mirrors only, so the order is free, and the
-        # followers' round starts a whole host_pack earlier. Its wall
-        # and CPU time are neither ingress_drain's nor host_pack's
-        # (which starts after it): they ride the ticket into aer_fanout,
-        # which the realising thread alone writes. Egress-produced AERs
-        # (commit advances) ride the ticket too.
+        # hand-off. _send_aers reads logs and host mirrors only, so the
+        # order is free, and the followers' round starts a whole
+        # host_pack earlier. Its wall and CPU time are neither
+        # ingress_drain's nor host_pack's (which starts after it): they
+        # ride the ticket into aer_fanout, which realisation alone
+        # writes. Egress-produced AERs (commit advances) ride the ticket
+        # too.
         aer0_ns = aer0_cpu_ns = None
         if aer_dirty:
             if tr:
@@ -2069,15 +1933,15 @@ class BatchCoordinator:
         if tr:
             _obs.end(sp_pack)
         # full-width steps are the shape worth pre-zeroing a spare
-        # mailbox for during the next overlap window (sub-batch buffers
-        # are tiny; zeroing them inline is already free)
+        # mailbox for while the device computes (sub-batch buffers are
+        # tiny; zeroing them inline is already free)
         self._prezero_useful = stepped and act is None
         if cpu:
             _c_pack = time.thread_time_ns()
         _t_pack = time.perf_counter_ns()
         # dispatch is ASYNC: eg_packed is an in-flight device value; the
-        # ticket's realisation half syncs it (np.asarray) and processes
-        # the egress. The sequential step_once realises inline.
+        # turn's realisation half syncs it (np.asarray) and processes
+        # the egress.
         wh["ingress_drain"].record(_t_ing - _t_in)
         wh["ingress_classify"].record(_t_cls - _t_in)
         wh["step_lock_wait"].record(t_held - _t_cls)
@@ -2111,9 +1975,9 @@ class BatchCoordinator:
             self._pending_roles = []
             self.state = C.set_roles(self.state, gids, roles)
 
-        # consume the staged halves: detach so concurrent stagers (the
-        # WAL writer thread, the egress thread's rare paths) start a
-        # fresh buffer for the NEXT dispatch
+        # consume the staged halves: detach so the WAL writer threads,
+        # staging while the device computes, start a fresh buffer for
+        # the NEXT dispatch
         self._staged_app = {}
         self._staged_written = {}
 
@@ -2977,8 +2841,8 @@ class BatchCoordinator:
             shape = (self._NROWS, self.capacity)
             spare = self._spare_mbox
             if spare is not None:
-                # double-buffered staging: the spare was pre-zeroed in
-                # the pipeline overlap window — no take/zero cost here
+                # the spare was pre-zeroed while the last step computed
+                # — no take/zero cost here
                 self._spare_mbox = None
                 return spare
         else:
@@ -3708,7 +3572,7 @@ class BatchCoordinator:
 
     def _realise_effects(self, g: GroupHost, effs, is_leader: bool = True) -> None:
         """Machine effects, in apply order, on the thread that applied
-        (the egress thread; the step thread for ticks and aux), under
+        (the step thread's realisation; ticks and aux too), under
         the state lock. Log effects (release_cursor / checkpoint) are
         realised on EVERY replica (followers must truncate too); the
         rest (send_msg, monitor, demonitor, mod_call, timer, log read,

@@ -63,10 +63,10 @@ def main() -> int:
     # their own WALs (chip_smoke's storage, as the benchmark's cells
     # wire it), ``--groups`` groups led by pipe0, ``--cmds`` stamped
     # commands a group and wave. Every wave phase and all five commit
-    # stages must fire, and the started loop must PROVE overlap —
-    # staging while the previous step was still in flight — via the
-    # counter the pipeline exists for. Kept alive until the scrape
-    # below so the families are present in the exposition.
+    # stages must fire, and the started loop's turns must be counted
+    # (``pipeline_steps``, with ``sync_found_ready`` never above it).
+    # Kept alive until the scrape below so the families are present in
+    # the exposition.
     pipe_dir = tempfile.mkdtemp(prefix="obs_smoke_pipe_")
     pipe_reg = NodeRegistry()
     pipe_coords = [
@@ -99,13 +99,7 @@ def main() -> int:
                            for n in names), "leaders")
     t0 = time.perf_counter()
     waves = 0
-    # (an ingest-only pass that appended sends its AppendEntries at once:
-    # sub-phase ingest_fanout, which only the overlap works)
-    fanout = obs.histograms().fetch(("wave", lead.name, "ingest_fanout"))
-    while waves < args.cmds or (
-        (lead.counters.get("pipeline_overlap_ns") <= 0 or fanout.n == 0)
-        and waves < 200
-    ):
+    while waves < args.cmds:
         waves += 1
         lead.deliver_many([
             ((n, lead.name),
@@ -119,8 +113,9 @@ def main() -> int:
             break
     print(f"obs_smoke: {waves} waves x {args.groups} groups served in "
           f"{time.perf_counter() - t0:.2f} s", file=sys.stderr)
-    if lead.counters.get("pipeline_overlap_ns") <= 0:
-        errors.append("started loop recorded no staging overlap")
+    if lead.counters.get("sync_found_ready") > lead.counters.get(
+            "pipeline_steps"):
+        errors.append("more turns found their program ready than ran")
 
     # The adaptive group-commit flush_wait family must EXIST (a short
     # smoke burst may legitimately never clear the coalescing gate, so
@@ -138,6 +133,8 @@ def main() -> int:
                               f"{k}=0 (native path never engaged)")
     # effects_realise must exist; this burst's machine returns no
     # effect, so its count is 0 (tests/test_fifo_deployment.py works it).
+    # ingest_fanout must exist and reads 0: every pass that fans out
+    # dispatches, and a dispatching pass's fan-out is aer_fanout's.
     # Every other sub-phase must be NONZERO here, send_queue and gil_wait
     # among them: those two may be empty only on a cluster that was never
     # started (no sender thread, no probe), and this one is started, with
@@ -146,9 +143,10 @@ def main() -> int:
     required_pipe = (
         [rf"ra_wave_pipe0_{ph}_seconds_count (\d+)"
          for ph, _ in obs.WAVE_PHASES
-         if ph != "effects_realise"
+         if ph not in ("effects_realise", "ingest_fanout")
          and (rt_loaded or ph not in _native_phases)]
-        + [r"ra_wave_pipe0_effects_realise_seconds_count \d+"]
+        + [rf"ra_wave_pipe0_{ph}_seconds_count \d+"
+           for ph in ("effects_realise", "ingest_fanout")]
         + [rf"ra_commit_pipe0_{st}_seconds_count (\d+)"
            for st, _ in obs.COMMIT_STAGES]
         + [r"ra_wal_\w+_fsync_seconds_count (\d+)",
@@ -286,10 +284,10 @@ def main() -> int:
             r"# TYPE ra_commit_rate gauge",
             r"# TYPE ra_commands_rejected counter",
             r"ra_lane_wedges",  # presence only: 0 is the healthy value
-            # pipelined wave loop: the pipe cluster above must show
-            # overlap > 0 (the (\d+)-zero check enforces nonzero)
-            r"ra_pipeline_overlap_ns\{[^}]*pipe0[^}]*\} (\d+)",
+            # the started wave loop: the pipe cluster above must have
+            # turned (the (\d+)-zero check enforces nonzero)
             r"ra_pipeline_steps\{[^}]*pipe0[^}]*\} (\d+)",
+            r"# TYPE ra_sync_found_ready counter",
             # adaptive group-commit gauge family (wal counters register
             # per-scope; the pipe cluster's WALs are alive at the scrape)
             r"# TYPE ra_group_commit_delay_us gauge",
@@ -324,7 +322,6 @@ def main() -> int:
             r"ra_egress_thread_batches\{[^}]*obs0[^}]*\} (\d+)",
             r"ra_egress_thread_msgs\{[^}]*obs0[^}]*\} (\d+)",
             r"# TYPE ra_egress_thread_ring_full counter",
-            r"# TYPE ra_staging_passes counter",
             r"# TYPE ra_staging_prezeroed counter",
             # health plane families (docs/INTERNALS.md §14)
             r"ra_health_scans\{[^}]*obs0[^}]*\} (\d+)",

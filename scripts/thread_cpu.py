@@ -39,7 +39,6 @@ OTHER = "not Python's"
 # shard or the caller ("fifo-gen-3", "ycsb-17") it was started for
 ROLES = (
     (re.compile(r"^ra-batch-det-"), "detector"),
-    (re.compile(r"^ra-batch-eg-"), "egress"),
     (re.compile(r"^ra-batch-snd-"), "sender"),
     (re.compile(r"^ra-batch-"), "step"),
     (re.compile(r"^ra-tcp-out-"), "wire writer"),

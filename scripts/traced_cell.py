@@ -35,7 +35,9 @@ before the result line:
   ``obs.WAVE_PHASES`` over the traced window, the coordinators added:
   samples, seconds, and ``mean_ms`` (the deltas the per-layer readers
   read; PERF.md section 5's leaf sums come from it), with the counters
-  that turn a leaf's total into a time a message.
+  that turn a leaf's total into a time a message, and the turns whose
+  program had finished before their egress sync (``sync_found_ready``,
+  beside ``pipeline_steps``).
 
 It runs beside the benchmark and is no code a cell runs."""
 
@@ -103,7 +105,8 @@ def events_inside(xplane_path: str, span_name: str) -> list:
 
 
 COUNTERS = ("routed_msgs", "follower_aers", "follower_entries",
-            "rares_handled", "egress_thread_batches", "egress_thread_msgs")
+            "rares_handled", "egress_thread_batches", "egress_thread_msgs",
+            "pipeline_steps", "sync_found_ready")
 
 
 def wave_account(run) -> dict:

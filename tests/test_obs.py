@@ -252,9 +252,9 @@ def test_system_overview_live_batch_cluster(three_coords):
     # sub-phase a leader's step, realisation and apply of four client
     # commands must pass through. The native sub-phases have no sample
     # while the native path is off, effects_realise none while no
-    # machine returns an effect, ingest_fanout none unless a command
-    # arrived while a step was in flight, gil_wait none on a node that
-    # is not the process's first started one
+    # machine returns an effect, ingest_fanout none (every pass that
+    # fans out dispatches), gil_wait none on a node that is not the
+    # process's first started one
     # (tests/test_wave_account.py holds those two);
     # system_overview leaves an empty histogram out.
     wave = {k[2]: v for k, v in ov["histograms"].items()

@@ -6,9 +6,10 @@ must poison and recover on both mailbox shapes; the native
 serialize+write+fsync batch path must be byte-identical with the pure-
 Python fallback (and degrade to it when the .so is missing); the
 adaptive group-commit policy must coalesce bursts but never delay an
-idle write; the started loop must commit while proving overlap; and a
-coordinator offers two ways to turn it, the started loop and
-``step_once``, with no argument that picks another.
+idle write; the started loop must commit with each ticket realised by
+the thread that dispatched it; and a coordinator offers two ways to
+turn it, the started loop and ``step_once``, with no argument that
+picks another.
 """
 
 import os
@@ -415,9 +416,15 @@ def _drive(coords, step, cond, timeout=20.0):
     raise AssertionError("drive timeout")
 
 
-def test_threaded_pipelined_loop_commits_and_overlaps():
-    """The started two-stage loop (step thread + egress thread) commits
-    commands and records staging overlap."""
+REALISE_PHASES = ("ticket_queue", "egress_sync", "egress_lock_wait",
+                  "device_step")
+
+
+def test_started_loop_realises_each_ticket_on_the_dispatching_thread():
+    """The started loop commits commands with one thread turning the
+    wave: every ticket is realised by the thread that dispatched it,
+    before that thread dispatches again, and every stepped ticket adds
+    one sample to each sub-phase of ``device_step`` and to the phase."""
     coords = [
         BatchCoordinator(f"tp{i}", capacity=8, num_peers=3,
                          election_timeout_s=0.15,
@@ -425,10 +432,33 @@ def test_threaded_pipelined_loop_commits_and_overlaps():
         for i in range(3)
     ]
     ids = [("tg", f"tp{i}") for i in range(3)]
+    events = {c.name: [] for c in coords}
+    # (a histogram outlives its coordinator: count from here)
+    base = {c.name: {ph: c._wave_h[ph].n for ph in REALISE_PHASES}
+            for c in coords}
+
+    def watch(c):
+        dispatch, realise = c._drain_and_dispatch, c._realise
+        log = events[c.name]
+
+        def drain_and_dispatch(pre):
+            ticket = dispatch(pre)
+            if ticket is not None:
+                log.append(("d", threading.get_ident(), ticket))
+            return ticket
+
+        def realise_(ticket, lock=None):
+            log.append(("r", threading.get_ident(), ticket))
+            realise(ticket, lock)
+
+        c._drain_and_dispatch = drain_and_dispatch
+        c._realise = realise_
+
     try:
         for c in coords:
             c.add_group("tg", "tpcl", ids,
                         SimpleMachine(lambda cm, s: s + cm, 0))
+            watch(c)
             c.start()
         coords[0].deliver(ids[0], ElectionTimeout(), None)
         await_(lambda: any(c.by_name["tg"].role == C.R_LEADER
@@ -440,13 +470,75 @@ def test_threaded_pipelined_loop_commits_and_overlaps():
         assert total == 50
         await_(lambda: all(c.by_name["tg"].machine_state == 50
                            for c in coords), what="replicas converge")
-        assert sum(c.counters.get("pipeline_steps") for c in coords) > 0
-        assert sum(
-            c.counters.get("pipeline_overlap_ns") for c in coords
-        ) > 0
+        for c in coords:
+            # the step thread, the sender and the detector: no other
+            # thread of the coordinator's own
+            assert c._egress_thread is None
+            mine = sorted(t.name for t in threading.enumerate()
+                          if t.name.endswith(c.name)
+                          and t.name.startswith("ra-batch"))
+            assert mine == sorted([f"ra-batch-{c.name}",
+                                   f"ra-batch-det-{c.name}",
+                                   f"ra-batch-snd-{c.name}"]), mine
     finally:
         for c in coords:
             c.stop()
+    for c in coords:
+        log = events[c.name]
+        assert log and len(log) % 2 == 0
+        step_ident = c._step_thread.ident
+        stepped = 0
+        for (d, d_ident, d_ticket), (r, r_ident, r_ticket) in zip(
+                log[::2], log[1::2]):
+            assert (d, r) == ("d", "r")
+            assert r_ticket is d_ticket
+            assert d_ident == r_ident == step_ident
+            stepped += d_ticket.eg_packed is not None
+        assert stepped > 0
+        assert c.counters.get("pipeline_steps") == len(log) // 2
+        for ph in REALISE_PHASES:
+            assert c._wave_h[ph].n - base[c.name][ph] == stepped, \
+                (c.name, ph)
+        assert 0 <= c.counters.get("sync_found_ready") <= stepped
+
+
+def test_a_turn_whose_program_finished_before_its_sync_is_counted_once():
+    """``sync_found_ready``: the started turn reads the program's
+    readiness once, before its egress sync. A program held to its end
+    before the sync is counted, once a stepped ticket; ``step_once``
+    books neither it nor ``pipeline_steps``."""
+    c = BatchCoordinator("sfr0", capacity=4, num_peers=1)
+    try:
+        c.add_group("sg", "sfrcl", [("sg", "sfr0")],
+                    SimpleMachine(lambda cm, s: s + cm, 0))
+        c.deliver(("sg", "sfr0"), ElectionTimeout(), None)
+        while c.step_once():
+            pass
+        assert c.by_name["sg"].role == C.R_LEADER
+        assert c.counters.get("pipeline_steps") == 0
+        assert c.counters.get("sync_found_ready") == 0
+        dispatch = c._drain_and_dispatch
+        stepped = []
+
+        def dispatch_and_wait(pre):
+            ticket = dispatch(pre)
+            if ticket is not None and ticket.eg_packed is not None:
+                ticket.eg_packed.block_until_ready()
+                stepped.append(ticket)
+            return ticket
+
+        c._drain_and_dispatch = dispatch_and_wait
+        for k in range(3):
+            c.deliver(("sg", "sfr0"),
+                      Command(kind=USR, data=1, reply_mode="noreply"), None)
+            while c._turn(served=True):
+                pass
+        assert c.by_name["sg"].machine_state == 3
+        assert len(stepped) >= 3
+        assert c.counters.get("sync_found_ready") == len(stepped)
+        assert c.counters.get("pipeline_steps") >= len(stepped)
+    finally:
+        c.stop()
 
 
 def test_constructor_offers_no_path_selector():
@@ -680,11 +772,10 @@ def test_stale_election_timeout_is_dropped():
 
 
 def test_rare_messages_processed_exactly_once():
-    """A dispatching pass must DETACH _pending_rare before routing into
-    it: keeping an alias of the live (empty) list re-seeds — and
-    re-processes — the pass's own rares one pass later. Regression: a
-    single explicit ElectionTimeout used to run TWO elections (term 2,
-    a second pre-vote round piled onto a resolved one)."""
+    """A turn's rare messages are handled by that turn and never seen
+    again. Regression: a single explicit ElectionTimeout used to run TWO
+    elections (term 2, a second pre-vote round piled onto a resolved
+    one) when a pass kept an alias of the list it had routed into."""
     c = BatchCoordinator("ro0", capacity=4, num_peers=1, idle_sleep_s=0)
     try:
         c.add_group("rg", "rocl", [("rg", "ro0")],
@@ -692,7 +783,6 @@ def test_rare_messages_processed_exactly_once():
         g = c.by_name["rg"]
         c.deliver(("rg", "ro0"), ElectionTimeout(), None)
         c.step_once()
-        assert not c._pending_rare, "dispatching pass left its rares parked"
         for _ in range(10):
             c.step_once()
         assert g.role == C.R_LEADER

@@ -44,14 +44,13 @@ BURST = (4, 10)  # callers at once, puts each: a put lands on a busy loop
 SPANS = {
     "step": {"ra/step/classify", "ra/step/lock_wait", "ra/step/ingress_drain",
              "ra/step/ingress_drain/ingest_append",
-             "ra/step/ingress_drain/route", "ra/step/ingress_drain/fanout",
-             "ra/step/host_pack",
+             "ra/step/ingress_drain/route", "ra/step/host_pack",
              "ra/step/host_pack/scatter_dispatch",
              "ra/step/host_pack/mailbox_build",
              "ra/step/host_pack/step_dispatch",
              "ra/step/host_pack/step_dispatch/release", "ra/step/aer_fanout",
              "ra/step/idle"},
-    "egress": {"ra/egress/wait", "ra/egress/sync", "ra/egress/lock_wait",
+    "egress": {"ra/egress/sync", "ra/egress/lock_wait",
                "ra/egress/host_egress", "ra/egress/host_egress/follow",
                "ra/egress/host_egress/mirror", "ra/egress/rare",
                "ra/egress/aer_fanout"},
@@ -164,7 +163,7 @@ def traced(tmp_path_factory):
             read_ns = time.monotonic_ns() - t0
 
             # puts from several callers at once: one lands while a step
-            # is in flight, and its pass is an ingest-only one
+            # is realised, and waits in its lane for the next turn
             def burst(g):
                 for _ in range(BURST[1]):
                     api.process_command((names[g], lead.name),
@@ -278,16 +277,18 @@ def test_ingress_and_egress_leaves_stay_inside_and_have_their_spans(traced):
     n_in, ns_in = wave["ingress_drain"]
     assert abs(wave["ingress_classify"][0] - n_in) <= 3  # (read live)
     ingress = ("ingress_classify", "step_lock_wait", "ingress_route",
-               "ingest_append", "ingest_fanout")
+               "ingest_append")
     egress = ("egress_follow", "egress_mirror", "egress_apply", "egress_rare")
     for leaves, whole in ((ingress, "ingress_drain"), (egress, "host_egress")):
         for leaf in leaves:
             assert 0 < wave[leaf][0] <= wave[whole][0], (leaf, wave[leaf])
             assert 0 < wave[leaf][1] <= wave[whole][1], (leaf, wave[leaf])
         assert sum(wave[leaf][1] for leaf in leaves) <= wave[whole][1]
+    # every pass that fans out dispatches: its fan-out is aer_fanout's
+    assert wave["ingest_fanout"] == (0, 0)
+    assert "ra/step/ingress_drain/fanout" not in got
     # (the histograms were read just outside the profiler session)
     for span, leaf in (("ra/step/ingress_drain/route", "ingress_route"),
-                       ("ra/step/ingress_drain/fanout", "ingest_fanout"),
                        ("ra/egress/host_egress/follow", "egress_follow"),
                        ("ra/egress/host_egress/mirror", "egress_mirror"),
                        ("ra/egress/rare", "egress_rare")):
@@ -295,8 +296,7 @@ def test_ingress_and_egress_leaves_stay_inside_and_have_their_spans(traced):
             (span, len(got[span]), wave[leaf][0])
     assert all(s["msgs"] >= 1 for s in got["ra/step/ingress_drain/route"])
     assert all(s["msgs"] >= 1 for s in got["ra/egress/host_egress/follow"])
-    # an ingest-only pass's fan-out is no longer called aer_fanout: that
-    # name is the dispatching pass's, one a step at most
+    # aer_fanout's span is the dispatching pass's, one a step at most
     assert len(got["ra/step/aer_fanout"]) <= wave["host_pack"][0]
     # the sender's queue: a sample a batch, in no phase
     assert wave["send_queue"][0] >= len(got["ra/send/batch"]) > 0
@@ -528,8 +528,8 @@ GAP_CASES = {
         [("ra/step/idle", "n1", 5, 45)],
         {("ra/step/idle", "n1"): 30}, 30, 0),
     "a gap cut by two spans": (
-        [("ra/step/host_pack", "n1", 0, 22), ("ra/egress/wait", "n2", 30, 60)],
-        {("ra/step/host_pack", "n1"): 12, ("ra/egress/wait", "n2"): 10},
+        [("ra/step/host_pack", "n1", 0, 22), ("ra/egress/sync", "n2", 30, 60)],
+        {("ra/step/host_pack", "n1"): 12, ("ra/egress/sync", "n2"): 10},
         22, 8),
     "a gap no span covers": (
         [("ra/step/idle", "n1", 0, 10), ("ra/step/idle", "n1", 40, 50)],

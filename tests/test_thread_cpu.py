@@ -27,7 +27,7 @@ pytestmark = pytest.mark.skipif(
 
 @pytest.mark.parametrize("name,role", [
     ("ra-batch-det-bench1", "detector"),
-    ("ra-batch-eg-bench0", "egress"),
+    ("ra-segment-writer", "segment writer"),
     ("ra-batch-snd-bench2", "sender"),
     ("ra-batch-bench2", "step"),
     ("ra-wal", "wal writer"),
@@ -95,7 +95,7 @@ def test_wrapped_around_a_cell_it_prints_its_line_and_changes_nothing(
         monkeypatch, capsys):
     """The wrapper's ``main`` with the chip's gate and the cell's size
     taken down to the CPU's: the line holds the detector, the step and
-    the egress threads, and the result line follows it."""
+    the sender threads, and the result line follows it."""
     import jax
     from benchmark import harness
     from benchmark import run as R
@@ -118,5 +118,6 @@ def test_wrapped_around_a_cell_it_prints_its_line_and_changes_nothing(
     table = lines[-2]
     assert table["line"] == "thread_cpu" and 1.5 < table["window_s"] < 8.0
     rows = {r["role"]: r for r in table["roles"]}
-    assert rows["step"]["threads"] == rows["egress"]["threads"] == 3
+    assert rows["step"]["threads"] == rows["sender"]["threads"] == 3
+    assert "egress" not in rows  # the step thread realises its steps
     assert 0 < table["process_cores"] < os.cpu_count()

@@ -122,4 +122,7 @@ def test_wrapped_around_a_cell_it_prints_both_tables_before_the_trace_goes(
         "egress_follow", "egress_mirror", "egress_apply", "egress_rare"))
     assert 0.8 * ph["host_egress"]["s"] <= leaves <= ph["host_egress"]["s"]
     assert account["counters"]["routed_msgs"] > 0
+    steps = account["counters"]["pipeline_steps"]
+    assert 0 <= account["counters"]["sync_found_ready"] <= steps
+    assert steps > 0
 

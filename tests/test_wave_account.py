@@ -4,8 +4,8 @@ the sender's queue its wait, and the interpreter lock's wait is sampled
 by the process's own probe thread (``ra_tpu/runtime/gil_probe.py``).
 
 One histogram has one writer: the step thread writes the leaves of
-``ingress_drain``, the realising thread those of ``host_egress`` and
-``aer_fanout``, the sender ``send_queue``, the probe ``gil_wait``."""
+``ingress_drain`` and, as it realises its step, those of ``host_egress``
+and ``aer_fanout``; the sender ``send_queue``, the probe ``gil_wait``."""
 
 import threading
 import time
@@ -162,7 +162,7 @@ def test_every_leaf_has_samples_and_the_leaves_add_up(coop):
     after = totals(coords, phases)
     got = {ph: (after[ph][0] - before[ph][0], after[ph][1] - before[ph][1])
            for ph in phases}
-    # (an ingest-only pass is the started loop's: the test below)
+    # (every pass that fans out dispatches: ingest_fanout reads zero)
     for ph in set(phases) - {"ingest_fanout"}:
         assert got[ph][0] > 0 and got[ph][1] > 0, (ph, got)
     assert got["ingest_fanout"] == (0, 0)
@@ -212,32 +212,52 @@ def test_a_rare_only_ticket_is_host_egress_too(coop):
     drive(q.done, "the query's reply")
 
 
-def test_an_ingest_only_pass_books_ingest_fanout_not_aer_fanout(coop):
-    """What the started loop does while a ticket is in flight: the pass
-    appends, sends its AppendEntries at once and dispatches nothing. The
-    fan-out's time is inside that pass's ``ingress_drain`` and is booked
-    as ``ingest_fanout``; ``aer_fanout`` keeps its one writer, the
-    thread that realises tickets."""
-    coords, ids, step, drive = coop
+def test_commands_arriving_while_a_step_realises_are_applied_everywhere(
+        no_leaked_serving):
+    """The started loop realises a step on the thread that dispatched
+    it: commands that arrive meanwhile wait in the lanes for its next
+    turn, and reach every replica. No pass fans out but a dispatching
+    one, so ``ingest_fanout`` stays registered and reads zero."""
+    made = no_leaked_serving
+    coords = [BatchCoordinator(f"ri{i}", capacity=8, num_peers=3,
+                               detector_poll_s=0.05, tick_interval_s=0.2)
+              for i in range(3)]
+    made.extend(coords)
+    ids = [("rg", c.name) for c in coords]
+    for c in coords:
+        c.add_group("rg", "ricl", ids, SimpleMachine(lambda cm, s: s + cm, 0))
+        c.start()
     lead = coords[0]
-    phases = ("ingest_fanout", "aer_fanout", "ingress_drain", "ingest_append")
-    before = totals([lead], phases)
-    lead.deliver(ids[0], Command(kind=USR, data=5, reply_mode="noreply"),
+    lead.deliver(ids[0], ElectionTimeout(), None)
+    await_(lambda: lead.by_name["rg"].role == C.R_LEADER, what="leader")
+    assert "ingest_fanout" in obs.WAVE_SUBSET_PHASES
+    fan0 = [(wave(c, "ingest_fanout").n, wave(c, "ingest_fanout").total)
+            for c in coords]
+    n = 5
+    arrived = []
+    realise = lead._realise
+
+    def realise_while_a_command_arrives(ticket, lock=None):
+        if ticket.eg_packed is not None and len(arrived) < n:
+            sent = threading.Event()
+
+            def send():
+                lead.deliver(ids[0], Command(kind=USR, data=1,
+                                             reply_mode="noreply"), None)
+                sent.set()
+
+            threading.Thread(target=send).start()
+            arrived.append(sent.wait(10))
+        realise(ticket, lock)
+
+    lead._realise = realise_while_a_command_arrives
+    lead.deliver(ids[0], Command(kind=USR, data=1, reply_mode="noreply"),
                  None)
-    pre = lead._drain_classify()
-    with lead._step_lock:
-        assert lead._drain_and_dispatch(pre, dispatch=False) is None
-    after = totals([lead], phases)
-    got = {ph: (after[ph][0] - before[ph][0], after[ph][1] - before[ph][1])
-           for ph in phases}
-    assert got["aer_fanout"] == (0, 0)
-    assert got["ingest_fanout"][0] == got["ingress_drain"][0] == 1
-    assert 0 < got["ingest_fanout"][1] <= got["ingress_drain"][1]
-    assert got["ingest_append"][0] == 1
-    # the AppendEntries did leave: the followers write and the group
-    # commits with no further fan-out for that entry's append
-    drive(lambda: all(c.by_name["cg"].machine_state == 5 for c in coords),
-          "the command applied everywhere")
+    await_(lambda: all(c.by_name["rg"].machine_state == 1 + n
+                       for c in coords), what="every command everywhere")
+    assert arrived == [True] * n
+    assert [(wave(c, "ingest_fanout").n, wave(c, "ingest_fanout").total)
+            for c in coords] == fan0
 
 
 def test_no_new_span_is_built_without_a_profiler_session(coop, monkeypatch):
