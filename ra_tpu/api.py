@@ -5,7 +5,10 @@ The framework's counterpart of the reference's ``ra`` module
 process_command/pipeline_command, local/leader/consistent queries,
 membership management, leadership transfer, overview/metrics). Operates
 on in-proc nodes registered in ``ra_tpu.runtime.transport.registry()``;
-server ids are ``(name, node_name)`` tuples.
+server ids are ``(name, node_name)`` tuples. A server on a node outside
+this process is reached through a local node's TCP transport: commands
+and queries cross as call frames, their replies come back to the
+caller's future (``_send_remote``).
 """
 
 from __future__ import annotations
@@ -23,18 +26,22 @@ from ra_tpu.runtime.node import RaNode
 from ra_tpu.runtime.transport import registry as node_registry
 from ra_tpu.system import SystemConfig
 from ra_tpu.utils.lib import partition_parallel
+from ra_tpu.utils.wire import crossing_error
 
 
 class Future:
     """``t_born`` (``time.monotonic_ns()`` at construction) is where the
     batch backend's read accounts start (docs/INTERNALS.md §13)."""
 
-    __slots__ = ("_evt", "value", "t_born")
+    __slots__ = ("_evt", "value", "t_born", "on_timeout")
 
     def __init__(self) -> None:
         self._evt = threading.Event()
         self.value: Any = None
         self.t_born = time.monotonic_ns()
+        # called when ``result`` times out: a call sent to another node
+        # leaves the transport's table of pending calls
+        self.on_timeout: Optional[Callable[[], None]] = None
 
     def set_result(self, v: Any) -> None:
         self.value = v
@@ -42,6 +49,8 @@ class Future:
 
     def result(self, timeout: Optional[float] = None) -> Any:
         if not self._evt.wait(timeout):
+            if self.on_timeout is not None:
+                self.on_timeout()
             raise TimeoutError("ra_tpu call timed out")
         return self.value
 
@@ -133,12 +142,20 @@ def _mgmt_route(node_name: str):
     node = node_registry().get(node_name)
     if node is not None:
         return node
-    for local in node_registry().names():
-        n = node_registry().get(local)
-        t = getattr(n, "transport", None)
-        if t is not None and hasattr(t, "mgmt_call"):
-            return _RemoteNode(t, node_name)
+    t = _wire_transport()
+    if t is not None:
+        return _RemoteNode(t, node_name)
     raise RaError(f"no route to node {node_name!r} (no local TCP transport)")
+
+
+def _wire_transport():
+    """A local node's TCP transport, the way to a node outside this
+    process; None where no local node has one."""
+    for local in node_registry().names():
+        t = getattr(node_registry().get(local), "transport", None)
+        if t is not None and hasattr(t, "mgmt_call"):
+            return t
+    return None
 
 
 class _RemoteNode:
@@ -401,8 +418,48 @@ def process_command(
 def _try_send(sid: ServerId, msg: Any) -> bool:
     node = node_registry().get(sid[1])
     if node is None:
-        return False
+        return _send_remote(sid, msg)
     return node.deliver(sid, msg, None)
+
+
+def _send_remote(sid: ServerId, msg: Any) -> bool:
+    """A call to a server on a node outside this process, through a
+    local node's TCP transport (as ``_mgmt_route`` reaches a remote
+    node's management): a command or a query whose reply handle is a
+    ``Future`` crosses as a call frame, and the reply comes back to the
+    future (docs/INTERNALS.md, "Clients on another node"). False when
+    no local node has a TCP transport, or the message owes no reply. A
+    query's function crosses only as an object of a class the wire
+    admits (``utils.wire.crossing_error``): anything else raises
+    ``RaError`` here, before anything is sent."""
+    route = _wire_transport()
+    if route is None:
+        return False
+    if type(msg) is Command:
+        fut, slot, bare = msg.from_ref, -1, msg._replace(from_ref=None)
+    elif type(msg) is tuple:
+        slot = next((i for i, x in enumerate(msg) if isinstance(x, Future)),
+                    None)
+        if slot is None:
+            return False
+        fut, bare = msg[slot], msg[:slot] + (None,) + msg[slot + 1:]
+        for x in bare:
+            why = callable(x) and crossing_error(x)
+            if why:
+                raise RaError(f"{msg[0]} to {sid} cannot cross to node "
+                              f"{sid[1]!r}: {why}")
+    else:
+        return False
+    if not isinstance(fut, Future):
+        return False
+    try:
+        corr = route.send_call(sid, bare, slot, fut)
+    except TypeError as e:
+        raise RaError(f"{sid}: {e}") from e
+    if corr is None:
+        return False
+    fut.on_timeout = functools.partial(route.forget_call, corr)
+    return True
 
 
 def _try_send_many(sid: ServerId, msgs: list) -> int:
@@ -620,15 +677,19 @@ def consistent_query(
     raise RaError("consistent_query timed out")
 
 
-def members(server_id: ServerId, timeout: float = 5.0) -> Tuple[List[ServerId], ServerId]:
-    def get_members(s):
-        # Server exposes members() as a method; coordinator GroupHost as
-        # a plain attribute
+class MemberList:
+    """``members``' query, as an object so that it crosses to a node in
+    another process: a Server exposes ``members()`` as a method, a batch
+    group as a plain attribute."""
+
+    def __call__(self, s):
         m = s.members
         return list(m() if callable(m) else m)
 
+
+def members(server_id: ServerId, timeout: float = 5.0) -> Tuple[List[ServerId], ServerId]:
     fut = Future()
-    if not _try_send(server_id, ("state_query", get_members, fut)):
+    if not _try_send(server_id, ("state_query", MemberList(), fut)):
         raise RaError(f"server {server_id} unreachable")
     out = fut.result(timeout)
     return out[1], out[2]

@@ -412,6 +412,29 @@ COORDINATOR_FIELDS: List[FieldSpec] = [
      "timeouts, snapshot chunks, membership)"),
 ]
 
+# Per-node client-path vector (name ("client", node_name); kept by the
+# node's TcpTransport under its own lock, booked once a frame): calls
+# made through ``api`` to a server on another node, and their replies
+# (docs/INTERNALS.md, "Clients on another node").
+CLIENT_FIELDS: List[FieldSpec] = [
+    ("client_calls_out", "counter",
+     "calls this node sent to a server on another node"),
+    ("client_calls_in", "counter",
+     "calls from another node handed to this node's ingress"),
+    ("client_replies_out", "counter",
+     "replies this node sent to a caller on another node"),
+    ("client_replies_in", "counter",
+     "replies from another node taken in (late ones included)"),
+    ("client_frames_out", "counter",
+     "frames this node sent that carry at least one call or reply"),
+    ("client_redirects", "counter",
+     "replies taken in that redirect the caller to another member"),
+    ("client_timeouts", "counter",
+     "calls whose caller timed out first (removed from the table)"),
+    ("client_late_replies", "counter",
+     "replies that came after their call timed out (dropped)"),
+]
+
 # Per-node health-plane vector (name ("health", node_name); written
 # only by the node's health scanner on its detector/tick thread). The
 # scans==fetches invariant is the proof of the single-fetch-per-tick

@@ -82,11 +82,25 @@ def kv_get(api_mod, member, key, timeout: float = 5.0) -> Optional[Any]:
     return _kv_get(api_mod, member, key, timeout)
 
 
-def _kv_get(api_mod, member, key, timeout):
-    def resolve(st):
-        at = st.get(key)
+class KvResolve:
+    """``kv_get``'s consistent query, an object so that it crosses to a
+    leader in another process (Ra's ``{M, F, A}``: the code is the
+    class on the answering node, only ``key`` travels): the key's entry
+    in the index map as the ``LogRead`` the answering replica fills in,
+    or None for an absent key."""
+
+    __slots__ = ("key",)
+
+    def __init__(self, key):
+        self.key = key
+
+    def __call__(self, st):
+        at = st.get(self.key)
         return None if at is None else LogRead(at[0], at[1])
 
+
+def _kv_get(api_mod, member, key, timeout):
+    resolve = KvResolve(key)
     for _attempt in range(3):
         out = api_mod.consistent_query(member, resolve, timeout=timeout)
         if out[0] != "ok" or out[1] is None:

@@ -100,6 +100,7 @@ from ra_tpu.protocol import (
 )
 from ra_tpu.runtime import heap as _heap
 from ra_tpu.runtime.monitors import Monitors
+from ra_tpu.runtime.tcp import CLIENT_REPLY, RemoteRef
 from ra_tpu.runtime.transport import InProcTransport, NodeRegistry, registry as node_registry
 
 logger = logging.getLogger("ra_tpu")
@@ -566,6 +567,9 @@ class BatchCoordinator:
         # full-ring publish from one of these must divert to
         # _internal_q — gate-waiting would deadlock on itself
         self._drainer_idents: set = set()
+        # replies to callers on other nodes (RemoteRef) issued by the
+        # drainer thread this turn, by caller node: [(corr, value)]
+        self._client_out: Dict[str, List] = {}
         # reusable drain scratch (step-thread only)
         self._drain_buf: List = []
         # must-deliver self-publishes from the coordinator's own step
@@ -1354,9 +1358,13 @@ class BatchCoordinator:
         with self._step_lock:
             ticket = self._drain_and_dispatch(pre)
             if ticket is None:
+                if self._client_out:
+                    self._flush_client_replies()
                 return False
             if not served:
                 self._realise(ticket)
+                if self._client_out:
+                    self._flush_client_replies()
                 return True
         cnt = self.counters
         cnt.incr("pipeline_steps")
@@ -1380,6 +1388,8 @@ class BatchCoordinator:
         if eg is not None and eg.is_ready():
             cnt.incr("sync_found_ready")
         self._realise(ticket, self._egress_lock)
+        if self._client_out:
+            self._flush_client_replies()
         return True
 
     def _sender_loop(self) -> None:
@@ -1425,10 +1435,14 @@ class BatchCoordinator:
                 # interpreter lock: what queued for one destination
                 # while this thread waited its own turn leaves as one
                 # (the slower the turns, the larger the frames)
-                by_node: Dict[str, List] = {}
+                # (a batch of client replies keeps a frame of its own)
+                by_node: Dict[Tuple[str, bool], List] = {}
                 for node_name, msgs, _t_pub in out:
-                    by_node.setdefault(node_name, []).extend(msgs)
-                batches = by_node.items()
+                    by_node.setdefault(
+                        (node_name, bool(msgs)
+                         and msgs[0][0][0] == CLIENT_REPLY), []
+                    ).extend(msgs)
+                batches = [(key[0], msgs) for key, msgs in by_node.items()]
             for item in batches:
                 node_name, msgs = item[0], item[1]
                 try:
@@ -3784,11 +3798,28 @@ class BatchCoordinator:
     # -- outbound ----------------------------------------------------------
 
     def _reply(self, fut, value) -> None:
+        if type(fut) is RemoteRef and \
+                threading.get_ident() in self._drainer_idents:
+            # a caller on another node: the turn's replies to its node
+            # leave together when the turn ends (_flush_client_replies)
+            out = self._client_out.get(fut.node)
+            if out is None:
+                self._client_out[fut.node] = out = []
+            out.append((fut.corr, value))
+            return
         setter = getattr(fut, "set_result", None)
         if setter is not None:
             setter(value)
         elif callable(fut):
             fut(value)
+
+    def _flush_client_replies(self) -> None:
+        """The turn's replies to callers on other nodes, one batch a
+        caller node, through ``_send_batch`` (drainer thread only)."""
+        out, self._client_out = self._client_out, {}
+        reply_batch = self.transport.reply_batch
+        for node_name, replies in out.items():
+            self._send_batch(node_name, reply_batch(node_name, replies))
 
     def _send_batch(self, node_name: str, msgs) -> None:
         """Per-destination batch send. With the started loop,

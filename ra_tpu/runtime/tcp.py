@@ -36,6 +36,11 @@ src/ra_server_proc.erl:1875-1881, 2094-2110):
   peer and its writer is idle the caller's thread offers the frame to
   the socket itself (``MSG_DONTWAIT``: the socket takes what it can
   at once), and the writer gets what is left;
+- a client's call to a server on another node (``send_call``, from
+  ``api``) and the replies to it (``reply_batch``, from the replying
+  node's wave) are batch frames of their own, matched by a correlation
+  id in this transport's table of pending calls (docs/INTERNALS.md
+  section 24);
 - at-most-once delivery; reconnection is lazy on next send;
 - liveness: pings every ``ping_interval_s``; any authenticated frame
   from a node within ``pong_timeout_s`` proves it alive, and past that
@@ -65,9 +70,11 @@ import time
 from collections import deque
 from typing import Any, Dict, List, Optional, Tuple
 
+from ra_tpu import counters as _counters
 from ra_tpu import faults
 from ra_tpu import obs as _obs
-from ra_tpu.protocol import ServerId, sanitize_for_wire
+from ra_tpu.protocol import (
+    LogRead, ServerId, sanitize_for_wire, strip_entry_refs)
 
 logger = logging.getLogger("ra_tpu")
 
@@ -75,6 +82,13 @@ _LEN = struct.Struct("<I")
 MAX_FRAME = 64 * 1024 * 1024
 _MAC_LEN = 16  # truncated HMAC-SHA256 prefix on every frame
 _BATCH = "__batch__"
+# a batch frame whose list names one of these in its first triple
+# carries a client's calls, or their replies, and nothing else
+# (send_call, reply_batch): triples (CLIENT_CALL, None, (corr, server
+# name, msg, slot, t_sent)) and (CLIENT_REPLY, None, (corr, value,
+# t_issued))
+CLIENT_CALL = "__call__"
+CLIENT_REPLY = "__reply__"
 _RECV = 1 << 20  # one recv takes what the socket holds, up to this
 # hashlib lets go of the interpreter lock around an update of 2,048
 # bytes or more, and under a dozen busy threads getting it back costs a
@@ -90,6 +104,50 @@ from ra_tpu.utils.wire import (  # noqa: E402,F401 (re-export)
     wire_load_file as _wire_load_file,
     wire_loads as _wire_loads,
 )
+
+
+class RemoteRef:
+    """The reply handle of a call that came over the wire: where a local
+    caller's future would be (a ``Command``'s ``from_ref``, a query's
+    future), the caller's node and the call's correlation id. The batch
+    coordinator's ``_reply`` gathers these per caller node and sends a
+    wave's replies as one frame; anyone else replies at once through
+    ``set_result``. ``t_born`` is when the call was handed to this
+    node's ingress (``time.monotonic_ns()``), where a read's accounts
+    start. It never crosses a process boundary itself: the log and the
+    replication strip it as they strip a local future."""
+
+    __slots__ = ("transport", "node", "corr", "t_born")
+
+    def __init__(self, transport: "TcpTransport", node: str, corr: int,
+                 t_born: int):
+        self.transport = transport
+        self.node = node
+        self.corr = corr
+        self.t_born = t_born
+
+    def set_result(self, value: Any) -> None:
+        self.transport.send_replies(self.node, [(self.corr, value)])
+
+    def __reduce__(self):
+        raise TypeError("a RemoteRef does not leave its process")
+
+
+def _wire_reply(value: Any) -> Any:
+    """A reply as it can cross: a reject's gate waiter (an event of this
+    process) is left out, so the caller backs off by its own clock, and
+    the log entry a ``LogRead`` answer carries loses the leader's reply
+    handle and stamp, as replication strips them."""
+    if type(value) is tuple and value:
+        if value[0] == "reject":
+            return value[:2]
+        if len(value) > 1 and type(value[1]) is LogRead \
+                and value[1].entry is not None:
+            read = value[1]
+            (entry,) = strip_entry_refs((read.entry,))
+            if entry is not read.entry:
+                return (value[0], read._replace(entry=entry)) + value[2:]
+    return value
 
 
 class _Peer:
@@ -186,6 +244,27 @@ class TcpTransport:
         self._mgmt_futs: Dict[int, Tuple[threading.Event, dict]] = {}
         self._mgmt_seq = 0
         self._mgmt_lock = threading.Lock()
+        # the client path (send_call / reply_batch; docs/INTERNALS.md,
+        # "Clients on another node"): calls this node made and not yet
+        # answered, by correlation id, and the path's counters, all
+        # under one lock. The two hops are stamped on
+        # ``time.monotonic_ns()`` at each end, so they read true only
+        # where both nodes share a host's clock (the benchmark's nodes
+        # share a process)
+        self._calls: Dict[int, Any] = {}
+        self._call_seq = 0
+        self._client_lock = threading.Lock()
+        self.client = _counters.new(("client", node_name),
+                                    _counters.CLIENT_FIELDS)
+        self._client_hop = _obs.histogram(
+            ("client", node_name, "client_hop"),
+            "a call's stamp on the caller's node before encode -> handed "
+            "to this node's ingress", locked=True)
+        self._reply_hop = _obs.histogram(
+            ("client", node_name, "reply_hop"),
+            "a reply's stamp on the replying node as its wave hands it "
+            "to the send path -> taken in here, before the caller's "
+            "future is set", locked=True)
         self._ping_thread = threading.Thread(
             target=self._ping_loop, name=f"ra-tcp-ping-{node_name}", daemon=True
         )
@@ -278,8 +357,11 @@ class TcpTransport:
                 sent += n
                 frames += 1
                 size += len(wire)
-        self._book(frames_out=frames, msgs_out=sent, bytes_out=size,
-                   encode_ns=time.perf_counter_ns() - t0)
+        if triples and triples[0][0] == CLIENT_REPLY:
+            self._count(client_replies_out=sent, client_frames_out=frames)
+        else:
+            self._book(frames_out=frames, msgs_out=sent, bytes_out=size,
+                       encode_ns=time.perf_counter_ns() - t0)
         return sent
 
     def _batch_frames(self, triples: List[tuple]):
@@ -304,9 +386,8 @@ class TcpTransport:
             else:
                 self._drop(len(triples))  # (each encodes, the list not)
             return
-        head = pickle.dumps((_BATCH, self.node_name, len(triples)))
-        size = _MAC_LEN + len(head) + len(body)
-        if size > MAX_FRAME:
+        wire = self._batch_frame(len(triples), body)
+        if wire is None:
             if len(triples) == 1:
                 self._drop()  # one message larger than any frame
                 return
@@ -314,8 +395,146 @@ class TcpTransport:
             yield from self._batch_frames(triples[:half])
             yield from self._batch_frames(triples[half:])
             return
-        yield (b"".join((_LEN.pack(size), self._mac(head, body), head, body)),
-               len(triples))
+        yield wire, len(triples)
+
+    def _batch_frame(self, n: int, body: bytes) -> Optional[bytes]:
+        """The sealed, length-prefixed batch frame of ``n`` messages
+        whose list encodes to ``body``; None past ``MAX_FRAME``."""
+        head = pickle.dumps((_BATCH, self.node_name, n))
+        size = _MAC_LEN + len(head) + len(body)
+        if size > MAX_FRAME:
+            return None
+        return b"".join((_LEN.pack(size), self._mac(head, body), head, body))
+
+    def _count(self, **fields: int) -> None:
+        """Add to this node's client-path counters (``client_*``)."""
+        c = self.client
+        with self._client_lock:
+            for k, v in fields.items():
+                c.incr(k, v)
+
+    # -- the client path: calls to a server on another node ----------------
+
+    def send_call(self, to: ServerId, msg: Any, slot: int, fut) -> Optional[int]:
+        """Send one client call to the server ``to`` on another node,
+        as a batch frame of its own, from the caller's thread: ``msg``
+        is what ``api`` would deliver locally with its reply handle
+        taken out; ``slot`` says where the handle goes back (-1: a
+        ``Command``'s ``from_ref``, else the tuple's position). ``fut``
+        (``set_result``) is held in the table of pending calls until its
+        reply comes. Returns the correlation id, which
+        ``forget_call`` takes when the caller gives up; None when
+        ``to``'s node cannot be addressed. A frame lost on the way
+        (a blocked pair, a full outbox, a broken connection) is a call
+        whose caller times out. Raises ``TypeError`` before anything is
+        sent when the call does not encode."""
+        node_name = to[1]
+        peer = self._peer(node_name)
+        if peer is None:
+            return None
+        t_sent = time.monotonic_ns()
+        with self._client_lock:
+            self._call_seq += 1
+            corr = self._call_seq
+            self._calls[corr] = fut
+        tr = _obs.tracing()
+        if tr:
+            sp = _obs.begin("ra/client/call", node=self.node_name,
+                            peer=node_name)
+        try:
+            try:
+                body = pickle.dumps([(CLIENT_CALL, None, (
+                    corr, to[0], sanitize_for_wire(msg), slot, t_sent))])
+            except Exception as e:  # noqa: BLE001 — what will not encode
+                with self._client_lock:
+                    self._calls.pop(corr, None)
+                raise TypeError(f"the call does not encode: {e}") from e
+            wire = self._batch_frame(1, body)
+            if wire is None:
+                with self._client_lock:
+                    self._calls.pop(corr, None)
+                raise TypeError("the call is larger than any frame")
+            if self._closed or (self.node_name, node_name) in self.blocked:
+                self._drop()
+            elif self._enqueue(peer, wire, 1):
+                self._count(client_calls_out=1, client_frames_out=1)
+        finally:
+            if tr:
+                _obs.end(sp)
+        return corr
+
+    def forget_call(self, corr: int) -> None:
+        """The caller of ``corr`` timed out: its entry leaves the table,
+        and a reply that comes later is counted late and dropped."""
+        with self._client_lock:
+            if self._calls.pop(corr, None) is not None:
+                self.client.incr("client_timeouts")
+
+    def reply_batch(self, node_name: str, replies) -> List[tuple]:
+        """``(corr, value)`` replies to callers on ``node_name`` as the
+        ``(to, msg, from)`` triples of one ``send_batch``: one clock read
+        for all of them (their ``reply_hop`` starts here)."""
+        t = time.monotonic_ns()
+        to = (CLIENT_REPLY, node_name)
+        return [(to, (corr, _wire_reply(value), t), None)
+                for corr, value in replies]
+
+    def send_replies(self, node_name: str, replies) -> None:
+        """``(corr, value)`` replies to ``node_name`` now, as one frame
+        (message by message while a tcp failpoint is armed)."""
+        msgs = self.reply_batch(node_name, replies)
+        if self.send_batch(node_name, msgs) < 0:
+            for to, m, frm in msgs:
+                self.send(to, m, frm)
+
+    def _on_calls(self, sender: str, triples) -> None:
+        """A frame of calls from ``sender``: each handed to the owner's
+        ingress with a ``RemoteRef`` where the caller's future was (a
+        command takes its submit stamp here, on this node's clock). A
+        call to a server this node does not have is answered
+        ``("redirect", None)`` at once: never appended, try another."""
+        now = time.monotonic_ns()
+        hop = self._client_hop.record
+        deliver, me = self.deliver, self.node_name
+        refused = []
+        for _name, _frm, (corr, name, msg, slot, t_sent) in triples:
+            hop(now - t_sent)
+            ref = RemoteRef(self, sender, corr, now)
+            if slot < 0:
+                msg = msg._replace(from_ref=ref, ts=now)
+            else:
+                msg = msg[:slot] + (ref,) + msg[slot + 1:]
+            if not deliver((name, me), msg, None):
+                refused.append((corr, ("redirect", None)))
+        self._count(client_calls_in=len(triples))
+        if refused:
+            self.send_replies(sender, refused)
+
+    def _on_replies(self, triples) -> None:
+        """A frame of replies to calls this node made: each sets its
+        caller's future; one that finds no pending call is late."""
+        now = time.monotonic_ns()
+        hop = self._reply_hop.record
+        found, late, redirects = [], 0, 0
+        with self._client_lock:
+            calls = self._calls
+            for _name, _frm, (corr, value, t_issued) in triples:
+                fut = calls.pop(corr, None)
+                if fut is None:
+                    late += 1
+                    continue
+                found.append((fut, value))
+                hop(now - t_issued)
+                if type(value) is tuple and value and value[0] == "redirect":
+                    redirects += 1
+            c = self.client
+            c.incr("client_replies_in", len(triples))
+            if late:
+                c.incr("client_late_replies", late)
+            if redirects:
+                c.incr("client_redirects", redirects)
+        for fut, value in found:
+            fut.set_result(value)
 
     def _mac(self, *parts) -> bytes:
         """The truncated HMAC-SHA256 of ``parts`` under the cookie, fed
@@ -392,6 +611,9 @@ class TcpTransport:
         started (a caller on one of them is not joined)."""
         self._closed = True
         self._stop.set()
+        _counters.delete(("client", self.node_name))
+        for hop in ("client_hop", "reply_hop"):
+            _obs.histograms().delete(("client", self.node_name, hop))
         try:
             self._server.close()
         except OSError:
@@ -662,6 +884,24 @@ class TcpTransport:
                 _obs.end(sp)
             return False
         if to_name == _BATCH:
+            kind = triples[0][0] if triples else None
+            if kind == CLIENT_CALL or kind == CLIENT_REPLY:
+                # a client's calls or their replies: no protocol message
+                # rides such a frame, and the wire_* accounts leave it out
+                try:
+                    self._last_heard[from_sid] = time.monotonic()
+                    if kind == CLIENT_CALL:
+                        self._on_calls(from_sid, triples)
+                    elif sp is not None:
+                        with _obs.span("ra/client/reply", node=self.node_name,
+                                       peer=from_sid, msgs=msg):
+                            self._on_replies(triples)
+                    else:
+                        self._on_replies(triples)
+                finally:
+                    if sp is not None:
+                        _obs.end(sp)
+                return True
             try:
                 self._last_heard[from_sid] = time.monotonic()
                 cb = self.deliver_batch
@@ -714,6 +954,8 @@ class TcpTransport:
             if fut is not None:
                 fut[1]["r"] = r
                 fut[0].set()
+        elif to_name == CLIENT_REPLY:  # sent message by message (a failpoint)
+            self._on_replies([(to_name, from_sid, msg)])
         elif to_name == "__proc_down__":
             cb = self.on_proc_down_cb
             if cb is not None and msg is not None:

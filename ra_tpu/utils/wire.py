@@ -65,6 +65,27 @@ class _RestrictedUnpickler(pickle.Unpickler):
         )
 
 
+def crossing_error(fn) -> str:
+    """Why ``fn``, a query's function, cannot be sent to another node;
+    "" when it can. It can when it is an instance of a class the
+    allowlist above resolves (a class under ``ra_tpu.`` or a registered
+    wire type): Ra's ``{M, F, A}``, state in the object, code on the
+    receiving node. A function, lambda, closure or bound method never
+    crosses; the allowlist stays classes only."""
+    cls = type(fn)
+    if isinstance(fn, type) or not callable(fn):
+        return f"{fn!r} is not an instance of a callable class"
+    if (cls.__module__, cls.__qualname__) in _extra_wire_types:
+        return ""
+    mod, name = cls.__module__, cls.__qualname__
+    if (mod == "ra_tpu" or mod.startswith("ra_tpu.")) \
+            and "." not in name and not name.startswith("_"):
+        return ""
+    return (f"{fn!r} is a {mod}.{name}; only an instance of a public "
+            "class under ra_tpu. or of a registered wire type crosses "
+            "(see ra_tpu.utils.wire.register_wire_type)")
+
+
 def wire_loads(payload: bytes):
     """Deserialize untrusted bytes through the allowlist."""
     return _RestrictedUnpickler(io.BytesIO(payload)).load()

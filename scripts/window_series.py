@@ -41,21 +41,30 @@ class Sampler(threading.Thread):
         self.cluster = cluster
         self.rows = []
         self.done = threading.Event()
+        # a reading and the last one exclude each other: a wake whose
+        # wait ran out just as the window ended reads nothing after it
+        self._lock = threading.Lock()
+        self._closed = False
 
-    def read(self):
-        row = {"t_ns": time.monotonic_ns(),
-               "steps": [c.steps for c in self.cluster.coords]}
-        for k in COUNTERS:
-            row[k] = sum(c.counters.to_dict().get(k, 0)
-                         for c in self.cluster.coords)
-        for k in WAL:
-            row[k] = sum(w.counter.to_dict().get(k, 0)
-                         for _t, w, _sw, _d in self.cluster.storage)
-        hists = self.cluster._obs.histograms()
-        for k in PHASES:  # host ns inside the wave phase so far
-            hs = [hists.fetch(("wave", c.name, k)) for c in self.cluster.coords]
-            row[k] = sum(h.total for h in hs if h is not None)
-        self.rows.append(row)
+    def read(self, last=False):
+        with self._lock:
+            if self._closed:
+                return
+            self._closed = last
+            row = {"t_ns": time.monotonic_ns(),
+                   "steps": [c.steps for c in self.cluster.coords]}
+            for k in COUNTERS:
+                row[k] = sum(c.counters.to_dict().get(k, 0)
+                             for c in self.cluster.coords)
+            for k in WAL:
+                row[k] = sum(w.counter.to_dict().get(k, 0)
+                             for _t, w, _sw, _d in self.cluster.storage)
+            hists = self.cluster._obs.histograms()
+            for k in PHASES:  # host ns inside the wave phase so far
+                hs = [hists.fetch(("wave", c.name, k))
+                      for c in self.cluster.coords]
+                row[k] = sum(h.total for h in hs if h is not None)
+            self.rows.append(row)
 
     def run(self):
         while not self.done.wait(1.0):
@@ -87,6 +96,7 @@ def main(argv=None) -> int:
     from benchmark import run as R
 
     samplers = []
+    inside = []  # a deployment's snapshot() calling its base class's
     load_module = harness.load_module
 
     def load_and_watch(kind, name):
@@ -97,42 +107,61 @@ def main(argv=None) -> int:
 
             def watched(self):
                 # the first and last readings are taken here, on the
-                # caller's thread, next to the window's own clock reads:
-                # a sampler thread's wake under a busy interpreter lock
-                # lets operations end between window edge and reading
-                if not samplers:
-                    snap = snapshot(self)
-                    samplers.append(Sampler(self))
-                    samplers[0].read()
-                    # the window starts as this returns, not as the
-                    # reading began
-                    samplers[0].rows[0]["t_ns"] = time.monotonic_ns()
-                    samplers[0].start()
-                    return snap
-                sampler = samplers[0]
-                sampler.done.set()
-                sampler.read()
-                sampler.join()
-                sampler.rows.sort(key=lambda row: row["t_ns"])
-                return snapshot(self)
+                # caller's thread, next to the window's own clock reads
+                # (and laid on those below: result_and_series)
+                if inside:
+                    return snapshot(self)
+                inside.append(self)
+                try:
+                    if not samplers:
+                        snap = snapshot(self)
+                        samplers.append(Sampler(self))
+                        samplers[0].read()
+                        samplers[0].start()
+                        return snap
+                    sampler = samplers[0]
+                    sampler.done.set()
+                    sampler.read(last=True)
+                    sampler.join()
+                    return snapshot(self)
+                finally:
+                    inside.pop()
 
             mod.Cluster.snapshot = watched
         return mod
 
     harness.load_module = load_and_watch
     result_line = R.result_line
+    # the window's own edges, as run_cell hands them to op_window: the
+    # series' first and last readings are laid on them, so that its
+    # operations are the window's (a reading is taken a few
+    # microseconds from its edge, and more when the interpreter lock
+    # is handed to another thread in between)
+    edges = []
+    op_window = harness.op_window
+
+    def op_window_at(op, t0_ns, t1_ns):
+        edges[:] = [t0_ns, t1_ns]
+        return op_window(op, t0_ns, t1_ns)
 
     def result_and_series(bench, run, trace):
         if samplers:
+            rows = samplers[0].rows
+            if edges:
+                rows[0]["t_ns"], rows[-1]["t_ns"] = edges
             done = [t for op in run.history["ops"].values()
                     for t, ok in zip(op["t_done"], op["ok"]) if ok]
             print(json.dumps({"line": "window_series",
-                              "rows": series(samplers[0].rows, done)}),
+                              "rows": series(rows, done)}),
                   flush=True)
         return result_line(bench, run, trace)
 
     R.result_line = result_and_series
-    return R.main(argv)
+    harness.op_window = op_window_at
+    try:
+        return R.main(argv)
+    finally:
+        harness.op_window = op_window
 
 
 if __name__ == "__main__":
