@@ -408,8 +408,13 @@ COORDINATOR_FIELDS: List[FieldSpec] = [
      "log entries those AppendEntries carried"),
     ("rares_handled", "counter",
      "rare messages handled at realisation (sub-phase egress_rare's "
-     "work: consistent queries, heartbeats and their replies, election "
-     "timeouts, snapshot chunks, membership)"),
+     "work: election timeouts, snapshot chunks, membership, probes; "
+     "a read's queries and heartbeats where a ticket was unrealised "
+     "at ingest)"),
+    ("rares_early", "counter",
+     "rare messages handled by the ingest pass that routed them (inside "
+     "sub-phase ingress_route: consistent queries, heartbeats and their "
+     "replies, where no ticket was unrealised)"),
 ]
 
 # Per-node client-path vector (name ("client", node_name); kept by the

@@ -328,7 +328,8 @@ WAVE_SUBSET_PHASES = {
                         "the ingress lanes and classified, outside the "
                         "state lock; recorded where ingress_drain is)",
     "ingress_route": "subset of ingress_drain (every drained protocol "
-                     "message to its handler, and the replies that "
+                     "message to its handler, a read's queries and "
+                     "heartbeats handled there, and the replies that "
                      "routing produced handed to the sender; no sample "
                      "on a pass without messages)",
     "ingest_fanout": "subset of ingress_drain (kept for the accounts "
@@ -343,9 +344,10 @@ WAVE_SUBSET_PHASES = {
                      "term hints, and the step's replies handed to the "
                      "sender; less the applies inside it)",
     "egress_rare": "subset of host_egress (the step's rare messages: "
-                   "consistent queries registered, heartbeats answered, "
-                   "elections, snapshots, membership; no sample on a "
-                   "step without any)",
+                   "elections, snapshots, membership, probes, and a "
+                   "read's queries and heartbeats where the ingest "
+                   "found a ticket unrealised; no sample on a step "
+                   "without any)",
     "send_queue": "no phase's: a batch published to the sender's ring "
                   "-> the sender thread drains it (one sample a batch; "
                   "an inline send waits for nobody and records none)",

@@ -681,6 +681,17 @@ class BatchCoordinator:
         # cycles one buffer; the started loop at full width two, the
         # step's and the spare it zeroes while the device computes.
         self._mbox_pool: List[np.ndarray] = []
+        # tickets that _drain_and_dispatch made and _realise has not
+        # finished (step thread only). Both drivers realise a turn's
+        # ticket before the next ingest, so the host mirrors an ingest
+        # reads are the device state; a read's rare messages are
+        # handled there only while this is 0 (docs/INTERNALS.md §13)
+        self._unrealised = 0
+        # the answers those early rare messages give callers in this
+        # process, held while _holding until the turn ends, where every
+        # other reply of the turn is set (_turn_replies)
+        self._holding = False
+        self._held: List = []
         # guards self.state (donated buffers!) between the step thread and
         # add_group callers
         self._state_lock = threading.Lock()
@@ -1358,13 +1369,11 @@ class BatchCoordinator:
         with self._step_lock:
             ticket = self._drain_and_dispatch(pre)
             if ticket is None:
-                if self._client_out:
-                    self._flush_client_replies()
+                self._turn_replies()
                 return False
             if not served:
                 self._realise(ticket)
-                if self._client_out:
-                    self._flush_client_replies()
+                self._turn_replies()
                 return True
         cnt = self.counters
         cnt.incr("pipeline_steps")
@@ -1388,8 +1397,7 @@ class BatchCoordinator:
         if eg is not None and eg.is_ready():
             cnt.incr("sync_found_ready")
         self._realise(ticket, self._egress_lock)
-        if self._client_out:
-            self._flush_client_replies()
+        self._turn_replies()
         return True
 
     def _sender_loop(self) -> None:
@@ -1508,6 +1516,7 @@ class BatchCoordinator:
         else:
             with lock:
                 self._finish_ticket(ticket, eg_np, t_pop, t_sync, tr)
+        self._unrealised -= 1
 
     class _StepTicket:
         """One dispatched-but-unrealised step: the device egress handle
@@ -1798,6 +1807,8 @@ class BatchCoordinator:
                 if g is not None:
                     route(g, from_sid, msg, rare, appended, written,
                           aer_dirty, route_out, now_mono)
+            if rare and not self._unrealised:
+                rare = self._rares_at_ingest(rare, route_out, tr)
             for node_name, msgs in route_out.items():
                 self._send_batch(node_name, msgs)
             self._wave_h["ingress_route"].record(
@@ -1968,6 +1979,7 @@ class BatchCoordinator:
             cnt.incr("cpu_ns_ingress_drain", (_c_ing - _c_in) << shift)
             if stepped:
                 cnt.incr("cpu_ns_host_pack", (_c_pack - _c_drain) << shift)
+        self._unrealised += 1
         return self._StepTicket(
             eg_packed=eg_packed, consumed=consumed, act=act_np,
             aer_dirty=aer_dirty, rare=rare, mbox_buf=mbox_buf, t_in=_t_in,
@@ -2081,10 +2093,10 @@ class BatchCoordinator:
             _t_rare = _t_dev
         rare_ns = 0
         if ticket.rare:
-            # where a consistent query is registered and a heartbeat
-            # answered: sub-phase egress_rare, from where egress_mirror
-            # ended (booked below, beside the host_egress it is a part
-            # of)
+            # the rare messages the ingest left to the ticket
+            # (elections, snapshots, membership, probes): sub-phase
+            # egress_rare, from where egress_mirror ended (booked
+            # below, beside the host_egress it is a part of)
             if tr:
                 sp = _obs.begin("ra/egress/rare", node=node)
             self._handle_rares(ticket.rare)
@@ -2127,8 +2139,8 @@ class BatchCoordinator:
             wh["egress_lock_wait"].record(_t_dev - t_sync)
             wh["device_step"].record(_t_dev - ticket.t_pack)
         # (a pass that found nothing to step still hands its rare
-        # messages over on a ticket: a consistent query on a quiet
-        # leader. Their time is host_egress too, or no phase holds it)
+        # messages over on a ticket: a probe of a quiet group. Their
+        # time is host_egress too, or no phase holds it)
         egressed = eg_np is not None or rare_ns
         if egressed:
             wh["host_egress"].record(_t_eg - _t_dev)
@@ -2146,14 +2158,46 @@ class BatchCoordinator:
             if egressed:
                 cnt.incr("cpu_ns_host_egress", (_c_eg - _c_dev) << shift)
 
-    def _handle_rares(self, rares) -> None:
+    def _rares_at_ingest(self, rare, out, tr):
+        """A consistent read's three legs, handled by the pass that
+        routed them (docs/INTERNALS.md §13): the leader's query, the
+        follower's ``HeartbeatRpc`` and the leader's ``HeartbeatReply``
+        read host mirrors only, so they need not wait behind the pack
+        and the device step. Caller holds the state lock with no ticket
+        unrealised; their sends join ``out``, their answers to callers
+        leave as the turn ends (``_turn_replies``). Returns the other
+        rare messages, in their order, for the ticket."""
+        early, late = [], []
+        for r in rare:
+            m = r[1]
+            t = type(m)
+            if (t is HeartbeatRpc or t is HeartbeatReply
+                    or (t is tuple and m and m[0] == "consistent_query")):
+                early.append(r)
+            else:
+                late.append(r)
+        if early:
+            self._holding = True
+            try:
+                self._handle_rares(early, out)
+            finally:
+                self._holding = False
+            self.counters.incr("rares_early", len(early))
+            if tr:
+                self._end_effects_span()
+        return late
+
+    def _handle_rares(self, rares, rare_out=None) -> None:
         # rare-path outbound batches per destination ACROSS the whole
         # rare loop: an election storm over 10k groups must land on a
         # peer as a handful of ring items, not one per group — per-group
         # sends overflowed the peer's bounded ingress lane and the
         # overflow was shed as lossy traffic, wedging the un-retried
-        # tail of the storm (caught by the 10240-group bench election)
-        rare_out: Dict[str, List] = {}
+        # tail of the storm (caught by the 10240-group bench election).
+        # ``rare_out``: a caller's own outbound, which it ships
+        send = rare_out is None
+        if send:
+            rare_out = {}
         for g, msg, from_sid in rares:
             # crash isolation for the slow paths (snapshot transfer
             # decode of untrusted bytes, membership, queries): a
@@ -2168,8 +2212,9 @@ class BatchCoordinator:
                     "%s after handler crash", self.name, type(msg).__name__,
                     g.name,
                 )
-        for node_name, msgs in rare_out.items():
-            self._send_batch(node_name, msgs)
+        if send:
+            for node_name, msgs in rare_out.items():
+                self._send_batch(node_name, msgs)
 
     def _stage_app(self, gid: int, lo: int, hi: int, term: int) -> None:
         """Stage an appended run for the next dispatching pass's device
@@ -2648,7 +2693,12 @@ class BatchCoordinator:
         """The device side of a term learnt outside the mailbox (a
         heartbeat reply, a snapshot): ``g`` follows ``g.leader_slot``
         at the larger of its term and ``term``, its vote cleared where
-        the term moved (under the state lock)."""
+        the term moved (under the state lock). A role queued for the
+        next step's scatter before this (a pre-vote round the group's
+        election timeout started) is superseded, not applied after."""
+        if self._pending_roles:
+            self._pending_roles = [
+                pr for pr in self._pending_roles if pr[0] != g.gid]
         one = np.array([g.gid], np.int32)
         fields = {"leader_slot": np.array([g.leader_slot], np.int32),
                   "role": np.array([C.R_FOLLOWER], np.int32)}
@@ -3807,11 +3857,28 @@ class BatchCoordinator:
                 self._client_out[fut.node] = out = []
             out.append((fut.corr, value))
             return
+        if self._holding:
+            self._held.append((fut, value))
+            return
         setter = getattr(fut, "set_result", None)
         if setter is not None:
             setter(value)
         elif callable(fut):
             fut(value)
+
+    def _turn_replies(self) -> None:
+        """Where a turn's replies to its callers leave, once its step is
+        realised (drainer thread only): the answers its ingest gave to
+        callers in this process, held until here so that a caller's next
+        operation meets the next turn's ingest, as it does after a
+        commit's reply, and not the middle of this turn's pack; then
+        the replies to callers on other nodes, a batch a node."""
+        if self._held:
+            held, self._held = self._held, []
+            for fut, value in held:
+                self._reply(fut, value)
+        if self._client_out:
+            self._flush_client_replies()
 
     def _flush_client_replies(self) -> None:
         """The turn's replies to callers on other nodes, one batch a
@@ -4360,7 +4427,7 @@ class BatchCoordinator:
                 self._realise_effects(g, effs)
             return
         if isinstance(msg, tuple) and msg and msg[0] == "consistent_query":
-            self._handle_consistent_query(g, msg[1], msg[2])
+            self._handle_consistent_query(g, msg[1], msg[2], rare_out)
             return
         if isinstance(msg, HeartbeatRpc):
             # follower side of the query-index leadership confirmation.
@@ -4379,9 +4446,11 @@ class BatchCoordinator:
                     reply = HeartbeatReply(term=msg.term, query_index=msg.query_index)
                 else:
                     reply = HeartbeatReply(term=g.term, query_index=-1)
-                self._send_batch(
-                    from_sid[1], [(from_sid, reply, (g.name, self.name))]
-                )
+                item = (from_sid, reply, (g.name, self.name))
+                if rare_out is None:
+                    self._send_batch(from_sid[1], [item])
+                else:
+                    rare_out.setdefault(from_sid[1], []).append(item)
             return
         if isinstance(msg, HeartbeatReply):
             self._handle_heartbeat_reply(g, msg, from_sid)
@@ -4545,11 +4614,15 @@ class BatchCoordinator:
                 cnt.incr("read_log_misses")
         return res
 
-    def _handle_consistent_query(self, g: GroupHost, fn, fut) -> None:
+    def _handle_consistent_query(self, g: GroupHost, fn, fut,
+                                 out: Optional[Dict[str, List]] = None
+                                 ) -> None:
         """Linearizable read: confirm leadership with a voter heartbeat
         quorum round before answering, gated on the leader's own noop
         having committed (Raft read-index; reference: query_index
-        heartbeat protocol, src/ra_server.erl consistent queries)."""
+        heartbeat protocol, src/ra_server.erl consistent queries).
+        ``out``: the caller's per-destination outbound, which it ships;
+        None sends the heartbeats here."""
         if g.role != C.R_LEADER:
             self._reply(fut, ("redirect", g.sid_of(g.leader_slot)))
             return
@@ -4597,7 +4670,7 @@ class BatchCoordinator:
                         term=g.term, leader_id=me,
                         query_index=g.query_seq,
                     )
-                    ob0: Dict[str, List] = {}
+                    ob0: Dict[str, List] = {} if out is None else out
                     for s0, m0 in enumerate(g.members):
                         if (
                             m0 is None or s0 == g.self_slot
@@ -4606,8 +4679,9 @@ class BatchCoordinator:
                             continue
                         self._lease_stamp_send(gid, s0, now)
                         ob0.setdefault(m0[1], []).append((m0, hb0, me))
-                    for nn0, msgs0 in ob0.items():
-                        self._send_batch(nn0, msgs0)
+                    if out is None:
+                        for nn0, msgs0 in ob0.items():
+                            self._send_batch(nn0, msgs0)
                 return
             if exp > 0.0:
                 # held a lease, lapsed: count the expiry once. Bases
@@ -4635,7 +4709,7 @@ class BatchCoordinator:
                  "acks": set(), "t": now}
         g.pending_queries.append(query)
         hb = HeartbeatRpc(term=g.term, leader_id=me, query_index=qid)
-        outbound: Dict[str, List] = {}
+        outbound: Dict[str, List] = {} if out is None else out
         for s, member in enumerate(g.members):
             if (
                 member is None
@@ -4647,8 +4721,9 @@ class BatchCoordinator:
                 # the fallback round's acks re-earn the lease
                 self._lease_stamp_send(g.gid, s, now)
             outbound.setdefault(member[1], []).append((member, hb, me))
-        for node_name, msgs in outbound.items():
-            self._send_batch(node_name, msgs)
+        if out is None:
+            for node_name, msgs in outbound.items():
+                self._send_batch(node_name, msgs)
         if born is not None:
             query["t_reg"] = t_reg = time.monotonic_ns()
             cnt.incr("read_registers")

@@ -105,8 +105,8 @@ def events_inside(xplane_path: str, span_name: str) -> list:
 
 
 COUNTERS = ("routed_msgs", "follower_aers", "follower_entries",
-            "rares_handled", "egress_thread_batches", "egress_thread_msgs",
-            "pipeline_steps", "sync_found_ready")
+            "rares_handled", "rares_early", "egress_thread_batches",
+            "egress_thread_msgs", "pipeline_steps", "sync_found_ready")
 
 
 def wave_account(run) -> dict:

@@ -132,6 +132,94 @@ def test_live_batch_backend_linearizable():
     assert sum(res.per_key_ops.values()) > 30
 
 
+def test_live_batch_backend_kv_get_linearizable_across_leader_transfers():
+    """Concurrent callers, half ``put`` and half ``kv_get``, through
+    ``api`` against started coordinators while leadership moves from
+    replica to replica: a read is registered, and its heartbeats
+    answered, by the pass that routes them (docs/INTERNALS.md §13), and
+    the history must still be linearizable."""
+    import random
+    import threading
+
+    from ra_tpu import api
+    from ra_tpu.models.kv import KvMachine, kv_get
+    from ra_tpu.ops import consensus as C
+
+    from test_batch_parity import mk_cluster, stop_all
+
+    coords = mk_cluster("lt", machine=KvMachine)
+    ids = [("ltg0", f"lt{i}") for i in range(3)]
+    keys = ["k0", "k1", "k2"]
+    rec = linearize.HistoryRecorder()
+    moved = []
+    done = threading.Event()
+
+    def caller(cid):
+        rng = random.Random(cid)
+        # thirty operations at least, and on until leadership has moved
+        # three times (or half a minute has passed)
+        deadline = time.monotonic() + 30
+        for seq in range(100_000):
+            if seq >= 30 and (len(moved) >= 3
+                              or time.monotonic() > deadline):
+                break
+            key = rng.choice(keys)
+            member = ids[rng.randrange(3)]
+            inv = rec.now()
+            if rng.random() < 0.5:
+                value = (cid, seq)
+                try:
+                    r, _ = api.process_command(member, ("put", key, value),
+                                               timeout=10)
+                    ret = rec.now() if r[0] == "ok" else math.inf
+                except Exception:  # noqa: BLE001 - indeterminate
+                    ret = math.inf
+                rec.record(key, Op(cid, "write", value, inv, ret))
+            else:
+                try:
+                    got = kv_get(api, member, key, timeout=10)
+                except Exception:  # noqa: BLE001 - constrains nothing
+                    continue
+                rec.record(key, Op(cid, "read", got, inv, rec.now()))
+
+    def transfers():
+        k = 0
+        while not done.wait(0.02):
+            lead = next((i for i in range(3) if coords[i].by_name[
+                "ltg0"].role == C.R_LEADER), None)
+            if lead is None:
+                continue
+            k += 1
+            to = ids[(lead + 1 + k % 2) % 3]
+            try:
+                out = api.transfer_leadership(ids[lead], to, timeout=5)
+            except Exception:  # noqa: BLE001 - tried again next round
+                continue
+            if out[0] == "ok":
+                moved.append(to)
+
+    mover = threading.Thread(target=transfers, daemon=True)
+    callers = [threading.Thread(target=caller, args=(cid,), daemon=True)
+               for cid in range(4)]
+    try:
+        mover.start()
+        for t in callers:
+            t.start()
+        for t in callers:
+            t.join(120)
+        assert not any(t.is_alive() for t in callers)
+    finally:
+        done.set()
+        mover.join(10)
+        stop_all(coords)
+    history = rec.history()
+    assert len(moved) >= 3, moved
+    reads = [o for ops in history.values() for o in ops if o.kind == "read"]
+    assert len(reads) >= 20 and any(o.value is not None for o in reads)
+    res = check_history(history)
+    assert res.ok, res.violations
+
+
 # -- 3. the tier can FAIL: injected stale-read bug --------------------------
 
 
@@ -148,7 +236,7 @@ def _run_injected_stale_read_scenario(active_set: str = "auto"):
     from ra_tpu.runtime.coordinator import BatchCoordinator
     from ra_tpu.ops import consensus as C
 
-    def broken_consistent_query(self, g, fn, fut):
+    def broken_consistent_query(self, g, fn, fut, out=None):
         # BUG (deliberate): a deposed leader answers reads from its own
         # stale state
         if g.role == C.R_LEADER or g.leader_slot == g.self_slot:

@@ -108,7 +108,8 @@ def counter_totals(coords):
 @pytest.fixture(scope="module")
 def traced(tmp_path_factory):
     """One traced window of a started WAL-backed 3 x 4 ``ra_kv``
-    cluster: WRITES puts, READS ``kv_get``s, a WAL rollover. The
+    cluster: WRITES puts, READS ``kv_get``s and as many local queries,
+    a WAL rollover. The
     thread-CPU accounts read their clock on every turn here, so that
     they can be held to the wall phases turn for turn."""
     leaderboard.clear()
@@ -161,6 +162,11 @@ def traced(tmp_path_factory):
                 assert kv_get(api, (names[g], lead.name), f"key{g}") == \
                     b"w" * 64
             read_ns = time.monotonic_ns() - t0
+            # a read's queries and heartbeats are handled as they are
+            # routed; a local query still rides its pass's ticket
+            for i in range(READS):
+                assert api.local_query((names[i % GROUPS], lead.name),
+                                       lambda st: len(st))[0] == "ok"
 
             # puts from several callers at once: one lands while a step
             # is realised, and waits in its lane for the next turn

@@ -27,7 +27,7 @@ INGRESS_LEAVES = ("ingress_classify", "step_lock_wait", "ingress_route",
 EGRESS_LEAVES = ("egress_follow", "egress_mirror", "egress_apply",
                  "egress_rare")
 COUNTERS = ("routed_msgs", "follower_aers", "follower_entries",
-            "rares_handled")
+            "rares_handled", "rares_early")
 
 
 def wave(c, phase):
@@ -157,6 +157,10 @@ def test_every_leaf_has_samples_and_the_leaves_add_up(coop):
         lead.deliver(ids[0], ("consistent_query", lambda s: s, q), None)
         drive(q.done, "a query's reply")
         assert q.value[:2] == ("ok", k + 1)
+        # a state query rides the ticket: egress_rare
+        s = api.Future()
+        lead.deliver(ids[0], ("state_query", lambda g: g.term, s), None)
+        drive(s.done, "a state query's reply")
     while step():
         pass
     after = totals(coords, phases)
@@ -180,12 +184,15 @@ def test_every_leaf_has_samples_and_the_leaves_add_up(coop):
     # the counters that turn a total into a time a message: every
     # command is one AppendEntries with one entry on each of two
     # followers; a query is a rare message on the leader and a heartbeat
-    # on each follower, answered on the leader again
+    # on each follower, answered on the leader again, all five handled
+    # as they are routed; a state query is one rare message on a ticket
     cnt = {k: sum(c.counters.get(k) for c in coords) - cnt0[k]
            for k in COUNTERS}
     assert cnt["follower_aers"] == cnt["follower_entries"] == 2 * n
-    assert cnt["rares_handled"] >= 5 * n
-    assert cnt["routed_msgs"] >= cnt["rares_handled"] + 4 * n
+    assert cnt["rares_early"] == 5 * n
+    assert cnt["rares_handled"] >= n
+    assert cnt["routed_msgs"] >= (cnt["rares_handled"] + cnt["rares_early"]
+                                  + 4 * n)
     assert lead.counters.get("follower_aers") == 0
     # a send from a loop that was never started goes inline and waits for
     # no sender; no coordinator here was started, so no probe booked here
@@ -194,14 +201,14 @@ def test_every_leaf_has_samples_and_the_leaves_add_up(coop):
 
 
 def test_a_rare_only_ticket_is_host_egress_too(coop):
-    """A consistent query on a quiet leader finds nothing to step: its
-    pass hands the rare message over on a ticket with no egress, and its
+    """A state query on a quiet leader finds nothing to step: its pass
+    hands the rare message over on a ticket with no egress, and its
     time is ``host_egress`` and ``egress_rare`` (or no phase held it)."""
     coords, ids, step, drive = coop
     lead = coords[0]
     before = totals([lead], ("host_egress", "egress_rare", "device_step"))
     q = api.Future()
-    lead.deliver(ids[0], ("consistent_query", lambda s: s, q), None)
+    lead.deliver(ids[0], ("state_query", lambda g: g.term, q), None)
     assert lead.step_once()
     after = totals([lead], ("host_egress", "egress_rare", "device_step"))
     assert after["device_step"][0] == before["device_step"][0]
