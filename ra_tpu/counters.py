@@ -142,6 +142,12 @@ WAL_FIELDS: List[FieldSpec] = [
      "of those, the ones that lay above the snapshot floor and above "
      "all the file held of their writer, and so extended the file's "
      "index in place (the rest filtered, rewound or asked for a resend)"),
+    ("log_opens_fresh", "counter",
+     "server logs opened on a directory the open itself made: nothing "
+     "listed, recovered or synced"),
+    ("log_opens_recovered", "counter",
+     "server logs opened on a directory that was already there: the "
+     "full recovery ran"),
 ]
 
 # Flow-control / liveness counters for a batch coordinator's command

@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import os
 import pickle
+import threading
 import time
 from typing import Any, Callable, List, Optional, Sequence, Tuple
 
@@ -26,10 +27,13 @@ from ra_tpu.log.snapshot import CHECKPOINT, RECOVERY, SNAPSHOT, SnapshotStore
 from ra_tpu.log.tables import TableRegistry
 from ra_tpu.log.wal import Wal
 from ra_tpu.protocol import Entry, SnapshotMeta, encode_cmd
+from ra_tpu.utils.lib import make_dir
 from ra_tpu.utils.seq import Seq
 
 MIN_SNAPSHOT_INTERVAL = 4096
 MIN_CHECKPOINT_INTERVAL = 16384
+
+_OPENS_LOCK = threading.Lock()
 
 
 class Log(LogApi):
@@ -49,9 +53,13 @@ class Log(LogApi):
     ):
         self.uid = uid
         self.server_dir = server_dir
-        os.makedirs(server_dir, exist_ok=True)
+        fresh = make_dir(server_dir)
         self.tables = tables
         self.wal = wal
+        counter = getattr(wal, "counter", None)
+        if counter is not None:
+            with _OPENS_LOCK:  # logs of one node open on many threads
+                counter.incr("log_opens_fresh" if fresh else "log_opens_recovered")
         self.mt = tables.mem_table(uid)
         self.segs = SegmentSet(
             os.path.join(server_dir, "segments"), index_mode=segment_index_mode
@@ -68,8 +76,12 @@ class Log(LogApi):
         self.resend_window_s = 20.0
         self._last_resend_t = float("-inf")
 
-        # recover tail state
-        self._snapshot_meta = self.snapshots.current()
+        # recover tail state (a snapshot/ that the store this call made
+        # has just created holds no capture to list)
+        if snapshot_store is None and self.snapshots.fresh:
+            self._snapshot_meta = None
+        else:
+            self._snapshot_meta = self.snapshots.current()
         snap_idx = self._snapshot_meta.index if self._snapshot_meta else 0
         snap_term = self._snapshot_meta.term if self._snapshot_meta else 0
         if self._snapshot_meta is not None:

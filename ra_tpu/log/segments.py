@@ -31,7 +31,7 @@ from ra_tpu import faults
 from ra_tpu.log.segment import SegmentReader, SegmentWriterHandle
 from ra_tpu.protocol import Entry
 from ra_tpu.utils.flru import FLRU
-from ra_tpu.utils.lib import retry, sync_dir
+from ra_tpu.utils.lib import make_dir, retry, sync_dir
 from ra_tpu.utils.seq import Seq
 
 # symlinks left by major compaction are kept briefly so in-flight
@@ -49,7 +49,7 @@ class SegmentSet:
         readonly: bool = False,
     ):
         self.dir = dir
-        os.makedirs(dir, exist_ok=True)
+        made = make_dir(dir)
         self.index_mode = index_mode  # "map" | "binary" (low-memory)
         self.readonly = readonly
         self._lock = threading.RLock()
@@ -65,6 +65,10 @@ class SegmentSet:
         self._items: List[Tuple[int, int, str]] = []
         self._los: List[int] = []
         self._pmax: List[int] = []
+        if made:
+            # this call's mkdir made the directory: it is empty, so there
+            # is nothing to recover, sync or scan
+            return
         # a readonly view (external ReadPlan.execute) must not run crash
         # recovery: unlinking the owning process's in-flight .compacting
         # temp or .compaction_group marker would abort its live pass

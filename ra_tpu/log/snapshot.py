@@ -20,7 +20,7 @@ from typing import Any, Iterator, List, Optional, Tuple
 
 from ra_tpu import faults
 from ra_tpu.protocol import SnapshotMeta
-from ra_tpu.utils.lib import retry, sync_dir
+from ra_tpu.utils.lib import make_dir, retry, sync_dir
 from ra_tpu.utils.seq import Seq
 
 SNAPSHOT = "snapshots"
@@ -213,9 +213,15 @@ class SnapshotStore:
         self.codec = codec or PickleCodec()
         self.max_checkpoints = max_checkpoints
         self.sync_pool = sync_pool
+        # True when this constructor made snapshot/: it holds no capture
+        self.fresh = False
         for kind in (SNAPSHOT, CHECKPOINT, RECOVERY):
             d = os.path.join(server_dir, kind)
-            os.makedirs(d, exist_ok=True)
+            if make_dir(d):
+                # just made, so empty: no spool to clear
+                if kind == SNAPSHOT:
+                    self.fresh = True
+                continue
             # a crash mid-write/mid-accept leaves .writing/.accepting
             # (or legacy .partial) spool dirs; they are not valid
             # captures — clear them. Orphaned accept spools also count

@@ -65,6 +65,20 @@ def atomic_write(path: str, data: bytes, fsync: bool = True) -> None:
         sync_dir(d)
 
 
+def make_dir(path: str) -> bool:
+    """Create the directory ``path``: True when this call made it (so it
+    is empty), False when it was already there. One ``mkdir`` when the
+    parent exists; the parents are made only when it does not."""
+    try:
+        os.mkdir(path)
+    except FileExistsError:
+        return False
+    except FileNotFoundError:
+        os.makedirs(os.path.dirname(path), exist_ok=True)
+        return make_dir(path)
+    return True
+
+
 def sync_dir(path: str) -> None:
     fd = os.open(path, os.O_RDONLY | getattr(os, "O_DIRECTORY", 0))
     try:
